@@ -96,7 +96,7 @@ def test_jump_full_window_process_fails_safe():
     late_by_an_hour = "1564996400 10.8.22.1 cpu0 99.0"
 
     env = StreamExecutionEnvironment(
-        StreamConfig(batch_size=1, key_capacity=8)
+        StreamConfig(batch_size=1, key_capacity=8, strict_overflow=False)
     )
     env.set_stream_time_characteristic(TimeCharacteristic.IngestionTime)
     src = ReplaySource(

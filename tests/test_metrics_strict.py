@@ -112,7 +112,9 @@ def test_process_window_fires_counted():
 
 
 def test_process_buffer_overflow_counted_not_strict():
-    env = _median_env(LINES4, process_buffer_capacity=2)
+    env = _median_env(
+        LINES4, process_buffer_capacity=2, strict_overflow=False
+    )
     env.execute("overflow-counted")
     s = env.metrics.summary()
     # key 10.8.22.1 had 3 elements, capacity 2 -> 1 truncated
@@ -120,7 +122,8 @@ def test_process_buffer_overflow_counted_not_strict():
 
 
 def test_process_buffer_overflow_strict_raises():
-    env = _median_env(LINES4, process_buffer_capacity=2, strict_overflow=True)
+    # strict_overflow is the default
+    env = _median_env(LINES4, process_buffer_capacity=2)
     with pytest.raises(RuntimeError, match="strict_overflow.*buffer_overflow"):
         env.execute("overflow-strict")
 
@@ -195,7 +198,9 @@ def test_exchange_overflow_strict_raises():
 
 
 def test_exchange_overflow_counted_not_strict():
-    s = run_sharded_reduce(SKEWED, exchange_capacity_factor=0.125)
+    s = run_sharded_reduce(
+        SKEWED, exchange_capacity_factor=0.125, strict_overflow=False
+    )
     assert s["exchange_overflow"] > 0
 
 

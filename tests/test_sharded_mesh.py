@@ -7,11 +7,6 @@ import pytest
 
 import jax
 
-try:  # jax >= 0.6
-    shard_map = jax.shard_map
-except AttributeError:  # older jax
-    from jax.experimental.shard_map import shard_map
-
 from tpustream import StreamExecutionEnvironment, TimeCharacteristic
 from tpustream.config import StreamConfig
 from tpustream.jobs.chapter2_max import build as build_max
@@ -114,7 +109,7 @@ def test_exchange_roundtrip_all_records():
         return cols[0], cols[1], t, v, jax.lax.psum(ovf, AXIS)
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             core,
             mesh=mesh,
             in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS)),

@@ -82,6 +82,19 @@ else:
     # benchmark configs opt back into f32/i32 accumulators via
     # StreamConfig.
     _jax.config.update("jax_enable_x64", True)
+    # Persistent XLA compilation cache, set in this one place. JAX reads
+    # JAX_COMPILATION_CACHE_DIR itself; when neither it nor the caller
+    # names a directory, the cache sits at a fixed path in the checkout,
+    # where the next run finds it (a temp, pid or timestamp name would
+    # never be found again).
+    if _jax.config.jax_compilation_cache_dir is None:
+        _jax.config.update(
+            "jax_compilation_cache_dir",
+            _os.path.join(
+                _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+                ".jax_cache",
+            ),
+        )
 
     from .api.tuples import Tuple2, Tuple3, Tuple4  # noqa: E402
     from .api.timeapi import Time, TimeCharacteristic  # noqa: E402

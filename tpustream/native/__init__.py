@@ -1,9 +1,11 @@
 """ctypes bindings for the native fast parser.
 
-Builds ``_fastparse.so`` from ``fastparse.cpp`` on first use (g++ is in
-the image; pybind11 is not, so the binding is plain ctypes). Falls back
-gracefully: callers check ``available()`` and keep the numpy/python path
-when compilation fails.
+Builds ``_fastparse.so`` from ``fastparse.cpp`` on first use, on the
+host that loads it (g++ is in the image; pybind11 is not, so the binding
+is plain ctypes). The library is never committed: it is built with
+``-march=native``, and a copy from another CPU can die with SIGILL,
+which ``dlopen`` does not catch. Falls back gracefully: callers check
+``available()`` and keep the numpy/python path when compilation fails.
 """
 
 from __future__ import annotations
@@ -24,17 +26,15 @@ KIND_ISO = 3
 _HERE = os.path.dirname(os.path.abspath(__file__))
 # build flavors: "default" is the tuned production .so; "asan" (selected
 # with TPUSTREAM_NATIVE_FLAVOR=asan, plus LD_PRELOADing libasan into the
-# interpreter) is the Makefile's `asan` target with
-# -fsanitize=address,undefined for memory-safety runs of the same kernel
-_FLAVORS = {
-    "default": ("_fastparse.so", "_fastparse.so"),
-    "asan": ("_fastparse_asan.so", "asan"),
-}
+# interpreter) is the Makefile's sanitized target with
+# -fsanitize=address,undefined for memory-safety runs of the same kernel.
+# Each flavor's library name is also its Makefile target.
+_FLAVORS = {"default": "_fastparse.so", "asan": "_fastparse_asan.so"}
 _flavor = os.environ.get("TPUSTREAM_NATIVE_FLAVOR", "default")
 if _flavor not in _FLAVORS:
     _flavor = "default"
-_SO = os.path.join(_HERE, _FLAVORS[_flavor][0])
-_MAKE_TARGET = _FLAVORS[_flavor][1]
+_MAKE_TARGET = _FLAVORS[_flavor]
+_SO = os.path.join(_HERE, _MAKE_TARGET)
 _lock = threading.Lock()
 _lib = None
 _tried = False
@@ -51,36 +51,45 @@ def _build() -> bool:
 
     The Makefile carries the tuned flags (-march=native); the fallback
     drops them so a host whose toolchain rejects the tuned line still
-    gets A native parser rather than none. Never raises: on failure the
-    last compiler stderr is kept in ``_build_error`` for the executor's
-    flight breadcrumb and the numpy path takes over."""
+    gets A native parser rather than none. Each attempt writes a temp
+    name next to ``_SO`` and renames it into place, so a concurrent
+    loader (another test worker) never dlopens a half-written file.
+    Never raises: on failure the last compiler stderr is kept in
+    ``_build_error`` for the executor's flight breadcrumb and the numpy
+    path takes over."""
     global _build_error
     src = os.path.join(_HERE, "fastparse.cpp")
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     if _flavor == "asan":
         fallback = [
             "g++", "-O1", "-g", "-fno-omit-frame-pointer",
             "-fsanitize=address,undefined", "-shared", "-fPIC",
-            "-std=c++17", "-pthread", src, "-o", _SO,
+            "-std=c++17", "-pthread", src, "-o", tmp,
         ]
     else:
         fallback = [
             "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-            src, "-o", _SO,
+            src, "-o", tmp,
         ]
     attempts = [
-        ["make", "-C", _HERE, _MAKE_TARGET],
+        ["make", "-B", "-C", _HERE, _MAKE_TARGET, f"OUT={tmp}"],
         fallback,
     ]
     errors = []
-    for cmd in attempts:
-        try:
-            subprocess.run(cmd, check=True, capture_output=True)
-            _build_error = None
-            return True
-        except subprocess.CalledProcessError as e:
-            errors.append(f"{cmd[0]}: {_tail(e.stderr or e.stdout or b'')}")
-        except Exception as e:
-            errors.append(f"{cmd[0]}: {e}")
+    try:
+        for cmd in attempts:
+            try:
+                subprocess.run(cmd, check=True, capture_output=True)
+                os.replace(tmp, _SO)
+                _build_error = None
+                return True
+            except subprocess.CalledProcessError as e:
+                errors.append(f"{cmd[0]}: {_tail(e.stderr or e.stdout or b'')}")
+            except Exception as e:
+                errors.append(f"{cmd[0]}: {e}")
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
     _build_error = "; ".join(errors) or "unknown build failure"
     return False
 
@@ -105,7 +114,7 @@ def _load():
         try:
             lib = ctypes.CDLL(_SO)
         except OSError as e:
-            # a pre-built .so from another toolchain (missing GLIBCXX
+            # a stale .so from another toolchain (missing GLIBCXX
             # symbols, wrong arch) dlopen-fails even though it is newer
             # than the source: rebuild once against THIS toolchain
             if not _build():

@@ -39,6 +39,7 @@ KNOWN_SERIES = frozenset({
     "ingest_heartbeat_age_ms",
     # compile registry
     "compile_count", "recompile_count", "compile_wall_ms",
+    "compile_cache_hits",
     "compile_flops", "compile_bytes_accessed", "compile_instrument_fallback",
     "operator_recompile_cause",
     # operator scope (static members of the operator_ family)

@@ -28,15 +28,12 @@ first build.
 The instrumentation is strictly observational: the AOT ``Compiled``
 object exists only to be timed and analysed, and every actual step runs
 through the plain ``jax.jit`` dispatch — the byte-identical execution
-path the uninstrumented runtime uses. Executing the AOT object directly
-would be marginally cheaper, but executing a persistent-cache-touched
-executable against donated buffers intermittently corrupts the
-allocator heap on jax 0.4.37 CPU (``double free or corruption`` /
-segfault a few steps after a mid-job rebuild), so the metric compile
-runs with the compilation cache scoped off and the executable is
-discarded after analysis. Enabling obs therefore pays one extra XLA
-build per program signature — the price of an honest
-``compile_wall_ms`` and of never perturbing the execution path.
+path the uninstrumented runtime uses. The dispatch reuses the executable
+the AOT compile built (one XLA build per signature), and that build
+reads and writes the persistent compilation cache like any other. So a
+``compile_wall_ms`` reading times either a build (a cache miss) or a
+load from the cache (a hit); each ``program_compiled`` event says which
+in its ``cache`` field, and ``compile_cache_hits`` counts the loads.
 
 The AOT path is also belt-and-braces: if ``lower()``/``compile()``
 raises, the wrapper permanently falls back to counting builds by the
@@ -71,6 +68,26 @@ def _signature(args) -> tuple:
         else:
             sig.append(("py", type(leaf).__name__))
     return tuple(sig)
+
+
+_CACHE_HITS = [0]
+_CACHE_LISTENING = [False]
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        _CACHE_HITS[0] += 1
+
+
+def _cache_hits() -> int:
+    """Persistent-cache hits JAX has reported in this process since the
+    first call (the listener is installed then, once)."""
+    if not _CACHE_LISTENING[0]:
+        import jax.monitoring
+
+        jax.monitoring.register_event_listener(_on_event)
+        _CACHE_LISTENING[0] = True
+    return _CACHE_HITS[0]
 
 
 def _tree_leaves(args):
@@ -109,13 +126,19 @@ class CompileObs:
         self.compile_count = op_obs.counter("compile_count")
         self.recompile_count = op_obs.counter("recompile_count")
         self.compile_wall_ms = op_obs.histogram("compile_wall_ms")
+        self.compile_cache_hits = op_obs.counter("compile_cache_hits")
         self._n = 0
 
     def instrument(self, fn, cause: str, donate_argnums=0) -> "InstrumentedStep":
         return InstrumentedStep(fn, self, cause, donate_argnums=donate_argnums)
 
-    def record_compile(self, cause: str, wall_ms: float, compiled=None) -> None:
+    def record_compile(self, cause: str, wall_ms: float, compiled=None,
+                       cache: Optional[str] = None) -> None:
+        """``cache``: "hit" when the build was a persistent-cache load,
+        "miss" when XLA built it, None when unknown (fallback path)."""
         self.compile_count.inc()
+        if cache == "hit":
+            self.compile_cache_hits.inc()
         if self._n > 0:
             self.recompile_count.inc()
             self._obs.scoped(cause=cause).counter("operator_recompile_cause").inc()
@@ -123,6 +146,7 @@ class CompileObs:
             "operator": self._obs.name,
             "cause": cause,
             "wall_ms": round(wall_ms, 3),
+            "cache": cache,
             "compile_index": self._n,
         }
         event.update(self._meta)
@@ -164,14 +188,9 @@ class InstrumentedStep:
     """Callable twin of ``jax.jit(fn, donate_argnums=...)`` that makes
     every build explicit: each new input signature is lowered and
     compiled ahead of time so the wall clock, cost analysis and cause
-    can be recorded — then the AOT executable is DISCARDED and the call
-    runs through the jit's own dispatch.
-
-    Executing the AOT ``Compiled`` object ourselves would save the
-    dispatch's cache lookup, but donated buffers + ``Compiled.__call__``
-    + the persistent XLA compilation cache intermittently corrupt the
-    heap on jax 0.4.37 CPU, so execution stays on the stock path and
-    keeps its donation semantics untouched.
+    can be recorded — then the call runs through the jit's own dispatch,
+    which finds that executable already built and keeps its donation
+    semantics untouched.
 
     The signature cache mirrors jit's own: one recorded build per
     distinct input aval signature. The first build carries the cause the
@@ -196,9 +215,11 @@ class InstrumentedStep:
                 cause = self._next_cause
                 self._next_cause = CAUSE_BATCH_SHAPE
                 try:
+                    hits = _cache_hits()
                     t0 = time.perf_counter()
-                    compiled = self._aot_compile(*args)
+                    compiled = self._jit.lower(*args).compile()
                     wall_ms = (time.perf_counter() - t0) * 1e3
+                    cache = "hit" if _cache_hits() > hits else "miss"
                 except Exception as e:
                     # AOT path unavailable here: count the build the
                     # plain dispatch below performs (trace+compile+run
@@ -212,23 +233,6 @@ class InstrumentedStep:
                     )
                     return out
                 self._seen.add(sig)
-                self._obs.record_compile(cause, wall_ms, compiled)
+                self._obs.record_compile(cause, wall_ms, compiled, cache)
                 del compiled  # analysed, never executed (see class doc)
         return self._jit(*args)
-
-    def _aot_compile(self, *args):
-        """Lower+compile for analysis only, with the persistent XLA
-        compilation cache scoped OFF. If the metric compile wrote the
-        cache entry, the dispatch below would execute a deserialized
-        executable against donated buffers — the combination that
-        intermittently corrupts the heap on jax 0.4.37 CPU. Keeping the
-        cache out of this build also keeps ``compile_wall_ms`` honest:
-        it always times a real build, never a disk hit."""
-        import jax
-
-        prev = jax.config.jax_enable_compilation_cache
-        jax.config.update("jax_enable_compilation_cache", False)
-        try:
-            return self._jit.lower(*args).compile()
-        finally:
-            jax.config.update("jax_enable_compilation_cache", prev)

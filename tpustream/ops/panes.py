@@ -147,16 +147,10 @@ def fire_candidates(hi_pane, wm_old, wm_new, spec: RingSpec):
 
 def vary(x, axes):
     """Mark a freshly-created constant as device-varying over ``axes`` so
-    VMA tracking under shard_map accepts it alongside sharded data. On
-    jax builds that predate varying-manual-axes tracking there is
-    nothing to satisfy (no ``jax.lax.pcast``), so the value passes
-    through unchanged."""
+    VMA tracking under shard_map accepts it alongside sharded data."""
     if not axes:
         return x
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, axes, to="varying")
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def compact(mask_flat: jnp.ndarray, cols, capacity: int):

@@ -506,8 +506,8 @@ class Runner:
         # task-manager-local sink semantics (chapter1/README.md:80-84's
         # n> prefixes, printed on whichever host owns the subtask)
         self._multiproc = jax.process_count() > 1
+        mesh = getattr(self.program, "mesh", None)
         if self._multiproc:
-            mesh = getattr(self.program, "mesh", None)
             if mesh is None:
                 raise NotImplementedError(
                     "multi-host execution needs a sharded program: set "
@@ -527,8 +527,14 @@ class Runner:
             from ..parallel.mesh import AXIS
 
             self._data_sharding = NamedSharding(mesh, P(AXIS))
-            # place the initial state onto the global mesh (leaves built
-            # host-local would not be addressable under the SPMD step)
+        if mesh is not None:
+            from jax.sharding import NamedSharding
+
+            # place the initial state onto the mesh as the step returns
+            # it: leaves built host-local would not be addressable under
+            # a multi-host SPMD step, and on one host an unplaced state
+            # would miss the jit cache on the second step (its input
+            # types would lack the mesh the first step's outputs carry)
             leaves, treedef = jax.tree_util.tree_flatten(self.state)
             spec_leaves = jax.tree_util.tree_leaves(
                 self.program.state_specs(self.state),
@@ -1180,11 +1186,13 @@ class Runner:
             if self.count_input:
                 self.metrics.records_in += int(sub.n)
                 self.obs.records_in.inc(int(sub.n))
-            # with a max_fires_per_step budget, drain deferred window ends
-            # BEFORE the next batch can advance the pane ring past them —
-            # each drain step still fires at most `budget` ends, so the
-            # per-step latency bound holds while no fire is ever lost
-            self._drain(wm_lower, t_batch)
+            # with a max_fires_per_step budget, or after a batch with no
+            # valid row (whose step may defer ends that overflow
+            # alert_capacity), drain deferred window ends BEFORE the
+            # next batch can add records to their windows — each drain
+            # step still fires at most `budget` ends, so the per-step
+            # latency bound holds while no fire is ever lost
+            self._drain(wm_lower, t_batch, empty=not padded.valid.any())
 
     @staticmethod
     def _wire_nbytes(inputs) -> int:
@@ -1272,8 +1280,9 @@ class Runner:
         """Advance time with an empty batch (processing-time tick / EOS).
 
         Window programs fire at most ``max_fires_per_step`` window ends
-        per step (bounding fire-step latency); the loop here drains any
-        deferred ends until ``state["pending_fires"]`` reaches zero."""
+        per step (bounding fire-step latency), and an empty step only as
+        many as fit alert_capacity; the loop here drains any deferred
+        ends until ``state["pending_fires"]`` reaches zero."""
         # staged batches must step before any clock tick: an empty step
         # jumping ahead of a staged data batch would fire its windows
         # from a pre-batch state
@@ -1282,8 +1291,14 @@ class Runner:
             return
         if t_batch is None:
             t_batch = time.perf_counter()
-        cfg = self.cfg
+        self._run_step(self._empty_inputs(), wm_lower, t_batch)
+        self._drain(wm_lower, t_batch, empty=True)
+
+    def _empty_inputs(self):
+        """Packed step inputs of a batch with no valid row (clock ticks,
+        end of stream, drain rounds)."""
         if self._empty_cache is None:
+            cfg = self.cfg
             cols = [
                 np.zeros(
                     (cfg.batch_size,),
@@ -1296,8 +1311,14 @@ class Runner:
             valid = np.zeros((cfg.batch_size,), dtype=bool)
             ts = np.zeros((cfg.batch_size,), dtype=np.int64)
             self._empty_cache = self._pack(cols, valid, ts)
-        self._run_step(self._empty_cache, wm_lower, t_batch)
-        self._drain(wm_lower, t_batch)
+        inputs = self._empty_cache
+        if self._h2d_ahead and self._h2d_sharding is not None:
+            # placed like the staged data batches, or the step would
+            # miss the jit cache on the mesh's input types
+            packed, bases, valid, ts_p, ts_b = inputs
+            packed, valid, ts_p = self._sharded_put((packed, valid, ts_p))
+            inputs = (packed, bases, valid, ts_p, ts_b)
+        return inputs
 
     def _counted_step(self, inner):
         """Wrap the program's jitted step to (a) decode the packed wire
@@ -1979,10 +2000,9 @@ class Runner:
         programs: a slice of the 'main' stream sized by the PREVIOUS
         firing step's count, fetched in the same round trip as the count
         scalars. When the hint covers the actual count, a firing step
-        costs ONE link round trip instead of two — on a ~100 ms-RTT
-        tunnel that halves the alert-path fetch latency; on PCIe the
-        saving is noise and the speculative bytes are bounded by the
-        hint. Returns (stream_slice, hint_rows) or (None, 0)."""
+        costs ONE host-device round trip instead of two; the
+        speculative bytes are bounded by the hint. Returns
+        (stream_slice, hint_rows) or (None, 0)."""
         if not self._spec_eligible(entries) or not self._prefix_hint:
             return None, 0
         main = entries[0][0]["main"]
@@ -2130,14 +2150,18 @@ class Runner:
                 "process_buffer_capacity / pane_ring_slack)"
             )
 
-    def _drain(self, wm_lower: int, t_batch=None):
-        """Run empty-batch steps until no window fires remain deferred by
-        the max_fires_per_step budget (no-op for programs without one).
+    def _drain(self, wm_lower: int, t_batch=None, empty: bool = False):
+        """Run empty-batch steps until no window fires remain deferred.
 
-        Without a budget every step fires all due ends, so pending is
-        provably zero — skip even the scalar device_get on the hot loop."""
-        if self.cfg.max_fires_per_step is None:
+        Ends are deferred by the max_fires_per_step budget, or, on a
+        step fed no valid row (``empty``), when firing them would
+        overflow alert_capacity. Otherwise every step fires all due
+        ends, so pending is provably zero: skip even the scalar
+        device_get on the hot loop."""
+        if self.cfg.max_fires_per_step is None and not empty:
             return
+        # a staged empty batch has not stepped yet
+        self._flush_uploads()
         pending = (
             self.state.get("pending_fires")
             if isinstance(self.state, dict)
@@ -2145,13 +2169,11 @@ class Runner:
         )
         if pending is None or int(jax.device_get(pending)) == 0:
             return
-        if self._empty_cache is None:
-            # builds the cache and runs one round
-            self.flush(wm_lower, t_batch)
-            return
+        # each round fires at least one end: the first pending end always
+        # fits an empty alert buffer (or fires alone, counting overflow)
         max_rounds = self.program.ring.n_fire_candidates + 1
         for _ in range(max_rounds):
-            self._run_step(self._empty_cache, wm_lower, t_batch)
+            self._run_step(self._empty_inputs(), wm_lower, t_batch)
             if int(jax.device_get(self.state["pending_fires"])) == 0:
                 break
 

@@ -1,9 +1,8 @@
 """Sharded host ingestion: the IngestPlane, now self-healing.
 
 The single-lane host stage is one socket -> one parse thread -> one H2D
-lane; its measured single-stream wire ceiling (~531K rows/s, BENCH_r05)
-is the end-to-end flood bottleneck while the device sustains tens of
-millions of events/s. This module shards that host data plane the way
+lane, a single-stream ceiling far below what the device step can
+absorb. This module shards that host data plane the way
 Flink scales sources (parallel source subtasks feeding a partitioned
 exchange): ``StreamConfig.ingest_lanes`` worker processes
 (parallel/lanes.py) each own a shared-memory ring of length-framed
@@ -65,6 +64,7 @@ FORMAT_VERSION change. The pieces:
 
 from __future__ import annotations
 
+import multiprocessing
 import threading
 import time
 from typing import Iterator, List, Optional
@@ -268,6 +268,17 @@ def build_ingest_plane(
     return plane
 
 
+# fork when the platform has it: the worker inherits the already-
+# imported parse modules and skips spawn's re-exec of the user's
+# __main__ (the child never touches jax — it only runs the numpy/native
+# parse loop). spawn is the fallback; there the TPUSTREAM_LANE_WORKER
+# gate keeps the child's package import light and the gate's lazy
+# __getattr__ keeps user scripts importable.
+LANE_START_METHOD = (
+    "fork" if "fork" in multiprocessing.get_all_start_methods() else "spawn"
+)
+
+
 class IngestPlane:
     """N supervised lane worker processes + the deterministic merge."""
 
@@ -278,8 +289,6 @@ class IngestPlane:
         stall_limit_s: float = 0.0, restart_budget: int = 0,
         watchdog_limit_s: float = 30.0, fault_points: Optional[list] = None,
     ):
-        import multiprocessing as mp
-
         self.lanes = lanes
         self.spec = spec
         self._global_tables = global_tables
@@ -293,17 +302,7 @@ class IngestPlane:
         self._stall_limit_s = stall_limit_s
         self._policy = LaneRestartPolicy(restart_budget)
 
-        # fork when the platform has it: the worker inherits the already-
-        # imported parse modules and skips spawn's re-exec of the user's
-        # __main__ (the child never touches jax — it only runs the
-        # numpy/native parse loop). spawn is the fallback; there the
-        # TPUSTREAM_LANE_WORKER gate keeps the child's package import
-        # light and the gate's lazy __getattr__ keeps user scripts
-        # importable.
-        try:
-            self._ctx = mp.get_context("fork")
-        except ValueError:
-            self._ctx = mp.get_context("spawn")
+        self._ctx = multiprocessing.get_context(LANE_START_METHOD)
         self._lane_faults = self._build_lane_faults(fault_points or [])
 
         # merge/producer shared state. The lock is re-entrant: lane

@@ -3,7 +3,7 @@
 The single-chip programs become SPMD by overriding four hooks:
 ``_exchange`` (keyBy as ICI all_to_all), ``_local_keys`` (key -> owner's
 dense slot), ``_global_max``/``_global_sum`` (watermark & counters via
-``pmax``/``psum``). Keyed state shards over the mesh axis: key ``k``
+all-reduces). Keyed state shards over the mesh axis: key ``k``
 lives on shard ``k % S`` at local row ``k // S``. The whole step runs
 under ``jax.shard_map`` so XLA schedules the collectives on ICI
 (SURVEY.md §2.3: the TPU-native equivalent of Flink's keyed exchange).
@@ -17,16 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KWARGS: dict = {}
-except AttributeError:  # older jax: the experimental namespace, whose
-    # replication checker predates while_loop support (VMA tracking
-    # replaced it upstream) — disable it rather than fail to trace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KWARGS = {"check_rep": False}
 
 from ..parallel.exchange import exchange_by_key, exchange_capacity
 from ..parallel.mesh import AXIS, make_mesh
@@ -67,7 +57,15 @@ class _ShardedMixin:
         )
 
     def _global_max(self, x):
-        return jax.lax.pmax(x, AXIS)
+        # The TPU compiler lowers only sum all-reduces of 64-bit values
+        # (a pmax of the int64 watermark is UNIMPLEMENTED there), and an
+        # all_gather's result stays per-shard to shard_map's type check:
+        # gather every shard's value with a psum of one-hot rows, then
+        # reduce locally. Exact for every dtype: other rows add zero.
+        rows = jnp.arange(self.n_shards) == jax.lax.axis_index(AXIS)
+        rows = rows.reshape((self.n_shards,) + (1,) * jnp.ndim(x))
+        gathered = jax.lax.psum(jnp.where(rows, x, 0), AXIS)
+        return jnp.max(gathered, axis=0).astype(x.dtype)
 
     def _global_sum(self, x):
         return jax.lax.psum(x, AXIS)
@@ -105,12 +103,11 @@ class _ShardedMixin:
         # a RuleSet (rule leaves are 0-d -> P() above -> replicated, so
         # every shard evaluates the same rule version per batch), else
         # _step itself
-        fn = _shard_map(
+        fn = jax.shard_map(
             self.traced_step(),
             mesh=self.mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            **_SHARD_MAP_KWARGS,
         )
         return jax.jit(fn, donate_argnums=0)
 

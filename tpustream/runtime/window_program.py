@@ -28,7 +28,9 @@ on v5e (the scatter/gather cost model in docs/architecture.md):
      dynamic row slices over the ring, O(panes * keys) sequential HBM
      reads, no large gathers — then finalizes, runs the post chain over
      all keys at once, and append-compacts surviving alerts into the
-     fixed ``alert_capacity`` output buffer.
+     fixed ``alert_capacity`` output buffer. A step fed no valid row
+     (clock tick, end of stream) defers the ends whose alerts no longer
+     fit that buffer; the executor drains them with further such steps.
 """
 
 from __future__ import annotations
@@ -570,7 +572,7 @@ class WindowProgram(BaseProgram):
     # ------------------------------------------------------------------
     def _fire_dense(
         self, planes, cnt, slot_pane, hi, wm_old, wm_new, fired_through, touched,
-        emission_carry=None, budget_on=None,
+        emission_carry=None, budget_on=None, defer_on=None,
     ):
         """Fire due window ends from the ring.
 
@@ -579,7 +581,14 @@ class WindowProgram(BaseProgram):
         emission buffer; None starts fresh. ``budget_on`` (traced bool)
         suspends the max_fires_per_step budget on non-final sweep
         iterations — a deferred fire there would fall out of ring
-        coverage before the next drain tick could reach it."""
+        coverage before the next drain tick could reach it.
+        ``defer_on`` (traced, replicated bool) lets the pending ends
+        whose alerts would overflow the ``alert_capacity`` buffer wait,
+        in order, for the next step; only steps the executor drains
+        after (no valid row) pass it. An end that alone overflows an
+        empty buffer still fires and counts the excess. Not on a mesh
+        with allowed lateness, whose per-shard refires would put the
+        deferral's all-reduce under a per-shard branch."""
         ring = self.ring
         k, n, f = self.local_key_capacity, ring.n_slots, ring.n_fire_candidates
         cap = self.cfg.alert_capacity
@@ -593,7 +602,9 @@ class WindowProgram(BaseProgram):
             budget = jnp.where(budget_on, budget, f)
         csum = jnp.cumsum(pending.astype(jnp.int32))
         fire_now = pending & (csum <= budget)
-        n_deferred = (jnp.sum(pending) - jnp.sum(fire_now)).astype(jnp.int64)
+        can_defer = defer_on is not None and (
+            self.n_shards == 1 or self.allowed_lateness_ms == 0
+        )
         if self.allowed_lateness_ms > 0:
             # allowed-late arrivals re-fire already-fired windows they
             # touch (chapter3/README.md:212 option 2). Refires are EXEMPT
@@ -612,10 +623,6 @@ class WindowProgram(BaseProgram):
                 & dirty
             )
             fire_now = fire_now | refire
-        new_ft = jnp.maximum(
-            fired_through,
-            jnp.max(jnp.where(fire_now & pending, ends - 1, W0)),
-        )
         any_fire = jnp.any(fire_now)
 
         v = lambda x: pane_ops.vary(x, self.vary_axes)
@@ -626,10 +633,10 @@ class WindowProgram(BaseProgram):
 
         def do_fire(_):
             def cand_body(carry, jj):
-                out_cols, count, ovf, fires = carry
+                out_cols, count, ovf, fires, stop, n_done = carry
 
                 def fire_one(c2):
-                    out_cols, count, ovf, fires = c2
+                    out_cols, count, ovf, fires, stop, n_done = c2
                     e_pane = cand[jj]
 
                     def pane_body(c3, o):
@@ -690,12 +697,20 @@ class WindowProgram(BaseProgram):
                         list(results), has
                     )
                     emit = post_mask & has
+                    defer = jnp.asarray(False)
+                    if can_defer:
+                        n_emit = jnp.sum(emit, dtype=jnp.int32)
+                        full = (count > 0) & (count + n_emit > cap)
+                        defer = (
+                            defer_on & pending[jj]
+                            & (self._global_max(full) > 0)
+                        )
 
                     # append-compact the fired alerts after current count
                     end_col = jnp.zeros((k,), dtype=jnp.int64) + ends[jj]
                     src_cols = post_cols + [key_col, end_col]
                     out_cols, new_count, overflowed = pane_ops.append_compact(
-                        emit, src_cols, out_cols, count, cap
+                        emit & ~defer, src_cols, out_cols, count, cap
                     )
                     # every (key, window) with content is one window fire,
                     # counted BEFORE the post-chain filter (metrics parity
@@ -704,34 +719,52 @@ class WindowProgram(BaseProgram):
                         out_cols,
                         new_count,
                         ovf + overflowed,
-                        fires + jnp.sum(has).astype(jnp.int64),
+                        fires + jnp.where(defer, 0, jnp.sum(has)).astype(
+                            jnp.int64
+                        ),
+                        stop | defer,
+                        n_done + (pending[jj] & ~defer).astype(jnp.int32),
                     )
 
                 return jax.lax.cond(
-                    fire_now[jj], fire_one, lambda c2: c2,
-                    (out_cols, count, ovf, fires),
+                    fire_now[jj] & ~(stop & pending[jj]),
+                    fire_one, lambda c2: c2,
+                    (out_cols, count, ovf, fires, stop, n_done),
                 ), None
 
-            (out_cols, count, ovf, fires), _ = jax.lax.scan(
+            (out_cols, count, ovf, fires, _, n_done), _ = jax.lax.scan(
                 cand_body,
-                (list(carry_out), carry_cnt, carry_ovf, carry_fires),
+                (
+                    list(carry_out), carry_cnt, carry_ovf, carry_fires,
+                    jnp.asarray(False), jnp.zeros((), dtype=jnp.int32),
+                ),
                 jnp.arange(f),
             )
-            return out_cols, count, ovf, fires
+            return out_cols, count, ovf, fires, n_done
 
         def no_fire(_):
-            return list(carry_out), carry_cnt, carry_ovf, carry_fires
+            return (
+                list(carry_out), carry_cnt, carry_ovf, carry_fires,
+                jnp.zeros((), dtype=jnp.int32),
+            )
 
-        out_cols, count, overflow, n_fired = jax.lax.cond(
+        out_cols, count, overflow, n_fired, n_done = jax.lax.cond(
             any_fire, do_fire, no_fire, operand=None
         )
+        # pending ends fire in order, so the fired ones are the first
+        # n_done of them
+        new_ft = jnp.maximum(
+            fired_through,
+            jnp.max(jnp.where(pending & (csum <= n_done), ends - 1, W0)),
+        )
+        n_deferred = (jnp.sum(pending) - n_done).astype(jnp.int64)
         # (cols, count, overflow, fires) is cumulative past the carry —
         # re-feed it as emission_carry to append further sweep fires
         return (out_cols, count, overflow, n_fired), new_ft, n_deferred
 
     def _sweep(
         self, planes, cnt, slot_pane, hi_target, ft0,
-        wm_old, wm_new, keys, mid_cols, live, pane, init_leaves,
+        wm_old, wm_new, keys, mid_cols, live, pane, init_leaves, drain,
     ):
         """Advance the ring from its current head to ``hi_target`` in
         safe chunks when one step spans more panes than the ring covers
@@ -809,6 +842,7 @@ class WindowProgram(BaseProgram):
             emission, ft2, pending = self._fire_dense(
                 planes2, cnt2, slot_pane2, hi_next, wm_old, wm_eff, ft,
                 touched, emission_carry=emission, budget_on=is_final,
+                defer_on=is_final & drain,
             )
             return (
                 jnp.asarray(False), hi_next, hi_next, planes2, cnt2,
@@ -847,6 +881,9 @@ class WindowProgram(BaseProgram):
     def _step(self, state, cols, valid, ts, wm_lower):
         mid_cols, mask = self._apply_pre(cols, valid)
         ring = self.ring
+        # fed no valid row: the executor drains after this step, so it
+        # may defer ends that overflow the alert buffer
+        drain = ~self._global_max(jnp.any(valid))
 
         wm_old = state["wm"]
         batch_max = self._global_max(jnp.max(jnp.where(mask, ts, W0)))
@@ -935,6 +972,7 @@ class WindowProgram(BaseProgram):
             )
             emission, new_ft, n_pending = self._fire_dense(
                 planes2, cnt2, slot_pane, hi, wm_old, wm_new, ft0, touched,
+                defer_on=drain,
             )
             return (
                 planes2, cnt2, slot_pane, new_ft, evicted,
@@ -946,6 +984,7 @@ class WindowProgram(BaseProgram):
             return self._sweep(
                 planes, cnt, state["slot_pane"], hi, ft0,
                 wm_old, wm_new, keys, mid_cols, live, pane, init_leaves,
+                drain,
             )
 
         (
