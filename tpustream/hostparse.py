@@ -337,12 +337,16 @@ class PlanEvaluator:
             return np.asarray(va, np.float64) / np.asarray(vb, np.float64)
         return va // vb
 
-    def parse_bytes(self, data: bytes, n_lines: int) -> Optional[List[np.ndarray]]:
+    def parse_bytes(self, data: bytes, n_lines: int, tracer=None,
+                    step: int = -1) -> Optional[List[np.ndarray]]:
         """Native path over a raw newline-separated buffer; None if the
-        native parser is unavailable for this plan."""
+        native parser is unavailable for this plan. ``tracer``/``step``
+        time the native call and the id remap as sub-spans of the
+        caller's ``parse`` span (:meth:`NativeParser.parse`)."""
         if self._native is None:
             return None
-        base_vals, bad = self._native.parse(data, n_lines)
+        base_vals, bad = self._native.parse(data, n_lines, tracer=tracer,
+                                            step=step)
         if bad or len(base_vals[0]) != n_lines:
             # bad fields zero-fill in the C kernel rather than raise, so
             # a batch with ANY malformed line must take the strict python

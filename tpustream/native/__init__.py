@@ -10,6 +10,7 @@ which ``dlopen`` does not catch. Falls back gracefully: callers check
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import os
 import subprocess
@@ -210,6 +211,13 @@ class NativeTable:
         return np.asarray(self._remap, dtype=np.int32)
 
 
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _no_span(kind: str, step: int):
+    return _NO_SPAN
+
+
 class NativeParser:
     """Parses a byte buffer of lines into columns per a base-field spec."""
 
@@ -233,11 +241,16 @@ class NativeParser:
             *[t.ptr if t is not None else None for t in self.tables]
         )
 
-    def parse(self, data: bytes, max_rows: int, threads: Optional[int] = None):
+    def parse(self, data: bytes, max_rows: int, threads: Optional[int] = None,
+              tracer=None, step: int = -1):
         """Parse into fresh numpy columns. ``threads`` > 1 uses the
         chunked multi-threaded kernel (identical output, including
         intern-id assignment order); default: TPUSTREAM_PARSE_THREADS or
-        the core count, engaged only for buffers >= 1 MiB."""
+        the core count, engaged only for buffers >= 1 MiB. With a
+        ``tracer`` (obs/tracing.py) the kernel call records a
+        ``parse.native`` span and the id remap a ``parse.remap`` span,
+        both for ``step``: one span call each per batch, nothing per
+        record."""
         if threads is None:
             try:
                 threads = int(
@@ -261,25 +274,28 @@ class NativeParser:
             cols.append(c)
             ptrs[i] = c.ctypes.data_as(ctypes.c_void_p)
         bad = ctypes.c_int64(0)
-        rows = self._lib.tsp_parse_mt(
-            data,
-            len(data),
-            self.sep,
-            n,
-            self._field,
-            self._kind,
-            self._tz,
-            self._tbl_ptrs,
-            ptrs,
-            max_rows,
-            ctypes.byref(bad),
-            max(1, threads),
-        )
+        span = tracer.span if tracer is not None else _no_span
+        with span("parse.native", step):
+            rows = self._lib.tsp_parse_mt(
+                data,
+                len(data),
+                self.sep,
+                n,
+                self._field,
+                self._kind,
+                self._tz,
+                self._tbl_ptrs,
+                ptrs,
+                max_rows,
+                ctypes.byref(bad),
+                max(1, threads),
+            )
         out = []
-        for c, t in zip(cols, self.tables):
-            c = c[:rows]
-            if t is not None:
-                remap = t.sync()
-                c = remap[c] if len(remap) else c
-            out.append(c)
+        with span("parse.remap", step):
+            for c, t in zip(cols, self.tables):
+                c = c[:rows]
+                if t is not None:
+                    remap = t.sync()
+                    c = remap[c] if len(remap) else c
+                out.append(c)
         return out, int(bad.value)

@@ -39,17 +39,57 @@ The AOT path is also belt-and-braces: if ``lower()``/``compile()``
 raises, the wrapper permanently falls back to counting builds by the
 plain dispatch's wall time — execution semantics are never at risk for
 the sake of a metric. The fallback itself is a flight-recorder event.
+
+With the profiler bridge on, each build also yields the program's
+op-to-scope map (:func:`scope_map`): the ``jax.named_scope`` blocks of
+the step (``STEP_SCOPES``) land in each HLO instruction's
+``op_name`` metadata, so a device trace's per-op events can be summed
+by phase. The map is published on the job's obs as
+``JobObs.op_scopes[<module>]`` (``jit_step``), newest build per module.
 """
 
 from __future__ import annotations
 
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 CAUSE_INITIAL = "initial"
 CAUSE_KEY_GROWTH = "key_capacity_growth"
 CAUSE_BATCH_SHAPE = "batch_shape_change"
 CAUSE_CONFIG = "config_change"
+
+
+# the named scopes the step programs open (runtime/executor.py
+# _counted_step, runtime/window_program.py _step/_sweep), innermost wins
+STEP_SCOPES = ("unpack", "retarget", "scatter", "fire", "sweep", "compact")
+_OP_NAME = 'op_name="'
+
+
+def scope_map(hlo_text: str) -> Tuple[Optional[str], Dict[str, str]]:
+    """``(module, {instruction: scope})`` from one HLO module's text:
+    each instruction whose ``op_name`` metadata runs through one of
+    ``STEP_SCOPES`` maps to the innermost of them. The last component
+    of an ``op_name`` is the primitive itself (``.../scatter`` is an
+    unscoped scatter op), so only the components before it count."""
+    module = None
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        if module is None and line.startswith("HloModule "):
+            module = line.split()[1].rstrip(",")
+            continue
+        i = line.find(_OP_NAME)
+        if i < 0:
+            continue
+        lhs = line.split(" = ", 1)[0].split()
+        if not lhs:
+            continue
+        j = i + len(_OP_NAME)
+        path = line[j:line.find('"', j)].split("/")[:-1]
+        for part in reversed(path):
+            if part in STEP_SCOPES:
+                out[lhs[-1].lstrip("%")] = part
+                break
+    return module, out
 
 
 def _signature(args) -> tuple:
@@ -119,10 +159,14 @@ _MEMORY_FIELDS = (
 class CompileObs:
     """Per-runner compile instrumentation bundle (one per OperatorObs)."""
 
-    def __init__(self, op_obs, flight, meta: Optional[Dict[str, Any]] = None):
+    def __init__(self, op_obs, flight, meta: Optional[Dict[str, Any]] = None,
+                 op_scopes: Optional[dict] = None):
+        """``op_scopes``: the job's ``JobObs.op_scopes`` to publish each
+        build's op-to-scope map into (profiler bridge on), else None."""
         self._obs = op_obs
         self._flight = flight
         self._meta = dict(meta or {})
+        self._op_scopes = op_scopes
         self.compile_count = op_obs.counter("compile_count")
         self.recompile_count = op_obs.counter("recompile_count")
         self.compile_wall_ms = op_obs.histogram("compile_wall_ms")
@@ -172,6 +216,15 @@ class CompileObs:
                     if v is not None:
                         self._obs.gauge(gauge).set(int(v))
                         event[gauge.replace("compile_", "")] = int(v)
+            if self._op_scopes is not None:
+                # the text keeps its metadata on a persistent-cache load
+                # too (checked on the v5e and the CPU, jax 0.9)
+                try:
+                    module, ops = scope_map(compiled.as_text())
+                except Exception:
+                    module = None
+                if module is not None:
+                    self._op_scopes[module] = ops
         self._flight.record("program_compiled", **event)
 
     def record_fallback(self, exc: BaseException, where: str) -> None:

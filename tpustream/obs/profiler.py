@@ -19,6 +19,12 @@ stage and, at every snapshot tick, turns the lookback window into:
   (stages run concurrently); ~1.0 means serialized; far below 1.0 means
   the pipeline is starved (source-bound).
 
+The ``source`` wait and the sub-spans nested inside ``parse``
+(``NON_STAGE_KINDS``) are not stage work: they are reported under
+``waits`` with their own n/ms, and left out of the shares and the
+occupancy, so a parse is not counted twice and a starved pipeline
+still reads as starved.
+
 The ``profile()`` dict feeds the ``profile`` section of
 ``/snapshot.json`` and ``dump --profile``. Everything here is pure
 stdlib over the tracer's ring — no jax, safe for the dump selftest.
@@ -31,7 +37,9 @@ from typing import Dict, Optional
 
 from .registry import NULL_COUNTER, NULL_GAUGE
 from .timeseries import TimeSeries
-from .tracing import SPAN_KINDS
+from .tracing import NON_STAGE_KINDS, SPAN_KINDS
+
+STAGE_KINDS = tuple(k for k in SPAN_KINDS if k not in NON_STAGE_KINDS)
 
 
 class PipelineProfiler:
@@ -59,18 +67,18 @@ class PipelineProfiler:
             self._dropped_counter = group.counter("profile_spans_dropped")
             self._share_gauges = {
                 k: group.group(stage=k).gauge("profile_stage_share")
-                for k in SPAN_KINDS
+                for k in STAGE_KINDS
             }
             self._ms_gauges = {
                 k: group.group(stage=k).gauge("profile_stage_ms")
-                for k in SPAN_KINDS
+                for k in STAGE_KINDS
             }
         else:
             self._binding_gauge = NULL_GAUGE
             self._occupancy_gauge = NULL_GAUGE
             self._dropped_counter = NULL_COUNTER
-            self._share_gauges = {k: NULL_GAUGE for k in SPAN_KINDS}
-            self._ms_gauges = {k: NULL_GAUGE for k in SPAN_KINDS}
+            self._share_gauges = {k: NULL_GAUGE for k in STAGE_KINDS}
+            self._ms_gauges = {k: NULL_GAUGE for k in STAGE_KINDS}
 
     # -- ingestion -----------------------------------------------------------
 
@@ -117,6 +125,7 @@ class PipelineProfiler:
         if now is None:
             now = self._clock()
         stages = {}
+        waits = {}
         totals = {}
         t_lo_seen: Optional[float] = None
         t_hi_seen: Optional[float] = None
@@ -126,22 +135,26 @@ class PipelineProfiler:
             pts = ser.points(w, now)
             n = len(pts)
             tot = sum(v for _, v in pts)
-            totals[k] = tot
-            steps = max(steps, n)
-            if n:
-                t_lo_seen = pts[0][0] if t_lo_seen is None else min(t_lo_seen, pts[0][0])
-                t_hi_seen = pts[-1][0] if t_hi_seen is None else max(t_hi_seen, pts[-1][0])
-            stages[k] = {
+            row = {
                 "n": n,
                 "total_ms": round(tot, 6),
                 "mean_ms": round(tot / n, 6) if n else 0.0,
                 "p50_ms": round(ser.quantile(0.5, w, now), 6) if n else 0.0,
                 "p99_ms": round(ser.quantile(0.99, w, now), 6) if n else 0.0,
             }
+            if k in NON_STAGE_KINDS:
+                waits[k] = row
+                continue
+            stages[k] = row
+            totals[k] = tot
+            steps = max(steps, n)
+            if n:
+                t_lo_seen = pts[0][0] if t_lo_seen is None else min(t_lo_seen, pts[0][0])
+                t_hi_seen = pts[-1][0] if t_hi_seen is None else max(t_hi_seen, pts[-1][0])
         total_ms = sum(totals.values())
         binding = ""
         binding_share = 0.0
-        for k in SPAN_KINDS:
+        for k in STAGE_KINDS:
             share = (totals[k] / total_ms) if total_ms > 0 else 0.0
             stages[k]["share"] = round(share, 6)
             if totals[k] > 0 and share > binding_share:
@@ -153,12 +166,12 @@ class PipelineProfiler:
         if binding:
             self._binding_gauge.set(float(SPAN_KINDS.index(binding)))
         self._occupancy_gauge.set(round(occupancy, 6))
-        for k in SPAN_KINDS:
+        for k in STAGE_KINDS:
             self._share_gauges[k].set(stages[k]["share"])
             self._ms_gauges[k].set(stages[k]["total_ms"])
         return {
             "window_s": w,
-            "stage_kinds": list(SPAN_KINDS),
+            "stage_kinds": list(STAGE_KINDS),
             "binding_stage": binding,
             "binding_stage_index": SPAN_KINDS.index(binding) if binding else -1,
             "binding_share": round(binding_share, 6),
@@ -166,4 +179,5 @@ class PipelineProfiler:
             "batch_wall_ms": round(total_ms / steps, 6) if steps else 0.0,
             "spans_dropped": self.dropped,
             "stages": stages,
+            "waits": waits,
         }

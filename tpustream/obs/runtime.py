@@ -128,6 +128,10 @@ class JobObs:
             )
         self.snapshotter.profiler = self.profiler
         self._op_names: dict = {}
+        # op-to-scope map of each compiled step program, by HLO module
+        # name (obs/compilation.py scope_map); filled with the profiler
+        # bridge on, so a device trace's ops can be summed by phase
+        self.op_scopes: dict = {}
 
         # environment fingerprint (obs/resources.py): what host/device
         # this job actually ran on, embedded in every snapshot's meta
@@ -474,6 +478,7 @@ class _NullJobObs:
     ledger = None
     resources = None
     env_fingerprint = None
+    op_scopes = None
 
     __slots__ = ()
 

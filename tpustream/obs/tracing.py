@@ -17,20 +17,39 @@ device activity. It is opt-in (``ObsConfig.profiler_bridge``) because
 annotations add a little per-span overhead even when no trace is
 active.
 
+Every span carries the id of the step it serves. A step is numbered
+when its batch is pulled from the source (:meth:`StepTracer.new_step`);
+that batch's ``source``, ``parse``, ``pack``, ``h2d``, ``dispatch`` and
+``emit`` spans carry the one id, a ``fetch`` carries the last step of
+its group, and steps with no batch (deferred fires, end of stream) take
+fresh ids when they are dispatched. ``parse.native`` and
+``parse.remap`` are sub-spans nested inside ``parse`` (the native
+tokenize + C-side intern, and the id remap into the plan's tables).
+Under the bridge each annotation carries ``step=`` (and ``emit`` its
+``rows=``) as trace-event stats, so ring spans and trace events can be
+joined one to one.
+
 ``NULL_TRACER`` is the disabled twin: same surface, no state, no work.
 """
 
 from __future__ import annotations
 
+import itertools
 import time
 from typing import List, Optional
 
 # "lane_parse" is the ingest-lane worker's parse span (runtime/ingest.py
-# re-records it at the merge point with the worker-measured duration) —
-# appended LAST so the profiler's binding-stage gauge keeps its
-# historical index values for the original six stages.
+# re-records it at the merge point with the worker-measured duration);
+# "source" is the executor's wait for its next source batch, and the
+# two "parse." kinds nest inside "parse". New kinds are appended LAST
+# so the profiler's binding-stage gauge keeps its historical index
+# values for the original stages.
 SPAN_KINDS = ("parse", "pack", "h2d", "dispatch", "fetch", "emit",
-              "lane_parse")
+              "lane_parse", "source", "parse.native", "parse.remap")
+# spans that time a wait (source) or part of another span (parse.*):
+# the profiler leaves them out of stage shares and occupancy, so no
+# host time is counted twice and idle time does not read as work
+NON_STAGE_KINDS = ("source", "parse.native", "parse.remap")
 
 
 class _Span:
@@ -48,10 +67,24 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         if self._tracer._annotate is not None:
-            self._ann = self._tracer._annotate(f"tpustream.{self.kind}")
+            name = f"tpustream.{self.kind}"
+            self._ann = (
+                self._tracer._annotate(name, step=self.step)
+                if self.step >= 0 else self._tracer._annotate(name)
+            )
             self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
+
+    def note(self, step: Optional[int] = None, **stats) -> None:
+        """Set the span's step once it is known (a wait learns which
+        step it served when the wait ends), and add stats to the bridged
+        annotation (``rows=`` on ``emit``)."""
+        if step is not None:
+            self.step = step
+            stats["step"] = step
+        if self._ann is not None and stats:
+            self._ann.set_metadata(**stats)
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
@@ -78,6 +111,10 @@ class StepTracer:
         self._pos = 0
         self.total_spans = 0
         self._epoch = time.perf_counter()
+        # step ids: drawn by the source pull and by runners dispatching
+        # a step that no pulled batch numbered (count's next() is atomic
+        # under the GIL, so the parse-ahead thread may draw too)
+        self._step_ids = itertools.count(1)
         # span-drop accounting, wired by JobObs post-construction: a
         # Counter incremented per overwritten span, and a one-shot
         # callable fired on the FIRST drop (flight breadcrumb) so ring
@@ -95,6 +132,15 @@ class StepTracer:
 
     def span(self, kind: str, step: int = -1, operator: str = "") -> _Span:
         return _Span(self, kind, step, operator)
+
+    def new_step(self) -> int:
+        """A fresh step id (1-based, unique within the job)."""
+        return next(self._step_ids)
+
+    @property
+    def bridged(self) -> bool:
+        """Whether spans also enter the profiler's trace."""
+        return self._annotate is not None
 
     def _record(self, kind: str, step: int, operator: str, t0: float, dur: float) -> None:
         ev = (kind, step, operator, t0 - self._epoch, dur)
@@ -157,6 +203,9 @@ class _NullSpan:
     def __enter__(self):
         return self
 
+    def note(self, step=None, **stats) -> None:
+        pass
+
     def __exit__(self, *exc):
         pass
 
@@ -170,6 +219,7 @@ class _NullTracer:
     step."""
 
     enabled = False
+    bridged = False
     capacity = 0
     total_spans = 0
     epoch = 0.0
@@ -178,6 +228,9 @@ class _NullTracer:
 
     def span(self, kind: str, step: int = -1, operator: str = "") -> _NullSpan:
         return _NULL_SPAN
+
+    def new_step(self) -> int:
+        return -1
 
     def raw_tail(self, n: int) -> list:
         return []

@@ -28,6 +28,7 @@ from ..config import StreamConfig
 from ..hostparse import PlanEvaluator, run_fallback_map
 from ..records import STR, Batch, Column, DerivedKeyTable, StringTable
 from ..api.timeapi import TimeCharacteristic
+from ..obs.tracing import NULL_TRACER
 from .metrics import Metrics, Stopwatch
 from .plan import JobPlan, build_plan_chain
 from .sinks import (
@@ -59,6 +60,9 @@ class HostStage:
         # nothing, so quarantine reprocessing can't double-count);
         # account_source drains it per batch. None = ledger off.
         self.ledger_counts: Optional[dict] = None
+        # step tracer (obs/tracing.py) for the native parse's sub-spans;
+        # the executor installs the job's (NULL_TRACER when obs is off)
+        self.tracer = None
         if plan.ts_expr is not None:
             self._ts_eval = PlanEvaluator([plan.ts_expr], [None])
 
@@ -95,10 +99,12 @@ class HostStage:
         ev = PlanEvaluator(exprs, tbls)
         return ev if ev._native is not None else None
 
-    def process_raw(self, raw: bytes, n: int, proc_ts: np.ndarray):
+    def process_raw(self, raw: bytes, n: int, proc_ts: np.ndarray,
+                    step: int = -1):
         """Raw-buffer twin of :meth:`process`. Returns (Batch, wm_hint)
         or (None, None) when the native lane can't parse this batch —
-        the caller then decodes and takes the line path."""
+        the caller then decodes and takes the line path. ``step`` is the
+        id the batch's ``parse.*`` sub-spans carry."""
         if not n:
             return None, None
         if not self._raw_eval_built:
@@ -106,7 +112,8 @@ class HostStage:
             self._raw_eval_built = True
         if self._raw_eval is None:
             return None, None
-        cols = self._raw_eval.parse_bytes(raw, n)
+        cols = self._raw_eval.parse_bytes(raw, n, tracer=self.tracer,
+                                          step=step)
         if cols is None:
             return None, None
         ts = None
@@ -455,7 +462,9 @@ class Runner:
         # {job, operator} plus span minting. The null twin (obs disabled)
         # makes every obs call below a no-op attribute call.
         self.obs = metrics.job_obs.operator(self.program.operator_name)
-        self._step_idx = 0
+        # step ids (obs/tracing.py): a fed batch brings the id its source
+        # pull drew; a step with no pulled batch draws a fresh one here
+        self._new_step = self.obs.tracer.new_step
         # why the NEXT _counted_step build happens (obs/compilation.py
         # causes); rebuild sites overwrite this before nulling self.step
         self._recompile_cause = "initial"
@@ -628,6 +637,10 @@ class Runner:
                     "describe",
                     dict,
                 )(),
+                op_scopes=(
+                    metrics.job_obs.op_scopes
+                    if self.obs.tracer.bridged else None
+                ),
             )
             # HBM state accounting + key-cardinality/skew gauges
             self._state_mem = StateMemoryTracker(self)
@@ -1140,7 +1153,10 @@ class Runner:
         return jax.process_index() * local_shards * per_shard
 
     def feed(self, batch: Batch, wm_lower: int, t_batch: Optional[float] = None,
-             markers=None):
+             markers=None, step: Optional[int] = None):
+        """Run ``batch`` through the step, one step per ``batch_size``
+        rows. ``step`` is the id the source pull drew for the batch; its
+        first sub-batch carries it, later ones draw fresh ids."""
         cfg = self.cfg
         if markers:
             self._pending_markers.extend(markers)
@@ -1171,18 +1187,18 @@ class Runner:
                 valid=batch.valid[start : start + cfg.batch_size],
             )
             padded = sub.pad_to(cfg.batch_size)
+            sid = step if start == 0 and step is not None else self._new_step()
             t0p = time.perf_counter() if traced is not None else 0.0
-            with self.obs.span("pack", self._step_idx + 1):
+            with self.obs.span("pack", sid):
                 inputs = self._device_inputs(
                     padded, self.plan.time_characteristic
                 )
             if traced is not None:
                 dur = time.perf_counter() - t0p
                 for m in traced:
-                    m.add_span("pack", t0=t0p, dur=dur,
-                               step=self._step_idx + 1)
+                    m.add_span("pack", t0=t0p, dur=dur, step=sid)
                 traced = None
-            self._stage_step(inputs, wm_lower, t_batch)
+            self._stage_step(inputs, wm_lower, t_batch, sid)
             if self.count_input:
                 self.metrics.records_in += int(sub.n)
                 self.obs.records_in.inc(int(sub.n))
@@ -1207,7 +1223,8 @@ class Runner:
             + 8 * (len(bases) + 1)
         )
 
-    def _stage_step(self, inputs, wm_lower: int, t_batch=None):
+    def _stage_step(self, inputs, wm_lower: int, t_batch=None,
+                    step: Optional[int] = None):
         """Run one packed batch through the upload side of the pipeline:
         at h2d_depth 1 (or when staging is disabled) the step runs
         immediately and the transfer rides the dispatch; deeper, the
@@ -1217,7 +1234,7 @@ class Runner:
         if self.obs.enabled:
             self._h2d_bytes.inc(self._wire_nbytes(inputs))
         if not self._h2d_ahead:
-            self._run_step(inputs, wm_lower, t_batch)
+            self._run_step(inputs, wm_lower, t_batch, step=step)
             return
         packed, bases, valid, ts_p, ts_b = inputs
         traced = (
@@ -1225,7 +1242,7 @@ class Runner:
             if self._pending_markers else ()
         )
         t0h = time.perf_counter() if traced else 0.0
-        with self.obs.span("h2d", self._step_idx + len(self._upload_q) + 1):
+        with self.obs.span("h2d", step):
             put = (
                 jax.device_put
                 if self._h2d_sharding is None
@@ -1244,7 +1261,8 @@ class Runner:
         else:
             markers = None
         self._upload_q.append(
-            ((packed, bases, valid, ts_p, ts_b), wm_lower, t_batch, markers)
+            ((packed, bases, valid, ts_p, ts_b), wm_lower, t_batch, markers,
+             step)
         )
         while len(self._upload_q) > self._h2d_ahead:
             self._pop_upload()
@@ -1264,10 +1282,10 @@ class Runner:
         return jax.tree_util.tree_map(put, tree)
 
     def _pop_upload(self):
-        inputs, wm_lower, t_batch, markers = self._upload_q.pop(0)
+        inputs, wm_lower, t_batch, markers, step = self._upload_q.pop(0)
         self._run_step(
             inputs, wm_lower, t_batch,
-            markers=() if markers is None else markers,
+            markers=() if markers is None else markers, step=step,
         )
 
     def _flush_uploads(self):
@@ -1393,12 +1411,16 @@ class Runner:
             return comp
 
         def step(state, packed, bases, valid, ts_p, ts_b, wm_lower):
-            cols = tuple(
-                expand(p, b, m) for p, b, m in zip(packed, bases, col_modes)
-            )
-            if valid_mode == "bits":
-                valid = unpack_bits(valid)
-            ts = expand(ts_p, ts_b, ts_mode)
+            # named scopes label the step's phases in the compiled
+            # program's op metadata (obs/compilation.py op_scopes)
+            with jax.named_scope("unpack"):
+                cols = tuple(
+                    expand(p, b, m)
+                    for p, b, m in zip(packed, bases, col_modes)
+                )
+                if valid_mode == "bits":
+                    valid = unpack_bits(valid)
+                ts = expand(ts_p, ts_b, ts_mode)
             state, em = inner(state, cols, valid, ts, wm_lower)
             counts = {}
             for name, stream in em.items():
@@ -1413,7 +1435,8 @@ class Runner:
                         continue
                     if name == "main" and skip_main_compact:
                         continue
-                    compact[name] = compact_stream(stream)
+                    with jax.named_scope("compact"):
+                        compact[name] = compact_stream(stream)
             return state, em, counts, compact
 
         if self._compile_obs is not None:
@@ -1425,11 +1448,14 @@ class Runner:
             )
         return jax.jit(step, donate_argnums=0)
 
-    def _run_step(self, inputs, wm_lower: int, t_batch=None, markers=None):
+    def _run_step(self, inputs, wm_lower: int, t_batch=None, markers=None,
+                  step: Optional[int] = None):
         """One jitted step + emission dispatch (the only step call site).
 
         ``markers`` is the staged-upload path handing over the markers it
-        detached at stage time; None means take the pending ones here."""
+        detached at stage time; None means take the pending ones here.
+        ``step`` is the id of a fed batch; a step with no batch (clock
+        tick, deferred fires, end of stream) draws a fresh one."""
         self._ensure_step()
         if self._fault is not None:
             self._fault("device_step")
@@ -1445,9 +1471,9 @@ class Runner:
             packed = tuple(self._gshard(p) for p in packed)
             valid = self._gshard(valid)
             ts_p = self._gshard(ts_p)
-        self._step_idx += 1
+        sid = step if step is not None else self._new_step()
         self._flight.set_active(self.obs.name or self.program.operator_name)
-        with self.obs.span("dispatch", self._step_idx):
+        with self.obs.span("dispatch", sid):
             with Stopwatch() as sw:
                 self.state, emissions, counts, compact = self.step(
                     self.state, packed, bases, valid, ts_p, ts_b,
@@ -1477,11 +1503,11 @@ class Runner:
             if getattr(m, "trace_id", 0):
                 m.add_span(
                     "device_step", t0=sw.t0, dur=sw.elapsed,
-                    step=self._step_idx,
+                    step=sid,
                     operator=self.obs.name or self.program.operator_name,
                 )
         self._inflight.append(
-            (emissions, counts, compact, t_batch, step_markers)
+            (emissions, counts, compact, t_batch, step_markers, sid)
         )
         self.obs.inflight.set(len(self._inflight))
         while len(self._inflight) > self._max_inflight:
@@ -2011,14 +2037,16 @@ class Runner:
         return self._slice_stream(main, b, cap), b
 
     def _finish_group(self, entries):
-        # the blocking waits live here, not in _run_step (dispatch is
-        # async) — time them into step_times_s so summary()'s
-        # device_time_s still reflects device + transfer occupancy.
+        # the blocking wait on the device lives here, not in _run_step
+        # (dispatch is async): the ``fetch`` span times it, one span per
+        # group, carrying the group's last step. step_times_s gets the
+        # wait divided over the group's entries, beside the dispatch
+        # walls _run_step appends, so it is not the device's time.
         # All entries' count scalars fetch in ONE device_get (one link
         # round trip however many steps the group covers), then all
         # still-needed emission streams fetch in a second one; dispatch
         # order is unchanged.
-        with self.obs.span("fetch", self._step_idx), Stopwatch() as sw:
+        with self.obs.span("fetch", entries[-1][5]), Stopwatch() as sw:
             spec, spec_rows = self._speculative_main(entries)
             if spec is not None:
                 cnts0, spec_fetched = jax.device_get(
@@ -2026,10 +2054,10 @@ class Runner:
                 )
                 cnts_list = [cnts0]
             else:
-                cnts_list = jax.device_get([c for _, c, _, _, _ in entries])
+                cnts_list = jax.device_get([e[1] for e in entries])
             fetches = [
                 self._plan_fetch(em, comp, cnts)
-                for (em, _, comp, _, _), cnts in zip(entries, cnts_list)
+                for (em, _, comp, *_), cnts in zip(entries, cnts_list)
             ]
             pre_fetched: List[dict] = [{} for _ in fetches]
             if self._spec_eligible(entries):
@@ -2067,7 +2095,7 @@ class Runner:
                 if getattr(m, "trace_id", 0):
                     m.add_span("fetch", t0=sw.t0, dur=sw.elapsed,
                                group=len(entries))
-            self._dispatch(fetched, entry[3])
+            self._dispatch(fetched, entry[3], entry[5])
             if entry[4]:
                 self._record_markers(entry[4])
 
@@ -2250,11 +2278,15 @@ class Runner:
 
         return sel, take, j_valid
 
-    def _dispatch(self, emissions, t_batch=None):
-        with self.obs.span("emit", self._step_idx):
-            self._dispatch_inner(emissions, t_batch)
+    def _dispatch(self, emissions, t_batch=None, step: int = -1):
+        with self.obs.span("emit", step) as sp:
+            rows = self._dispatch_inner(emissions, t_batch)
+            if self.obs.enabled:
+                sp.note(rows=rows)
 
-    def _dispatch_inner(self, emissions, t_batch=None):
+    def _dispatch_inner(self, emissions, t_batch=None) -> int:
+        """Hand one step's fetched emissions to the sinks (or the next
+        stage); returns the rows the sinks received."""
         # step epoch for host-evaluated fire ordering: the per-step
         # dispatch sequence is SPMD-identical across processes (the
         # fetch decision keys on GLOBAL emission counts), so it is a
@@ -2360,6 +2392,7 @@ class Runner:
             self.metrics.emit_latencies_s.append(
                 time.perf_counter() - t_batch
             )
+        return emitted_delta
 
     def _dispatch_late(self, late):
         # late-drop COUNTING happens on device (state["late_dropped"], so
@@ -2536,13 +2569,36 @@ def _make_runner_chain(plans, cfg, metrics, lazy_schemas=None) -> Runner:
     return runner
 
 
-def _prefetch_iter(it, depth: int, depth_gauge=None):
+def _pulled(source_batches, tracer, timed: bool = True):
+    """Number each batch pulled from the source with a fresh step id
+    (``SourceBatch.step``). With ``timed`` the pull itself is the
+    executor's wait for the batch: a ``source`` span for that step,
+    whose duration lands in ``SourceBatch.wait_s``."""
+    it = iter(source_batches)
+    while True:
+        sid = tracer.new_step()
+        if timed:
+            with tracer.span("source", sid), Stopwatch() as sw:
+                sb = next(it, None)
+        else:
+            sb = next(it, None)
+        if sb is None:
+            return
+        sb.step = sid
+        if timed:
+            sb.wait_s = sw.elapsed
+        yield sb
+
+
+def _prefetch_iter(it, depth: int, depth_gauge=None, tracer=NULL_TRACER):
     """Drain ``it`` on a daemon thread into a bounded queue (size =
     ``depth``): the producer blocks when the consumer falls behind
     (bounded memory, natural backpressure), and producer exceptions
     re-raise at the consumer. Used for StreamConfig.parse_ahead.
     ``depth_gauge`` (obs) reads the queue depth at snapshot time — a
-    full queue means the device loop, not the parser, is the bottleneck."""
+    full queue means the device loop, not the parser, is the bottleneck.
+    Each queue wait is the ``tracer``'s ``source`` span of the step
+    whose prepared batch it returned (``SourceBatch.wait_s``)."""
     import queue as queue_mod
     import threading
 
@@ -2576,7 +2632,12 @@ def _prefetch_iter(it, depth: int, depth_gauge=None):
     threading.Thread(target=run, daemon=True).start()
     try:
         while True:
-            kind, payload = q.get()
+            with tracer.span("source") as sp, Stopwatch() as sw:
+                kind, payload = q.get()
+                if kind == "item":
+                    sp.note(step=payload[0].step)
+            if kind == "item":
+                payload[0].wait_s = sw.elapsed
             if kind == "done":
                 return
             if kind == "err":
@@ -2732,6 +2793,7 @@ def _execute_job(env, sink_nodes) -> JobResult:
     else:
         metrics = Metrics()
         job_obs = metrics.job_obs  # the null twin
+    host.tracer = job_obs.tracer
     # one breadcrumb per resolution clamp (every attempt: the resolved
     # knobs are part of this attempt's story, like config_resolved)
     for note in resolve_notes:
@@ -3053,8 +3115,8 @@ def _execute_job(env, sink_nodes) -> JobResult:
             tenants=tenant_slots or None,
         )
 
-    def _feed_measured(b, wm_low, t0):
-        runner.feed(b, wm_low, t_batch=t0)
+    def _feed_measured(b, wm_low, t0, step=None):
+        runner.feed(b, wm_low, t_batch=t0, step=step)
         if rule_apply_t0[0] is not None and b.n:
             job_obs.histogram("rule_update_propagation_ms").observe(
                 (time.perf_counter() - rule_apply_t0[0]) * 1000.0
@@ -3313,17 +3375,19 @@ def _execute_job(env, sink_nodes) -> JobResult:
                 sb = SourceBatch(
                     [], sb.proc_ts[take:], sb.advance_proc_to, sb.final,
                     raw=rest, n_raw=sb.n_raw - take, markers=sb.markers,
+                    step=sb.step, wait_s=sb.wait_s,
                 )
             else:
                 sb = SourceBatch(
                     sb.lines[take:], sb.proc_ts[take:], sb.advance_proc_to,
-                    sb.final, markers=sb.markers,
+                    sb.final, markers=sb.markers, step=sb.step,
+                    wait_s=sb.wait_s,
                 )
             skip_state[0] -= take
         batch = wm_hint = None
         # parse spans may record from the parse-ahead thread; the
         # tracer's ring append is GIL-safe for this single extra writer
-        with job_obs.tracer.span("parse"), Stopwatch() as hw:
+        with job_obs.tracer.span("parse", sb.step), Stopwatch() as hw:
             if fault is not None:
                 fault("parse")
             try:
@@ -3349,7 +3413,8 @@ def _execute_job(env, sink_nodes) -> JobResult:
 
     def _parse(sb):
         if sb.raw is not None:
-            batch, wm_hint = host.process_raw(sb.raw, sb.n_raw, sb.proc_ts)
+            batch, wm_hint = host.process_raw(sb.raw, sb.n_raw, sb.proc_ts,
+                                              sb.step)
             if batch is None and sb.n_raw:
                 # native lane unavailable: decode and take the line path
                 batch, wm_hint = host.process(_raw_lines(sb), sb.proc_ts)
@@ -3461,7 +3526,6 @@ def _execute_job(env, sink_nodes) -> JobResult:
                 ),
             ),
         )
-    prepared = map(_prepare, source_batches)
     # sharded host ingestion (runtime/ingest.py): lane worker processes
     # parse frames in parallel; the merge point yields the SAME
     # (sb, batch, wm_hint, hw) tuples in sequence order, so everything
@@ -3476,19 +3540,26 @@ def _execute_job(env, sink_nodes) -> JobResult:
             single_process=jax.process_count() == 1,
             fault=fault, skip_lines=skip_lines,
         )
-        if ingest_plane is not None:
-            prepared = ingest_plane.frames(source_batches, _prepare)
-            # per-lane CPU attribution (obs/resources.py): the sampler
-            # re-reads the PID map at every tick, so lane respawns are
-            # tracked without re-attachment
-            resources = getattr(job_obs, "resources", None)
-            if resources is not None:
-                resources.attach_lanes(ingest_plane.lane_pids)
     prefetched = (
         cfg.parse_ahead > 0
         and jax.process_count() == 1
         and ingest_plane is None
     )
+    # every pull numbers its batch's step; the executor's wait for the
+    # batch is the ``source`` span: around the pull itself, or with
+    # parse-ahead around the consumer's queue wait (below)
+    source_batches = _pulled(source_batches, job_obs.tracer,
+                             timed=not prefetched)
+    if ingest_plane is not None:
+        prepared = ingest_plane.frames(source_batches, _prepare)
+        # per-lane CPU attribution (obs/resources.py): the sampler
+        # re-reads the PID map at every tick, so lane respawns are
+        # tracked without re-attachment
+        resources = getattr(job_obs, "resources", None)
+        if resources is not None:
+            resources.attach_lanes(ingest_plane.lane_pids)
+    else:
+        prepared = map(_prepare, source_batches)
     if prefetched:
         # source + parse on their own thread (the reference's source-
         # operator thread): batch N+1 parses while N crosses the link
@@ -3500,6 +3571,7 @@ def _execute_job(env, sink_nodes) -> JobResult:
                 if job_obs.enabled
                 else None
             ),
+            tracer=job_obs.tracer,
         )
 
     for sb, batch, wm_hint, hw in prepared:
@@ -3511,12 +3583,13 @@ def _execute_job(env, sink_nodes) -> JobResult:
         src_gap = (
             now_ref - t_iter_done if t_iter_done is not None else 0.0
         )
-        if src_gap > STALL_GAP_S:
+        if sb.wait_s > STALL_GAP_S:
             # per-incident, not per-batch: a stalled source records one
-            # event per observed gap, bounded by the gap itself
+            # event per observed gap (its ``source`` span), bounded by
+            # the gap itself
             job_obs.flight.record(
-                "source_stall", gap_s=round(src_gap, 3),
-                batches_consumed=metrics.batches,
+                "source_stall", gap_s=round(sb.wait_s, 3),
+                batches_consumed=metrics.batches, step=sb.step,
             )
         if sb.markers:
             for m in sb.markers:
@@ -3580,7 +3653,7 @@ def _execute_job(env, sink_nodes) -> JobResult:
                 marker_backlog = []
             wm_low = wm_lower_for_records(wm_hint)
             if control_feed is None:
-                runner.feed(batch, wm_low, t_batch=hw.t0)
+                runner.feed(batch, wm_low, t_batch=hw.t0, step=sb.step)
             else:
                 # split the batch at each pending update's record
                 # boundary: rows before position N run under the old
@@ -3588,6 +3661,8 @@ def _execute_job(env, sink_nodes) -> JobResult:
                 # and batch-size independent (docs/dynamic_rules.md)
                 base = lines_consumed - sb.n_records
                 cursor = 0
+                # the pulled step id goes with the batch's first slice
+                sid = [sb.step]
                 for off, updates in control_feed.splits_for(
                     base, sb.n_records
                 ):
@@ -3596,13 +3671,15 @@ def _execute_job(env, sink_nodes) -> JobResult:
                     off = min(off, batch.n)
                     if off > cursor:
                         _feed_measured(
-                            batch.slice_rows(cursor, off), wm_low, hw.t0
+                            batch.slice_rows(cursor, off), wm_low, hw.t0,
+                            sid.pop() if sid else None,
                         )
                         cursor = off
                     _apply_rule_updates(updates)
                 rest = batch.slice_rows(cursor, batch.n) if cursor else batch
                 if rest.n or not cursor:
-                    _feed_measured(rest, wm_low, hw.t0)
+                    _feed_measured(rest, wm_low, hw.t0,
+                                   sid.pop() if sid else None)
             if idle:
                 runner.drain_inflight()
         elif (

@@ -827,7 +827,7 @@ class IngestPlane:
                 f"got {dseq}"
             )
         job_obs = self._job_obs
-        with job_obs.tracer.span("parse"), Stopwatch() as hw:
+        with job_obs.tracer.span("parse", sb.step), Stopwatch() as hw:
             if self._fault is not None:
                 self._fault("parse")
             payload = inc.out_ring.read(off, nbytes)
@@ -861,7 +861,7 @@ class IngestPlane:
             # ingest plane
             now = time.perf_counter()
             job_obs.tracer._record(
-                "lane_parse", -1, f"lane{lane.idx}", now - dur, dur
+                "lane_parse", sb.step, f"lane{lane.idx}", now - dur, dur
             )
             if trace_ids and sb.markers:
                 # attribute the worker-side parse to the flight-path

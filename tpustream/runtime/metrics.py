@@ -128,9 +128,6 @@ class Metrics:
             "cep_timeouts": self.cep_timeouts,
             "device_time_s": total_step,
             "host_time_s": sum(self.host_times_s),
-            "events_per_sec_device": (
-                self.records_in / total_step if total_step > 0 else None
-            ),
             "emit_latency_p50_ms": _percentile(lat, 0.50) * 1000.0,
             "emit_latency_p99_ms": _percentile(lat, 0.99) * 1000.0,
         }

@@ -75,6 +75,11 @@ class SourceBatch:
     markers: Optional[list] = None      # obs LatencyMarkers riding this
                                         # batch (None unless the obs
                                         # stamper is installed)
+    # set by the executor when it pulls the batch: the step id its
+    # spans carry (obs/tracing.py; -1 with tracing off) and how long
+    # the executor waited for it (its ``source`` span)
+    step: int = -1
+    wait_s: float = 0.0
 
     @property
     def n_records(self) -> int:

@@ -828,22 +828,25 @@ class WindowProgram(BaseProgram):
                     v(jnp.zeros((), dtype=jnp.int64)),
                 )
 
-            planes2, cnt2, slot_pane2, ev = jax.lax.cond(
-                hi_next > hi_cur, do_rt, no_rt, operand=None
-            )
+            with jax.named_scope("retarget"):
+                planes2, cnt2, slot_pane2, ev = jax.lax.cond(
+                    hi_next > hi_cur, do_rt, no_rt, operand=None
+                )
             smask = unscat & (pane <= hi_next)
-            planes2, cnt2, touched = self._scatter_words(
-                planes2, cnt2, keys, mid_cols, smask, pane
-            )
+            with jax.named_scope("scatter"):
+                planes2, cnt2, touched = self._scatter_words(
+                    planes2, cnt2, keys, mid_cols, smask, pane
+                )
             is_final = hi_next >= hi_target
             wm_eff = jnp.where(
                 is_final, wm_new, jnp.minimum(wm_new, hi_next * g - 1)
             )
-            emission, ft2, pending = self._fire_dense(
-                planes2, cnt2, slot_pane2, hi_next, wm_old, wm_eff, ft,
-                touched, emission_carry=emission, budget_on=is_final,
-                defer_on=is_final & drain,
-            )
+            with jax.named_scope("fire"):
+                emission, ft2, pending = self._fire_dense(
+                    planes2, cnt2, slot_pane2, hi_next, wm_old, wm_eff, ft,
+                    touched, emission_carry=emission, budget_on=is_final,
+                    defer_on=is_final & drain,
+                )
             return (
                 jnp.asarray(False), hi_next, hi_next, planes2, cnt2,
                 slot_pane2, ft2, evicted + ev, emission, pending,
@@ -964,16 +967,22 @@ class WindowProgram(BaseProgram):
                     pane_ops.vary(jnp.zeros((), dtype=jnp.int64), self.vary_axes),
                 )
 
-            planes2, cnt2, slot_pane, evicted = jax.lax.cond(
-                hi > state["hi"], do_retarget, skip_retarget, operand=None
-            )
-            planes2, cnt2, touched = self._scatter_words(
-                planes2, cnt2, keys, mid_cols, live, pane
-            )
-            emission, new_ft, n_pending = self._fire_dense(
-                planes2, cnt2, slot_pane, hi, wm_old, wm_new, ft0, touched,
-                defer_on=drain,
-            )
+            # named scopes label the device ops of each phase in the
+            # compiled program's metadata (obs/compilation.py maps them)
+            with jax.named_scope("retarget"):
+                planes2, cnt2, slot_pane, evicted = jax.lax.cond(
+                    hi > state["hi"], do_retarget, skip_retarget,
+                    operand=None,
+                )
+            with jax.named_scope("scatter"):
+                planes2, cnt2, touched = self._scatter_words(
+                    planes2, cnt2, keys, mid_cols, live, pane
+                )
+            with jax.named_scope("fire"):
+                emission, new_ft, n_pending = self._fire_dense(
+                    planes2, cnt2, slot_pane, hi, wm_old, wm_new, ft0,
+                    touched, defer_on=drain,
+                )
             return (
                 planes2, cnt2, slot_pane, new_ft, evicted,
                 emission, n_pending,
@@ -981,11 +990,12 @@ class WindowProgram(BaseProgram):
 
         def sweep_path(op):
             planes, cnt = op
-            return self._sweep(
-                planes, cnt, state["slot_pane"], hi, ft0,
-                wm_old, wm_new, keys, mid_cols, live, pane, init_leaves,
-                drain,
-            )
+            with jax.named_scope("sweep"):
+                return self._sweep(
+                    planes, cnt, state["slot_pane"], hi, ft0,
+                    wm_old, wm_new, keys, mid_cols, live, pane, init_leaves,
+                    drain,
+                )
 
         (
             planes, cnt, slot_pane, new_ft, evicted,
