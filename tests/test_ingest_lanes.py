@@ -307,7 +307,7 @@ def test_merge_remap_grow_array():
     """The merge-side lane->global remap appends into a grow-by-doubling
     int32 array and gathers through the live prefix (a plain list would
     re-materialize O(all strings) per frame — quadratic over a stream)."""
-    from tpustream.runtime.ingest import _Remap
+    from tpustream.records import _Remap
 
     r = _Remap()
     expect = []

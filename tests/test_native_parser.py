@@ -80,6 +80,75 @@ def test_native_id_namespace_shared_with_python_interning():
     assert int(cols[0][0]) == 0  # remapped onto the existing python id
 
 
+_LITERALS = ["lit-a", "k3", "lit-b"]
+
+
+@pytest.mark.parametrize(
+    "batches",
+    [
+        # new keys in the middle batch, then only seen keys
+        [[f"k{i}" for i in range(20)],
+         [k for i in range(10) for k in (f"k{i}", f"n{i}")],
+         [f"k{i}" for i in range(5, 16)]],
+        # every batch after the first holds seen keys only
+        [[f"k{i}" for i in range(50)],
+         [f"k{i}" for i in reversed(range(50))],
+         [f"k{(7 * i) % 50}" for i in range(50)]],
+        # the remap outgrows its first buffer after columns went out
+        [[f"k{i}" for i in range(10)],
+         [k for i in range(1000) for k in (f"g{i}", f"k{i % 10}")],
+         [f"k{i}" for i in range(10)]],
+        # keys the python side interned first, among new ones
+        [["k3", "lit-b", "x1", "x2"], ["x2", "k3"], ["lit-a", "y", "x1"]],
+    ],
+    ids=["new_keys_mid", "seen_only", "growth", "literals_first"],
+)
+def test_native_remap_grows_in_place(batches):
+    """The native table's remap onto the python ids is extended by the
+    new ids only and gathered through a view of its buffer: per batch,
+    the key column holds the StringTable ids the plain first-appearance
+    rule gives (literals interned python-side first shift them), a
+    batch of seen keys leaves the remap's buffer where it was (no copy
+    of all ids), columns returned earlier keep their values after the
+    buffer grows, and the counter counts exactly the new native ids."""
+    from tpustream.obs.registry import MetricsRegistry
+
+    table = StringTable()
+    for s in _LITERALS:
+        table.intern(s)
+    new_ids = MetricsRegistry().group().counter("intern_new_ids_total")
+    p = native.NativeParser(
+        " ", [(1, native.KIND_STR, 0), (2, native.KIND_I64, 0)], [table, None]
+    )
+    (nt,) = [t for t in p.tables if t is not None]
+    want_ids = {s: i for i, s in enumerate(_LITERALS)}
+    native_seen: set = set()
+    returned = []
+    for keys in batches:
+        data = "\n".join(f"{i} {k} {3 * i}" for i, k in enumerate(keys))
+        before = nt.sync()
+        counted = new_ids.value
+        (ids, vals), bad = p.parse(data.encode(), len(keys), threads=1,
+                                   new_ids=new_ids)
+        after = nt.sync()
+        fresh = [k for k in dict.fromkeys(keys) if k not in native_seen]
+        native_seen.update(fresh)
+        for k in keys:
+            want_ids.setdefault(k, len(want_ids))
+        want = np.array([want_ids[k] for k in keys], dtype=np.int32)
+        assert bad == 0
+        assert ids.dtype == np.int32 and np.array_equal(ids, want)
+        assert table.lookup_many(ids) == keys
+        assert np.array_equal(vals, 3 * np.arange(len(keys)))
+        assert new_ids.value - counted == len(fresh)
+        if not fresh:
+            assert np.shares_memory(before, after)
+        returned.append((ids, want))
+        for got, w in returned:
+            assert np.array_equal(got, w)
+    assert len(nt) == len(native_seen) == new_ids.value
+
+
 def test_native_parser_throughput():
     from tpustream.jobs.chapter1_threshold import parse
 

@@ -127,6 +127,13 @@ def test_fetch_carries_the_last_step_of_its_group(traced):
     assert emitted == dispatched  # each step emits once, in order
 
 
+def test_intern_counter_counts_each_channel_once(traced):
+    """``intern_new_ids_total`` counts native ids appended to the remap:
+    each channel once, however many batches carry it again."""
+    _, job_obs, _ = traced
+    assert job_obs.counter("intern_new_ids_total").value == 6
+
+
 def test_parse_sub_spans_nest_inside_parse(traced):
     _, job_obs, _ = traced
     spans = spans_of(job_obs)

@@ -338,15 +338,16 @@ class PlanEvaluator:
         return va // vb
 
     def parse_bytes(self, data: bytes, n_lines: int, tracer=None,
-                    step: int = -1) -> Optional[List[np.ndarray]]:
+                    step: int = -1, new_ids=None) -> Optional[List[np.ndarray]]:
         """Native path over a raw newline-separated buffer; None if the
         native parser is unavailable for this plan. ``tracer``/``step``
         time the native call and the id remap as sub-spans of the
-        caller's ``parse`` span (:meth:`NativeParser.parse`)."""
+        caller's ``parse`` span; ``new_ids`` counts the native ids
+        interned (:meth:`NativeParser.parse`)."""
         if self._native is None:
             return None
         base_vals, bad = self._native.parse(data, n_lines, tracer=tracer,
-                                            step=step)
+                                            step=step, new_ids=new_ids)
         if bad or len(base_vals[0]) != n_lines:
             # bad fields zero-fill in the C kernel rather than raise, so
             # a batch with ANY malformed line must take the strict python

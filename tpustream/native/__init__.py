@@ -19,6 +19,8 @@ from typing import List, Optional
 
 import numpy as np
 
+from ..records import _Remap
+
 KIND_STR = 0
 KIND_F64 = 1
 KIND_I64 = 2
@@ -189,7 +191,7 @@ class NativeTable:
         self._lib = lib
         self.ptr = lib.tsp_table_new()
         self.py_table = py_table
-        self._remap: List[int] = []
+        self._remap = _Remap()
 
     def __del__(self):  # pragma: no cover - interpreter teardown
         try:
@@ -197,18 +199,26 @@ class NativeTable:
         except Exception:
             pass
 
+    def __len__(self) -> int:
+        return len(self._remap)
+
     def sync(self) -> np.ndarray:
-        """Extend the remap for newly-interned native ids; returns the
-        int32 remap array (native id -> python id)."""
+        """Extend the remap for the native ids interned since the last
+        call; returns the int32 remap (native id -> python id) as a view
+        of the table's buffer, so a batch of seen keys costs no work
+        proportional to the table's size."""
         lib = self._lib
         n = lib.tsp_table_size(self.ptr)
-        if n > len(self._remap):
+        have = len(self._remap)
+        if n > have:
             buf = ctypes.create_string_buffer(4096)
-            for i in range(len(self._remap), n):
+            ids = []
+            for i in range(have, n):
                 ln = lib.tsp_table_get(self.ptr, i, buf, 4096)
                 s = buf.raw[: min(ln, 4096)].decode("utf-8", "replace")
-                self._remap.append(self.py_table.intern(s))
-        return np.asarray(self._remap, dtype=np.int32)
+                ids.append(self.py_table.intern(s))
+            self._remap.extend(ids)
+        return self._remap.view()
 
 
 _NO_SPAN = contextlib.nullcontext()
@@ -242,7 +252,7 @@ class NativeParser:
         )
 
     def parse(self, data: bytes, max_rows: int, threads: Optional[int] = None,
-              tracer=None, step: int = -1):
+              tracer=None, step: int = -1, new_ids=None):
         """Parse into fresh numpy columns. ``threads`` > 1 uses the
         chunked multi-threaded kernel (identical output, including
         intern-id assignment order); default: TPUSTREAM_PARSE_THREADS or
@@ -250,7 +260,8 @@ class NativeParser:
         ``tracer`` (obs/tracing.py) the kernel call records a
         ``parse.native`` span and the id remap a ``parse.remap`` span,
         both for ``step``: one span call each per batch, nothing per
-        record."""
+        record. ``new_ids`` (an obs counter) is bumped once per batch by
+        the native ids this batch appended to the remaps."""
         if threads is None:
             try:
                 threads = int(
@@ -291,11 +302,16 @@ class NativeParser:
                 max(1, threads),
             )
         out = []
+        added = 0
         with span("parse.remap", step):
             for c, t in zip(cols, self.tables):
                 c = c[:rows]
                 if t is not None:
+                    have = len(t)
                     remap = t.sync()
+                    added += len(remap) - have
                     c = remap[c] if len(remap) else c
                 out.append(c)
+        if new_ids is not None:
+            new_ids.inc(added)
         return out, int(bad.value)

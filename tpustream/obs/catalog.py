@@ -31,6 +31,8 @@ KNOWN_SERIES = frozenset({
     "pipeline_occupancy", "parse_ahead_queue_depth", "source_queue_depth",
     "chain_buffer_entries", "exchange_buffer_bytes", "exchange_capacity_rows",
     "compaction_ratio", "compaction_spills", "latency_markers_emitted",
+    # native parse (native/): ids appended to the intern remap
+    "intern_new_ids_total",
     # sharded ingestion (runtime/ingest.py), lane-labelled
     "ingest_lane_records_total", "ingest_ring_occupancy",
     "ingest_lane_stall_ms",

@@ -71,6 +71,43 @@ class StringTable:
         self._to_id = {s: i for i, s in enumerate(self._to_str)}
 
 
+class _Remap:
+    """Foreign intern id -> StringTable id (an ingest lane's or the
+    native parser's table onto the plan's), kept as a grow-by-doubling
+    int32 array so the per-batch gather indexes a live prefix. A plain
+    list re-materialized with np.asarray per batch would be O(total
+    strings interned) per batch per str column — quadratic over a
+    long-running stream with a growing intern table.
+
+    ``view()`` is the buffer's live prefix, not a copy: gather through
+    it (``view()[ids]`` makes a fresh array); a view taken before an
+    ``extend`` does not see the ids it appends."""
+
+    __slots__ = ("_buf", "_n")
+
+    def __init__(self):
+        self._buf = np.empty(256, dtype=np.int32)
+        self._n = 0
+
+    def __len__(self) -> int:
+        return self._n
+
+    def extend(self, ids) -> None:
+        m = len(ids)
+        if self._n + m > self._buf.shape[0]:
+            cap = self._buf.shape[0]
+            while cap < self._n + m:
+                cap *= 2
+            buf = np.empty(cap, dtype=np.int32)
+            buf[: self._n] = self._buf[: self._n]
+            self._buf = buf
+        self._buf[self._n : self._n + m] = ids
+        self._n += m
+
+    def view(self) -> np.ndarray:
+        return self._buf[: self._n]
+
+
 class DerivedKeyTable(StringTable):
     """Intern table for COMPUTED KeySelector results (a selector that
     derives a key rather than projecting a field). Values intern under

@@ -60,9 +60,11 @@ class HostStage:
         # nothing, so quarantine reprocessing can't double-count);
         # account_source drains it per batch. None = ledger off.
         self.ledger_counts: Optional[dict] = None
-        # step tracer (obs/tracing.py) for the native parse's sub-spans;
-        # the executor installs the job's (NULL_TRACER when obs is off)
+        # step tracer (obs/tracing.py) for the native parse's sub-spans
+        # and the intern_new_ids_total counter; the executor installs
+        # the job's (null twins when obs is off)
         self.tracer = None
+        self.new_ids = None
         if plan.ts_expr is not None:
             self._ts_eval = PlanEvaluator([plan.ts_expr], [None])
 
@@ -113,7 +115,7 @@ class HostStage:
         if self._raw_eval is None:
             return None, None
         cols = self._raw_eval.parse_bytes(raw, n, tracer=self.tracer,
-                                          step=step)
+                                          step=step, new_ids=self.new_ids)
         if cols is None:
             return None, None
         ts = None
@@ -2794,6 +2796,7 @@ def _execute_job(env, sink_nodes) -> JobResult:
         metrics = Metrics()
         job_obs = metrics.job_obs  # the null twin
     host.tracer = job_obs.tracer
+    host.new_ids = job_obs.counter("intern_new_ids_total")
     # one breadcrumb per resolution clamp (every attempt: the resolved
     # knobs are part of this attempt's story, like config_resolved)
     for note in resolve_notes:

@@ -72,7 +72,7 @@ from typing import Iterator, List, Optional
 import numpy as np
 
 from ..parallel.lanes import LaneSpec, ShmRing, spawn_lane, unpack_columns
-from ..records import STR, Batch, Column
+from ..records import STR, Batch, Column, _Remap
 from .metrics import Stopwatch
 from .watchdog import IngestStallError, StallWatchdog
 
@@ -87,35 +87,6 @@ _MAX_AHEAD_FRAMES = 4
 #: fault points forwarded into lane workers (mirrors
 #: testing/faults.py LANE_FAULT_POINTS without importing the test module)
 _LANE_FAULT_POINTS = ("lane_worker_crash", "lane_worker_hang")
-
-
-class _Remap:
-    """Lane-local string id -> global plan id, kept as a grow-by-doubling
-    int32 array so the per-frame gather indexes a live prefix. A plain
-    list re-materialized with np.asarray per frame would be O(total
-    strings interned) per frame per str column — quadratic over a
-    long-running stream with a growing intern table."""
-
-    __slots__ = ("_buf", "_n")
-
-    def __init__(self):
-        self._buf = np.empty(256, dtype=np.int32)
-        self._n = 0
-
-    def extend(self, ids) -> None:
-        m = len(ids)
-        if self._n + m > self._buf.shape[0]:
-            cap = self._buf.shape[0]
-            while cap < self._n + m:
-                cap *= 2
-            buf = np.empty(cap, dtype=np.int32)
-            buf[: self._n] = self._buf[: self._n]
-            self._buf = buf
-        self._buf[self._n : self._n + m] = ids
-        self._n += m
-
-    def view(self) -> np.ndarray:
-        return self._buf[: self._n]
 
 
 class LaneRestartPolicy:
