@@ -60,8 +60,10 @@ CAUSE_CONFIG = "config_change"
 
 
 # the named scopes the step programs open (runtime/executor.py
-# _counted_step, runtime/window_program.py _step/_sweep), innermost wins
-STEP_SCOPES = ("unpack", "retarget", "scatter", "fire", "sweep", "compact")
+# _counted_step, runtime/window_program.py _step/_sweep, and on a mesh
+# runtime/sharded.py's keyBy exchange and all-reduces), innermost wins
+STEP_SCOPES = ("unpack", "retarget", "scatter", "fire", "sweep", "compact",
+               "exchange", "allreduce")
 _OP_NAME = 'op_name="'
 
 

@@ -62,19 +62,23 @@ class _ShardedMixin:
         # all_gather's result stays per-shard to shard_map's type check:
         # gather every shard's value with a psum of one-hot rows, then
         # reduce locally. Exact for every dtype: other rows add zero.
-        rows = jnp.arange(self.n_shards) == jax.lax.axis_index(AXIS)
-        rows = rows.reshape((self.n_shards,) + (1,) * jnp.ndim(x))
-        gathered = jax.lax.psum(jnp.where(rows, x, 0), AXIS)
-        return jnp.max(gathered, axis=0).astype(x.dtype)
+        with jax.named_scope("allreduce"):
+            rows = jnp.arange(self.n_shards) == jax.lax.axis_index(AXIS)
+            rows = rows.reshape((self.n_shards,) + (1,) * jnp.ndim(x))
+            gathered = jax.lax.psum(jnp.where(rows, x, 0), AXIS)
+            return jnp.max(gathered, axis=0).astype(x.dtype)
 
     def _global_sum(self, x):
-        return jax.lax.psum(x, AXIS)
+        with jax.named_scope("allreduce"):
+            return jax.lax.psum(x, AXIS)
 
     def _exchange(self, mid_cols, mask, ts):
         keys = mid_cols[self.key_pos]
-        cols, valid, ts2, ovf = exchange_by_key(
-            list(mid_cols), mask, ts, keys, self.n_shards, self.exchange_capacity
-        )
+        with jax.named_scope("exchange"):
+            cols, valid, ts2, ovf = exchange_by_key(
+                list(mid_cols), mask, ts, keys, self.n_shards,
+                self.exchange_capacity,
+            )
         return cols, valid, ts2, ovf
 
     def _local_keys(self, key_col):
