@@ -446,6 +446,38 @@ def _ledger_contents(sink):
     return None, False
 
 
+class _Inflight:
+    """A dispatched step whose emissions have not reached the sinks yet.
+
+    ``seq`` is the step's place in the runner's dispatch order. ``fetch``
+    stays None until the step's fetch is planned: then it holds the
+    streams still to fetch (count-sized slices launched on the device)
+    and ``fetched`` the rows already on the host; ``wait_s`` is the
+    time the host blocked planning it."""
+
+    __slots__ = ("emissions", "counts", "compact", "t_batch", "markers",
+                 "step", "seq", "fetch", "fetched", "wait_s")
+
+    def __init__(self, emissions, counts, compact, t_batch, markers, step,
+                 seq):
+        self.emissions = emissions
+        self.counts = counts
+        self.compact = compact
+        self.t_batch = t_batch
+        self.markers = markers
+        self.step = step
+        self.seq = seq
+        self.fetch: Optional[dict] = None
+        self.fetched: dict = {}
+        self.wait_s = 0.0
+
+    def finished(self) -> bool:
+        """Whether the step has finished on the device (its outputs,
+        the count scalars among them, are ready): reading its counts
+        does not wait."""
+        return all(c.is_ready() for c in self.counts.values())
+
+
 class Runner:
     """Feeds padded batches through the jitted program and fans emissions
     out to sinks."""
@@ -495,7 +527,8 @@ class Runner:
         # live device state (full-window process()) must stay sync.
         depth = 1 if self.program.emissions_reference_state else cfg.async_depth
         self._max_inflight = max(0, depth - 1)
-        self._inflight: List[tuple] = []
+        self._inflight: List[_Inflight] = []
+        self._dispatched = 0  # steps dispatched so far (_Inflight.seq)
         # end-to-end latency markers (obs/latency.py): markers ride the
         # inflight entries like data, so the source->edge age includes
         # real pipelining delay. Pending markers attach to the NEXT
@@ -505,9 +538,10 @@ class Runner:
         self._pending_markers: List = []
         self._marker_out: List = []
         self._flight = metrics.job_obs.flight
-        # rows of the last firing step's 'main' prefix (speculative
-        # count+emission piggyback fetch, _speculative_main); 0 until
-        # the first firing step establishes a scale
+        # rows of the last firing step's 'main' prefix, one pow2 level
+        # up: the least 'main' slice size (_plan_fetch) and the
+        # speculative count+emission piggyback fetch (_speculative_main);
+        # 0 until the first firing step establishes a scale
         self._prefix_hint = 0
         # -- multi-host (jax.distributed) SPMD --------------------------
         # every process runs this same executor over the same replayed
@@ -605,6 +639,13 @@ class Runner:
         self._h2d_bytes = self.obs.counter("h2d_bytes_total")
         self._fetch_bytes = self.obs.counter("fetch_bytes_total")
         self._spill_counter = self.obs.counter("compaction_spills")
+        # fetch slice programs (one per sliced leaf), and those launched
+        # while a later step was already dispatched: the chip runs
+        # programs in launch order, so such a slice waits for that step
+        self._slices_counter = self.obs.counter("fetch_slices_total")
+        self._slices_behind = self.obs.counter(
+            "fetch_slices_behind_step_total"
+        )
         self._compaction_gauge = self.obs.gauge("compaction_ratio")
         # chained stages: emissions feed the downstream runner as
         # columnar batches instead of the sinks (build_plan_chain).
@@ -1475,6 +1516,8 @@ class Runner:
             ts_p = self._gshard(ts_p)
         sid = step if step is not None else self._new_step()
         self._flight.set_active(self.obs.name or self.program.operator_name)
+        if self._inflight and self._slices_fetch:
+            self._prepare_ahead()
         with self.obs.span("dispatch", sid):
             with Stopwatch() as sw:
                 self.state, emissions, counts, compact = self.step(
@@ -1483,6 +1526,7 @@ class Runner:
                 )
                 for leaf in counts.values():
                     leaf.copy_to_host_async()
+        self._dispatched += 1
         self.metrics.step_times_s.append(sw.elapsed)
         self.obs.steps.inc()
         self.obs.dispatch_time_s.observe(sw.elapsed)
@@ -1508,9 +1552,10 @@ class Runner:
                     step=sid,
                     operator=self.obs.name or self.program.operator_name,
                 )
-        self._inflight.append(
-            (emissions, counts, compact, t_batch, step_markers, sid)
-        )
+        self._inflight.append(_Inflight(
+            emissions, counts, compact, t_batch, step_markers, sid,
+            self._dispatched,
+        ))
         self.obs.inflight.set(len(self._inflight))
         while len(self._inflight) > self._max_inflight:
             g = self._fetch_group
@@ -1519,7 +1564,8 @@ class Runner:
 
     @property
     def _fetch_group(self) -> int:
-        """Steps whose count scalars fetch in one device_get round trip
+        """Steps retired together, their rows (and the count scalars of
+        those not planned ahead) in one device_get round trip
         (StreamConfig.fetch_group; >1 amortizes a high-latency link's
         RTT). Multi-host keeps the per-step cadence: the fetch decision
         drives collective-bearing paths and must stay step-aligned.
@@ -1532,6 +1578,19 @@ class Runner:
         if self._multiproc:
             return 1
         return max(1, min(self.cfg.fetch_group, max(1, self._max_inflight)))
+
+    @property
+    def _slices_fetch(self) -> bool:
+        """Whether a fetch launches device slice programs: on one chip,
+        the prefix-compacted 'main' stream and device compaction. Only
+        there is a step's fetch planned before the next step's dispatch
+        (_run_step); multi-host and sharded fetches read whole buffers
+        and keep their order."""
+        return (
+            not self._multiproc
+            and self.cfg.parallelism <= 1
+            and bool(self._compact_cap or self.program.main_emission_prefix)
+        )
 
     def drain_inflight(self):
         """Dispatch every pending step's emissions (checkpoint barrier /
@@ -1935,13 +1994,14 @@ class Runner:
             r.pump_chain(proc_now)
             r = r.downstream
 
-    def _plan_fetch(self, emissions, compact, cnts) -> dict:
+    def _plan_fetch(self, emissions, compact, cnts, seq: int) -> dict:
         """The emission streams worth fetching for one step, given its
         host-side count scalars: skip empty streams, slice prefix-
         compacted buffers to ~count rows, and swap in the device-
         compacted form (count-sized, pre-gathered) when the count fits
         its capacity — past it, spill to the classic full fetch so
-        semantics hold at any alert density."""
+        semantics hold at any alert density. ``seq`` is the step's
+        place in the dispatch order (_launch_slices)."""
         fetch = {}
         tt = getattr(self.program, "timeout_tag", None)
         for name, stream in emissions.items():
@@ -1964,10 +2024,15 @@ class Runner:
                 # valid rows are a compacted prefix: fetch the next
                 # power-of-two past the count, not the whole
                 # alert_capacity buffer (bucketing keeps the number
-                # of device slice programs bounded)
+                # of device slice programs bounded), and at least the
+                # recent firing scale: each new size compiles a slice
+                # program, so sizes stay few as counts wander
                 cap = int(stream["mask"].shape[0])
-                b = min(cap, 1 << max(4, (int(c) - 1).bit_length()))
-                stream = self._slice_stream(stream, b, cap)
+                b = min(cap, max(
+                    1 << max(4, (int(c) - 1).bit_length()),
+                    self._prefix_hint,
+                ))
+                stream = self._launch_slices(stream, b, cap, seq)
             elif name in compact:
                 if int(c) <= self._compact_cap:
                     # count-sized fetch: slice the [capacity] compact
@@ -1978,8 +2043,8 @@ class Runner:
                         self._compact_cap,
                         1 << max(4, (int(c) - 1).bit_length()),
                     )
-                    comp = self._slice_stream(
-                        compact[name], b, self._compact_cap
+                    comp = self._launch_slices(
+                        compact[name], b, self._compact_cap, seq
                     )
                     comp["__n__"] = int(c)
                     fetch[name] = comp
@@ -2000,80 +2065,157 @@ class Runner:
             fetch[name] = stream
         return fetch
 
+    def _launch_slices(self, stream, b: int, cap: int, seq: int):
+        """``_slice_stream`` for the fetch of the ``seq``-th dispatched
+        step, counted in ``fetch_slices_total`` and, where a later step
+        was already dispatched, ``fetch_slices_behind_step_total``."""
+        if self.obs.enabled:
+            n = sum(
+                1
+                for a in jax.tree_util.tree_leaves(stream)
+                if self._capacity_leaf(a, cap)
+            )
+            self._slices_counter.inc(n)
+            if self._dispatched > seq:
+                self._slices_behind.inc(n)
+        return self._slice_stream(stream, b, cap)
+
+    @staticmethod
+    def _capacity_leaf(a, cap: int) -> bool:
+        """A [cap]-rowed leaf of an emission stream (what a slice cuts)."""
+        return getattr(a, "ndim", 0) >= 1 and a.shape[0] == cap
+
     @staticmethod
     def _slice_stream(stream, b: int, cap: int):
         return jax.tree_util.tree_map(
-            lambda a: a[:b]
-            if getattr(a, "ndim", 0) >= 1 and a.shape[0] == cap
-            else a,
+            lambda a: a[:b] if Runner._capacity_leaf(a, cap) else a,
             stream,
         )
 
-    def _spec_eligible(self, entries) -> bool:
-        """Speculation / prefix-hint eligibility: the single-entry
-        (paced/sync) path on single-chip prefix-compacted programs.
-        One predicate for both the hint recorder and the speculative
-        fetch — they must agree or hints are recorded for steps that
-        can never use them."""
+    def _spec_eligible(self, entry: _Inflight) -> bool:
+        """Prefix-hint eligibility: a step of a single-chip
+        prefix-compacted program with a 'main' stream. One predicate
+        for both the hint recorder and the speculative fetch — they must
+        agree or hints are recorded for steps that can never use them."""
         return (
-            len(entries) == 1
-            and not self._multiproc
+            not self._multiproc
             and self.program.main_emission_prefix
             and self.cfg.parallelism <= 1
-            and entries[0][0].get("main") is not None
+            and entry.emissions.get("main") is not None
         )
 
     def _speculative_main(self, entries):
-        """For the single-entry (paced/sync) path on prefix-compacted
+        """For a lone step planned with no later step dispatched (the
+        synchronous path, the end-of-stream drain) on prefix-compacted
         programs: a slice of the 'main' stream sized by the PREVIOUS
         firing step's count, fetched in the same round trip as the count
         scalars. When the hint covers the actual count, a firing step
         costs ONE host-device round trip instead of two; the
         speculative bytes are bounded by the hint. Returns
         (stream_slice, hint_rows) or (None, 0)."""
-        if not self._spec_eligible(entries) or not self._prefix_hint:
+        if (
+            len(entries) != 1
+            or not self._prefix_hint
+            or not self._spec_eligible(entries[0])
+        ):
             return None, 0
-        main = entries[0][0]["main"]
+        e = entries[0]
+        main = e.emissions["main"]
         cap = int(main["mask"].shape[0])
         b = min(cap, self._prefix_hint)
-        return self._slice_stream(main, b, cap), b
+        return self._launch_slices(main, b, cap, e.seq), b
+
+    def _plan_entries(self, entries, speculate: bool):
+        """Read the entries' count scalars (copied to the host at their
+        dispatch) in one device_get and plan each one's fetch, launching
+        its slices. With ``speculate`` a lone prefix step also fetches a
+        hint-sized 'main' slice in that round trip; its rows then need
+        no exact slice."""
+        spec, spec_rows = (
+            self._speculative_main(entries) if speculate else (None, 0)
+        )
+        if spec is not None:
+            cnts0, spec_fetched = jax.device_get([entries[0].counts, spec])
+            cnts_list = [cnts0]
+        else:
+            cnts_list = jax.device_get([e.counts for e in entries])
+        for e, cnts in zip(entries, cnts_list):
+            c = int(cnts.get("main", 0))
+            if spec is not None and c and c <= spec_rows:
+                e.fetched["main"] = spec_fetched
+                e.fetch = self._plan_fetch(
+                    e.emissions, e.compact,
+                    {**cnts, "main": 0},  # rows already on the host
+                    e.seq,
+                )
+            else:
+                e.fetch = self._plan_fetch(
+                    e.emissions, e.compact, cnts, e.seq
+                )
+            if c and self._spec_eligible(e):
+                # track the recent firing scale (pow2 bucket, one level
+                # of headroom) so the next slice or speculation fits it
+                self._prefix_hint = min(
+                    int(e.emissions["main"]["mask"].shape[0]),
+                    1 << max(5, (c - 1).bit_length() + 1),
+                )
+
+    def _prepare_ahead(self):
+        """Plan, before the next dispatch, the fetch of each step in
+        flight that this dispatch retires, and of each other one that
+        has finished on the device. The chip runs programs in launch
+        order, so a slice launched after the dispatch would wait for
+        that whole step. A step still running that this dispatch does
+        not retire (a deep pipeline the device, not the host, paces) is
+        planned when it is retired, its slices then behind the later
+        steps (``fetch_slices_behind_step_total``)."""
+        n, retired = len(self._inflight) + 1, 0
+        while n - retired > self._max_inflight:
+            retired += self._fetch_group
+        todo = [
+            e
+            for i, e in enumerate(self._inflight)
+            if e.fetch is None and (i < retired or e.finished())
+        ]
+        if todo:
+            self._prepare_fetch(todo)
+
+    def _prepare_fetch(self, entries):
+        """First half of the fetch of steps still in flight, run just
+        before the next step's dispatch: plan them with their exact
+        counts, launch their slices (ahead of that step on the chip)
+        and start the slices' copies to the host. The next
+        _finish_group of these entries then only collects rows already
+        computed. Timed as its own ``fetch`` span."""
+        with self.obs.span("fetch", entries[-1].step), Stopwatch() as sw:
+            self._plan_entries(entries, speculate=False)
+            for e in entries:
+                for leaf in jax.tree_util.tree_leaves(e.fetch):
+                    if isinstance(leaf, jax.Array):
+                        leaf.copy_to_host_async()
+        for e in entries:
+            e.wait_s += sw.elapsed / len(entries)
+            for m in e.markers:
+                if getattr(m, "trace_id", 0):
+                    m.add_span("fetch", t0=sw.t0, dur=sw.elapsed,
+                               group=len(entries))
 
     def _finish_group(self, entries):
-        # the blocking wait on the device lives here, not in _run_step
-        # (dispatch is async): the ``fetch`` span times it, one span per
-        # group, carrying the group's last step. step_times_s gets the
-        # wait divided over the group's entries, beside the dispatch
-        # walls _run_step appends, so it is not the device's time.
-        # All entries' count scalars fetch in ONE device_get (one link
-        # round trip however many steps the group covers), then all
-        # still-needed emission streams fetch in a second one; dispatch
-        # order is unchanged.
-        with self.obs.span("fetch", entries[-1][5]), Stopwatch() as sw:
-            spec, spec_rows = self._speculative_main(entries)
-            if spec is not None:
-                cnts0, spec_fetched = jax.device_get(
-                    [entries[0][1], spec]
-                )
-                cnts_list = [cnts0]
-            else:
-                cnts_list = jax.device_get([e[1] for e in entries])
-            fetches = [
-                self._plan_fetch(em, comp, cnts)
-                for (em, _, comp, *_), cnts in zip(entries, cnts_list)
-            ]
-            pre_fetched: List[dict] = [{} for _ in fetches]
-            if self._spec_eligible(entries):
-                c = int(cnts_list[0].get("main", 0))
-                if c:
-                    # track the recent firing scale (pow2 bucket, one
-                    # level of headroom) so the next speculation fits it
-                    self._prefix_hint = min(
-                        int(entries[0][0]["main"]["mask"].shape[0]),
-                        1 << max(5, (c - 1).bit_length() + 1),
-                    )
-                if spec is not None and c and c <= spec_rows:
-                    pre_fetched[0]["main"] = spec_fetched
-                    del fetches[0]["main"]
+        # the blocking wait on the device lives here and in
+        # _prepare_fetch, not in _run_step (dispatch is async): the
+        # ``fetch`` span times it, one span per group, carrying the
+        # group's last step. step_times_s gets both halves' waits
+        # divided over the group's entries, beside the dispatch walls
+        # _run_step appends, so it is not the device's time.
+        # Entries not yet planned (no later step was dispatched: the
+        # synchronous path, a drain) read their count scalars in ONE
+        # device_get here; then all still-needed emission streams fetch
+        # in a second one; dispatch order is unchanged.
+        with self.obs.span("fetch", entries[-1].step), Stopwatch() as sw:
+            todo = [e for e in entries if e.fetch is None]
+            if todo:
+                self._plan_entries(todo, speculate=True)
+            fetches = [e.fetch for e in entries]
             if not any(fetches):
                 fetched_list = [{} for _ in fetches]
             elif self._multiproc:
@@ -2088,18 +2230,18 @@ class Runner:
         # wait divides evenly across its entries, so the histogram's
         # percentiles stay comparable across fetch_group settings while
         # the sum (summary()'s device_time_s) is unchanged (ADVICE r5)
-        per_entry = sw.elapsed / len(entries)
-        self.metrics.step_times_s.extend([per_entry] * len(entries))
-        self.obs.step_time_s.observe_many([per_entry] * len(entries))
-        for (entry, pre, fetched) in zip(entries, pre_fetched, fetched_list):
-            fetched.update(pre)
-            for m in entry[4]:
+        waits = [sw.elapsed / len(entries) + e.wait_s for e in entries]
+        self.metrics.step_times_s.extend(waits)
+        self.obs.step_time_s.observe_many(waits)
+        for entry, fetched in zip(entries, fetched_list):
+            fetched.update(entry.fetched)
+            for m in entry.markers:
                 if getattr(m, "trace_id", 0):
                     m.add_span("fetch", t0=sw.t0, dur=sw.elapsed,
                                group=len(entries))
-            self._dispatch(fetched, entry[3], entry[5])
-            if entry[4]:
-                self._record_markers(entry[4])
+            self._dispatch(fetched, entry.t_batch, entry.step)
+            if entry.markers:
+                self._record_markers(entry.markers)
 
     def _account_fetch(self, entries, fetches, fetched_list):
         """fetch_bytes_total / compaction_ratio bookkeeping (obs-enabled
@@ -2116,10 +2258,10 @@ class Runner:
 
         fetched_b = sum(nbytes(f) for f in fetched_list)
         # the count scalars fetch every step regardless
-        fetched_b += sum(4 * len(e[1]) for e in entries)
+        fetched_b += sum(4 * len(e.counts) for e in entries)
         self._fetch_bytes.inc(fetched_b)
         full_b = sum(
-            nbytes(entry[0].get(name))
+            nbytes(entry.emissions.get(name))
             for entry, plan in zip(entries, fetches)
             for name in plan
         )
