@@ -236,14 +236,13 @@ def _lat_result(src, m, alerts):
 
 
 def full_path_flagship(rate=None, nbuf=200, warm=80, fill_ms=None,
-                       fetch_group=1, async_depth=4, delay_s=60):
+                       async_depth=4, delay_s=60):
     """Config 4/5 through execute_job: raw bytes -> native ISO parse +
     intern -> H2D -> sliding event-time windows -> Mbps alert sink.
     Windows scaled to (5 s, 1 s) so the 1-min watermark delay is
     crossable in-bench; per-event device work is identical (pane ring).
     ``rate`` paces the source (records/s); None floods. ``fill_ms``
-    sizes paced arrival batches; ``fetch_group`` amortizes the per-step
-    count-fetch RTT under flood (StreamConfig.fetch_group)."""
+    sizes paced arrival batches."""
     from tpustream import StreamExecutionEnvironment, Time, TimeCharacteristic
     from tpustream.config import StreamConfig
     from tpustream.jobs.chapter3_bandwidth_eventtime import build
@@ -259,7 +258,6 @@ def full_path_flagship(rate=None, nbuf=200, warm=80, fill_ms=None,
         key_capacity=NKEY,
         alert_capacity=1 << 16,
         async_depth=async_depth,
-        fetch_group=fetch_group,
         max_batch_delay_ms=0.0,
         # flood: overlap parse with the link (paced runs keep the
         # inline host stage — latency attribution stays exact)
@@ -281,7 +279,7 @@ def full_path_flagship(rate=None, nbuf=200, warm=80, fill_ms=None,
 
 
 def full_path_ch1(rate=None, nbuf=65, warm=5, fill_ms=None,
-                  fetch_group=1, async_depth=4):
+                  async_depth=4):
     """Config 1 through execute_job: the stateless threshold-alert job
     (parse -> filter usage>90 -> sink)."""
     from tpustream import StreamExecutionEnvironment
@@ -297,7 +295,7 @@ def full_path_ch1(rate=None, nbuf=65, warm=5, fill_ms=None,
     # time patch writes into the numeric ts field (unused by the job)
     cfg = StreamConfig(
         batch_size=src.rows_per_batch, async_depth=async_depth,
-        fetch_group=fetch_group, max_batch_delay_ms=0.0,
+        max_batch_delay_ms=0.0,
         parse_ahead=0 if rate else 2,
     )
     env = StreamExecutionEnvironment(cfg)
@@ -1585,23 +1583,15 @@ def decompose_full_path(n_batches=10, bl=1 << 16, nkey=1 << 20,
     from tpustream.runtime.metrics import Metrics
     from tpustream.runtime.plan import build_plan_chain
 
-    def make_runner(cfg, job_obs=None):
+    def make_runner(cfg):
         env = StreamExecutionEnvironment(cfg)
         env.set_stream_time_characteristic(TimeCharacteristic.EventTime)
-        sink = []
         build(
             env, env.add_source(None), size=Time.seconds(5),
             slide=Time.seconds(1),
-        ).add_sink(lambda r: sink.append(r))
+        ).add_sink(lambda r: None)
         plan = build_plan_chain(env, env._sinks)[0]
-        if job_obs is None:
-            metrics = Metrics()
-        else:
-            metrics = Metrics(
-                registry=job_obs.registry, job_name=job_obs.job_name
-            )
-            metrics.job_obs = job_obs
-        return HostStage(plan, cfg), Runner(plan, cfg, metrics), sink, metrics
+        return HostStage(plan, cfg), Runner(plan, cfg, Metrics())
 
     def parse_batch(host, sb):
         """Native raw-bytes lane, falling back to the line path where
@@ -1618,7 +1608,7 @@ def decompose_full_path(n_batches=10, bl=1 << 16, nkey=1 << 20,
         batch_size=bl, key_capacity=nkey, alert_capacity=1 << 16,
         async_depth=1, max_batch_delay_ms=0.0,
     )
-    host, runner, _, _ = make_runner(cfg)
+    host, runner = make_runner(cfg)
 
     src = _GenBytesSource(tpl, tcols, n_batches + 3, 0, bl, 1_566_957_600_000)
     t_parse, t_pack, t_feed, t_rtt = [], [], [], []
@@ -1689,13 +1679,12 @@ def decompose_full_path(n_batches=10, bl=1 << 16, nkey=1 << 20,
     # compaction) over the same batches; ms/batch here is the overlapped
     # steady-state cost the flood actually pays
     pipelined_ms = pipelined_rate = None
-    baseline_sha = None
     if pipelined:
         cfg2 = StreamConfig(
             batch_size=bl, key_capacity=nkey, alert_capacity=1 << 16,
             max_batch_delay_ms=0.0,
         )
-        host2, runner2, sink2, _ = make_runner(cfg2)
+        host2, runner2 = make_runner(cfg2)
         src2 = _GenBytesSource(
             tpl, tcols, n_batches + 3, 0, bl, 1_566_957_600_000
         )
@@ -1710,97 +1699,13 @@ def decompose_full_path(n_batches=10, bl=1 << 16, nkey=1 << 20,
                 t_start = time.perf_counter()
             # real watermark progress (each buffer = one stream second)
             # so windows fire and the pass pays the emission path the
-            # flood pays — and leaves sink bytes to hold against the
-            # controller-on pass below
+            # flood pays
             runner2.feed(batch, int(np.asarray(batch.ts).max()))
             b2 += 1
         runner2.drain_inflight()
         if t_start is not None and b2 > 3:
             pipelined_ms = (time.perf_counter() - t_start) / (b2 - 3) * 1e3
             pipelined_rate = bl / (pipelined_ms / 1e3)
-        baseline_sha = _sink_digest(sink2)
-
-    # controller-on pass: same shape again with the obs layer live and
-    # the AdaptiveController driven at batch barriers (the bench stands
-    # in for the Snapshotter tick). The contract under test: knobs move
-    # only inside bounds, every move is a flight event + controller_*
-    # series, and the sink bytes match the controller-off pass exactly —
-    # depths overlap work, they never change results.
-    controller_report = None
-    if pipelined:
-        from tpustream.config import ObsConfig
-        from tpustream.obs.runtime import JobObs
-        from tpustream.runtime.controller import AdaptiveController
-
-        obs_cfg = ObsConfig(
-            enabled=True, adaptive=True, adaptive_cooldown_ticks=0,
-        )
-        cfg3 = StreamConfig(
-            batch_size=bl, key_capacity=nkey, alert_capacity=1 << 16,
-            max_batch_delay_ms=0.0, obs=obs_cfg,
-        )
-        job_obs3 = JobObs(obs_cfg, job_name="decompose")
-        host3, runner3, sink3, metrics3 = make_runner(cfg3, job_obs3)
-        controller = AdaptiveController(cfg3, job_obs3)
-        src3 = _GenBytesSource(
-            tpl, tcols, n_batches + 3, 0, bl, 1_566_957_600_000
-        )
-        b3 = 0
-        t_start3 = None
-        for sb in src3.batches(bl, 0.0):
-            if sb.final:
-                break
-            batch = parse_batch(host3, sb)
-            if b3 == 3:
-                runner3.drain_inflight()
-                t_start3 = time.perf_counter()
-            runner3.feed(batch, int(np.asarray(batch.ts).max()))
-            if b3 >= 3:  # tick once per steady-state batch
-                knobs = controller.on_tick()
-                if knobs:
-                    runner3.drain_inflight()
-                    for r in runner3.chain():
-                        r.apply_knobs(knobs)
-            b3 += 1
-        runner3.drain_inflight()
-        ctl_ms = ctl_rate = None
-        if t_start3 is not None and b3 > 3:
-            ctl_ms = (time.perf_counter() - t_start3) / (b3 - 3) * 1e3
-            ctl_rate = bl / (ctl_ms / 1e3)
-        summary3 = controller.summary()
-        prof = {}
-        if job_obs3.profiler is not None:
-            prof = job_obs3.profiler.profile()
-        lat3 = sorted(metrics3.emit_latencies_s)
-        p99_ms3 = (
-            float(np.percentile(lat3, 99) * 1e3) if lat3 else None
-        )
-        output_sha = _sink_digest(sink3)
-        controller_report = dict(
-            converged=controller.converged(),
-            bounds=summary3["bounds"],
-            decisions=summary3["decisions"],
-            reverts=summary3["reverts"],
-            p99_ms=p99_ms3,
-            ms_per_batch=ctl_ms,
-            rows_per_s=ctl_rate,
-            binding_stage=prof.get("binding_stage"),
-            binding_share=prof.get("binding_share"),
-            output_sha=output_sha,
-            baseline_sha=baseline_sha,
-        )
-        knob_txt = ", ".join(
-            f"{k}={v}" for k, v in sorted(controller.converged().items())
-        )
-        log(
-            f"phase F detail: controller-on pass converged to {knob_txt} "
-            f"after {summary3['decisions']} decisions "
-            f"({summary3['reverts']} reverts), emit p99 "
-            f"{0.0 if p99_ms3 is None else p99_ms3:.1f} ms, output "
-            f"{'MATCHES' if output_sha == baseline_sha else 'DIVERGES FROM'}"
-            f" the controller-off pass"
-        )
-        job_obs3.close(dump=False)
 
     return dict(
         rows_per_batch=bl,
@@ -1813,7 +1718,6 @@ def decompose_full_path(n_batches=10, bl=1 << 16, nkey=1 << 20,
         binding_ms=binding[1],
         pipelined_ms_per_batch=pipelined_ms,
         pipelined_rows_per_s=pipelined_rate,
-        controller=controller_report,
     )
 
 
@@ -2443,19 +2347,18 @@ def run_bench():
     rtt = rtt_ms or 100.0
 
     # ---- Phase F: ch1 threshold FULL PATH (config 1) --------------------
-    # F1 floods (throughput ceiling) with the count-fetch RTT amortized
-    # over fetch_group=8 steps (VERDICT r4 next #2); F2 walks the paced
+    # F1 floods (throughput ceiling) at async_depth 16; F2 walks the paced
     # rate ladder with ARRIVAL-SIZED batches and attributes each rung's
     # p99 into fill + parse + fetch + RTT (VERDICT r4 next #1)
     ch1_rate = None
     ch1_sus = None
     ch1_curve = None
     try:
-        f1 = full_path_ch1(fetch_group=16, async_depth=16)
+        f1 = full_path_ch1(async_depth=16)
         ch1_rate = f1["rate"]
         log(
             f"phase F1: ch1 full path FLOOD (execute_job, raw bytes, "
-            f"fetch_group=16): {ch1_rate/1e6:.2f}M events/s, "
+            f"async_depth=16): {ch1_rate/1e6:.2f}M events/s, "
             f"{f1['alerts']} alerts"
         )
         log(f"phase F1 summary: {f1['summary']}")
@@ -2478,29 +2381,17 @@ def run_bench():
     full_p99 = None
     flag_sus = None
     flag_curve = None
-    g1_perstep_rate = None
     try:
-        g1 = full_path_flagship(fetch_group=16, async_depth=16)
+        g1 = full_path_flagship(async_depth=16)
         full_rate, full_p99 = g1["rate"], g1["p99_ms"]
         p99_txt = f"{full_p99:.0f} ms" if full_p99 is not None else "n/a"
         log(
             f"phase G1: flagship full path FLOOD (execute_job, raw bytes, "
-            f"event time, fetch_group=16): {full_rate/1e6:.2f}M events/s, "
+            f"event time, async_depth=16): {full_rate/1e6:.2f}M events/s, "
             f"p99 ingest->alert {p99_txt} (queueing artifact under flood — "
             f"see G2 for the steady-state figure), {g1['alerts']} alerts"
         )
         log(f"phase G1 summary: {g1['summary']}")
-        # the per-step-fetch comparison run names the lever's size —
-        # identical knobs except fetch_group, so the ratio isolates it
-        g1p = full_path_flagship(
-            fetch_group=1, async_depth=16, nbuf=100, warm=40
-        )
-        g1_perstep_rate = g1p["rate"]
-        log(
-            f"phase G1a: same flood with per-step count fetches "
-            f"(fetch_group=1): {g1_perstep_rate/1e6:.2f}M events/s "
-            f"(grouping buys {full_rate/max(g1_perstep_rate,1):.2f}x here)"
-        )
 
         def run_flag(r, fill):
             BL = 1 << 16
@@ -2914,11 +2805,6 @@ def run_bench():
                     "link_rtt_ms": round(rtt, 1),
                     "rate_p99_curve_ch1": ch1_curve,
                     "rate_p99_curve_flagship": flag_curve,
-                    # flood with per-step count fetches, for the
-                    # amortization lever's measured size (r4 next #2)
-                    "flagship_flood_perstep_fetch_events_per_s": round(
-                        g1_perstep_rate or 0
-                    ),
                     # family device pipelines (r4 weak #6)
                     "session_window_events_per_s": round(session_rate or 0),
                     "count_window_events_per_s": round(count_rate or 0),

@@ -37,7 +37,7 @@ import pytest  # noqa: E402
 # Wall-time record on the 1-core driver host (budget: full gate <=
 # 20:00, VERDICT r3 next #9 / r4 next #7). Round-5 coverage (six-family
 # chain fuzz, five new rescale tests, multi-host rescale restore,
-# parse_ahead/fetch_group variants, selector-guard tests) first
+# parse_ahead/grouped-fetch variants, selector-guard tests) first
 # measured 28:56/244; structural cuts brought it to **23:42/225
 # measured warm; subsequent full runs of the final tree measured
 # 22:12-25:04** (per-tier: distributed ~3:20 in ONE worker-pair

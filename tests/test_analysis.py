@@ -245,13 +245,6 @@ def test_tsm008_tolerates_leading_flat_map():
     assert "TSM008" in codes(env.analyze())
 
 
-def test_tsm009_fetch_group_exceeds_window():
-    env = good_job(make_env(async_depth=2, fetch_group=4))
-    assert "TSM009" in codes(env.analyze())
-    env = good_job(make_env(async_depth=4, fetch_group=2))
-    assert "TSM009" not in codes(env.analyze())
-
-
 def test_tsm010_window_process_forces_depth_one():
     env = make_env(async_depth=2)
     (
@@ -264,31 +257,6 @@ def test_tsm010_window_process_forces_depth_one():
     )
     f = next(f for f in env.analyze() if f.code == "TSM010")
     assert f.severity == INFO
-
-
-def test_tsm011_adaptive_bounds():
-    obs = ObsConfig(enabled=True, adaptive=True,
-                    adaptive_bounds={"async_depth": (5, 2)})
-    env = good_job(make_env(obs=obs))
-    f = next(f for f in env.analyze() if f.code == "TSM011")
-    assert f.severity == ERROR
-    # unknown knob names: WARN, not ERROR
-    obs = ObsConfig(enabled=True, adaptive=True,
-                    adaptive_bounds={"warp_factor": (1, 2)})
-    env = good_job(make_env(obs=obs))
-    f = next(f for f in env.analyze() if f.code == "TSM011")
-    assert f.severity == WARN
-
-
-def test_tsm012_grouped_fetch_coarsens_latency():
-    obs = ObsConfig(enabled=True)
-    env = good_job(make_env(obs=obs, async_depth=4, fetch_group=2))
-    f = next(f for f in env.analyze() if f.code == "TSM012")
-    assert f.severity == INFO
-    assert "per-group averages" in f.message
-    # fetch_group=1: silent
-    env = good_job(make_env(obs=ObsConfig(enabled=True)))
-    assert "TSM012" not in codes(env.analyze())
 
 
 def test_tsm013_unproduced_side_output_tag():
@@ -843,20 +811,35 @@ def test_strict_analysis_blocks_before_compile():
     assert env.metrics is None
 
 
+def _remember(seen):
+    """A host map that captures a mutable list: TSM021, a WARN."""
+
+    def remember(v):
+        seen.append(v)
+        return v
+
+    return remember
+
+
 def test_strict_analysis_off_by_default_and_warns_pass():
-    env = make_env(strict_analysis=True, async_depth=2, fetch_group=4)
+    env = make_env(strict_analysis=True)
     text = env.add_source(ReplaySource(["1563452051 10.8.22.1 cpu2 99.2"]))
-    handle = text.map(parse).filter(lambda v: v.f2 > 90).collect()
-    env.execute("warn-only")  # TSM009 is WARN: strict mode still runs
+    handle = (
+        text.map(parse).map(_remember([])).filter(lambda v: v.f2 > 90)
+        .collect()
+    )
+    assert "TSM021" in codes(env.analyze())
+    env.execute("warn-only")  # TSM021 is WARN: strict mode still runs
     assert handle.items == [("10.8.22.1", "cpu2", 99.2)]
 
 
 def test_obs_records_findings_and_clamp():
-    env = make_env(
-        async_depth=2, fetch_group=4, obs=ObsConfig(enabled=True)
-    )
+    env = make_env(ingest_lanes=0, obs=ObsConfig(enabled=True))
     text = env.add_source(ReplaySource(["1563452051 10.8.22.1 cpu2 99.2"]))
-    handle = text.map(parse).filter(lambda v: v.f2 > 90).collect()
+    handle = (
+        text.map(parse).map(_remember([])).filter(lambda v: v.f2 > 90)
+        .collect()
+    )
     res = env.execute("obs-findings")
     assert handle.items == [("10.8.22.1", "cpu2", 99.2)]
     series = {
@@ -864,7 +847,7 @@ def test_obs_records_findings_and_clamp():
         for s in res.metrics.obs_snapshot()["metrics"]["series"]
         if s["name"] == "analysis_findings_total"
     }
-    assert series[("analysis_findings_total", "TSM009")] == 1
+    assert series[("analysis_findings_total", "TSM021")] == 1
     kinds = [e["kind"] for e in res.metrics.job_obs.flight.events()]
     assert "analysis_finding" in kinds
     assert "config_clamped" in kinds
@@ -872,7 +855,7 @@ def test_obs_records_findings_and_clamp():
         e for e in res.metrics.job_obs.flight.events()
         if e["kind"] == "config_clamped"
     )
-    assert clamp["knob"] == "fetch_group"
+    assert clamp["knob"] == "ingest_lanes"
     assert clamp["effective"] == 1
 
 
@@ -930,7 +913,7 @@ def test_catalog_is_stable():
     (docs/analysis.md renders from CATALOG)."""
     expected = {
         "TSM001", "TSM002", "TSM003", "TSM004", "TSM005", "TSM006",
-        "TSM007", "TSM008", "TSM009", "TSM010", "TSM011", "TSM012",
+        "TSM007", "TSM008", "TSM010",
         "TSM013", "TSM014", "TSM015", "TSM016", "TSM017", "TSM018",
         "TSM019", "TSM020", "TSM021",
         "TSM022", "TSM023", "TSM024", "TSM025", "TSM030", "TSM031",
@@ -939,6 +922,8 @@ def test_catalog_is_stable():
         "TSM052", "TSM053",
     }
     assert expected <= set(CATALOG)
+    # retired with the options they checked; never reused
+    assert not {"TSM009", "TSM011", "TSM012"} & set(CATALOG)
     for code, rule in CATALOG.items():
         assert rule.code == code
         assert rule.severity in (ERROR, WARN, INFO)

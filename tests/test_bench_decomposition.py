@@ -39,26 +39,6 @@ def test_decompose_full_path_tiny_mode(bench):
     assert d["pipelined_rows_per_s"] > 0
 
 
-def test_decompose_controller_pass_tiny_mode(bench):
-    """The controller-on pass reports converged knobs inside bounds and
-    sink bytes identical to the controller-off pipelined pass — the
-    adaptive loop may move depths, never results."""
-    d = bench.decompose_full_path(n_batches=4, bl=256, nkey=1024)
-    c = d["controller"]
-    assert c is not None
-    assert sorted(c["converged"]) == ["async_depth", "fetch_group", "h2d_depth"]
-    for knob, val in c["converged"].items():
-        lo, hi = c["bounds"][knob]
-        assert lo <= val <= hi, (knob, val, lo, hi)
-    assert c["decisions"] >= 0 and c["reverts"] >= 0
-    assert c["ms_per_batch"] is None or c["ms_per_batch"] > 0
-    # windows fired in both passes (the digest is of real emissions,
-    # not two empty sinks agreeing) and the bytes match exactly
-    empty = "e3b0c44298fc1c149afbf4c8996fb92427ae41e4649b934ca495991b7852b855"
-    assert c["baseline_sha"] != empty
-    assert c["output_sha"] == c["baseline_sha"]
-
-
 def test_ingest_lane_sweep_tiny_mode(bench):
     """Phase I2 in tiny mode: the lane sweep runs end to end, every
     lane count reports a positive rate over the full line budget, and

@@ -109,10 +109,9 @@ def test_execution_strategies_are_observationally_identical(seed):
         "sync_depth1": dict(async_depth=1),
         "no_compress": dict(h2d_compress=False),
         "fire_budget": dict(max_fires_per_step=2),
-        # grouped count fetches only shift WHEN emissions are fetched,
-        # never what they contain (async_depth=8 subsumes the former
-        # deep_pipeline variant)
-        "grouped_fetch": dict(async_depth=8, fetch_group=4),
+        # a deep in-flight window only shifts WHEN emissions are
+        # fetched, never what they contain
+        "deep_window": dict(async_depth=8),
         # source+parse on its own thread: pure pipelining, same output
         "parse_ahead": dict(parse_ahead=2),
     }

@@ -13,6 +13,7 @@ steps that spill past ``compaction_capacity``), that the counter
 order included, are those of the synchronous run.
 """
 
+import collections
 import functools
 
 import jax
@@ -173,8 +174,8 @@ def test_slices_of_a_step_launch_before_the_next_dispatch(
 @pytest.mark.parametrize(
     "over",
     [dict(async_depth=2), dict(async_depth=3),
-     dict(async_depth=3, fetch_group=2), dict(async_depth=4, h2d_depth=3)],
-    ids=["depth2", "depth3", "depth3-group2", "depth4-h2d3"],
+     dict(async_depth=8), dict(async_depth=4, h2d_depth=3)],
+    ids=["depth2", "depth3", "depth8", "depth4-h2d3"],
 )
 @pytest.mark.parametrize("job", ["prefix", "compaction"])
 def test_rows_match_the_synchronous_run_in_order(job, over):
@@ -186,6 +187,48 @@ def test_rows_match_the_synchronous_run_in_order(job, over):
         assert series_total(res, "fetch_slices_behind_step_total") == 0
     if job == "compaction":
         assert series_total(res, "compaction_spills") > 0
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3, 4, 8])
+def test_each_dispatch_retires_at_most_one_step(monkeypatch, depth):
+    """The in-flight window holds at most async_depth - 1 steps. A
+    dispatch past it retires the oldest step, and only that one; a
+    drain retires what is left one by one. Every step is retired once,
+    in dispatch order."""
+    want = sync_rows("prefix")  # before the spies
+    run_step, retire = Runner._run_step, Runner._retire
+    drain = Runner.drain_inflight
+    windows = []   # steps in flight after each dispatch
+    retired = []   # (step seq, steps dispatched then, in a drain)
+    draining = [False]
+
+    def spy_run_step(runner, *args, **kw):
+        run_step(runner, *args, **kw)
+        windows.append(len(runner._inflight))
+
+    def spy_retire(runner, entry):
+        retired.append((entry.seq, runner._dispatched, draining[0]))
+        retire(runner, entry)
+
+    def spy_drain(runner):
+        draining[0] = True
+        try:
+            drain(runner)
+        finally:
+            draining[0] = False
+
+    monkeypatch.setattr(Runner, "_run_step", spy_run_step)
+    monkeypatch.setattr(Runner, "_retire", spy_retire)
+    monkeypatch.setattr(Runner, "drain_inflight", spy_drain)
+    run, lines = JOBS["prefix"]
+    rows, _ = run(lines(), async_depth=depth)
+    assert rows and tuple(rows) == want
+    assert max(windows) == depth - 1
+    assert [seq for seq, _, _ in retired] == list(range(1, len(windows) + 1))
+    per_dispatch = collections.Counter(
+        d for _, d, in_drain in retired if not in_drain
+    )
+    assert set(per_dispatch.values()) == {1}
 
 
 @pytest.mark.parametrize("case", ["old-order", "steps-running"])

@@ -3,13 +3,12 @@ percentiles vs a numpy oracle, Prometheus exposition goldens (hostile
 label values included), the watermark-lag gauge and end-to-end latency
 markers on a chapter-3 event-time job, the health engine's CRIT rule on
 that job, the disabled-path overhead guard, snapshot/dump round trips
-(and the dump CLI's --selftest smoke mode), the fetch_group pipeline
-clamp, and the DerivedKeyTable snapshot-tear invariant."""
+(and the dump CLI's --selftest smoke mode), and the DerivedKeyTable
+snapshot-tear invariant."""
 
 import json
 import math
 import threading
-import types
 
 import numpy as np
 import pytest
@@ -29,7 +28,6 @@ from tpustream.obs import (
 )
 from tpustream.obs.dump import main as dump_main, render as dump_render
 from tpustream.records import DerivedKeyTable
-from tpustream.runtime.executor import Runner
 from tpustream.runtime.sources import ReplaySource
 
 
@@ -259,7 +257,7 @@ def test_dump_selftest_smoke(capsys):
     assert "FAIL" not in out
     m = re.search(r"selftest ok \((\d+) checks\)", out)
     assert m, out
-    assert int(m.group(1)) == 134
+    assert int(m.group(1)) == 133
     # the multi-tenant series checks are part of the suite
     assert "ok: prometheus carries the per-tenant labels" in out
     # ... and the sharded-ingestion lane series
@@ -434,24 +432,8 @@ def test_summary_keys_unchanged_by_obs():
 
 
 # ---------------------------------------------------------------------------
-# satellites: fetch_group clamp, DerivedKeyTable snapshot tear
+# satellite: DerivedKeyTable snapshot tear
 # ---------------------------------------------------------------------------
-
-
-def test_fetch_group_clamped_to_inflight_window():
-    def eff(fetch_group, async_depth, multiproc=False):
-        fake = types.SimpleNamespace(
-            cfg=types.SimpleNamespace(fetch_group=fetch_group),
-            _max_inflight=max(0, async_depth - 1),
-            _multiproc=multiproc,
-        )
-        return Runner._fetch_group.fget(fake)
-
-    assert eff(8, 2) == 1   # full-window group would drain the pipeline
-    assert eff(8, 4) == 3   # clamped to async_depth - 1
-    assert eff(2, 4) == 2   # under the window: honored
-    assert eff(4, 1) == 1   # no pipelining at all -> per-step fetch
-    assert eff(8, 8, multiproc=True) == 1  # multi-host stays step-aligned
 
 
 def test_derived_key_table_snapshot_tear():

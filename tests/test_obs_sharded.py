@@ -321,42 +321,3 @@ def test_tenant_series_merge_p8_matches_single_chip_oracle():
     assert by["slo_p99[acme]"]["labels"] == {"tenant": "acme"}
     assert by["slo_p99[globex]"]["level"] == "ok"
     assert by["slo_p99[initech]"]["level"] == "ok"
-
-
-def test_sharded_adaptive_controller_output_parity_p8():
-    """p=8 with the adaptive controller ticking at flood rate: sink
-    output identical to the controller-off run, and the controller left
-    its audit trail (series + at least one decision event)."""
-    _, out_off = _run(parallelism=8)
-
-    cfg = StreamConfig(
-        parallelism=8,
-        batch_size=40,
-        key_capacity=64,
-        print_parallelism=1,
-        obs=ObsConfig(
-            enabled=True, adaptive=True, snapshot_interval_s=1e-4,
-            adaptive_cooldown_ticks=0,
-        ),
-    )
-    env = StreamExecutionEnvironment(cfg)
-    env.set_stream_time_characteristic(TimeCharacteristic.EventTime)
-    h = build_et(env, env.add_source(ReplaySource(LINES))).collect()
-    env.execute("obs-sharded-adaptive")
-    out_on = sorted((t.f0, round(t.f1, 12)) for t in h.items)
-    assert out_on == out_off  # depth moves never change results
-
-    names = {
-        s["name"]
-        for s in env.metrics.obs_snapshot()["metrics"]["series"]
-    }
-    for want in (
-        "controller_async_depth", "controller_fetch_group",
-        "controller_h2d_depth", "controller_decisions_total",
-    ):
-        assert want in names, want
-    evs = [
-        e for e in env.metrics.job_obs.flight.events()
-        if e["kind"] == "controller_decision"
-    ]
-    assert evs, "flood-rate ticks must produce at least one decision"

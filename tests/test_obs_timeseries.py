@@ -1,5 +1,4 @@
-"""Time-series registry core, continuous profiler, and the adaptive
-controller (closed-loop observability tentpole).
+"""Time-series registry core and the continuous profiler.
 
 Covers, device-free unless noted:
 
@@ -12,20 +11,11 @@ Covers, device-free unless noted:
 * Snapshotter absolute-deadline cadence: a slow tick records skew but
   never shifts the grid, and a stall never burst-fires.
 * PipelineProfiler stage attribution over crafted spans.
-* AdaptiveController unit behavior (probe/keep/revert/backoff, bounds,
-  flight audit trail, off-by-default) and ``Runner.apply_knobs`` depth
-  plumbing.
-* End-to-end: a single-chip job with ``adaptive=True`` at a flood tick
-  rate produces output identical to the controller-off run, plus the
-  ``controller_*`` series and decision events.
 """
-
-import types
 
 import numpy as np
 import pytest
 
-from tpustream.config import ObsConfig, StreamConfig
 from tpustream.obs.registry import MetricsRegistry
 from tpustream.obs.snapshot import Snapshotter
 from tpustream.obs.timeseries import TimeSeries
@@ -240,230 +230,3 @@ def test_profiler_attributes_batch_time_to_stages():
     assert sum(s["share"] for s in p["stages"].values()) == pytest.approx(1.0)
     prom = reg.to_prometheus_text()
     assert "profile_binding_stage" in prom and 'stage="dispatch"' in prom
-
-
-# ---------------------------------------------------------------------------
-# adaptive controller
-# ---------------------------------------------------------------------------
-
-
-def make_controller(**obs_over):
-    from tpustream.obs.runtime import JobObs
-    from tpustream.runtime.controller import AdaptiveController
-
-    obs_over.setdefault("adaptive_cooldown_ticks", 0)
-    obs_cfg = ObsConfig(
-        enabled=True, adaptive=True, snapshot_interval_s=1.0, **obs_over
-    )
-    cfg = StreamConfig(obs=obs_cfg)
-    job_obs = JobObs(obs_cfg, job_name="ctl")
-    reg = job_obs.registry
-    clk = [100.0]
-    reg.now = lambda: clk[0]
-    reg._epoch_wall = 0.0
-    reg._epoch_perf = 0.0
-    ctl = AdaptiveController(cfg, job_obs)
-    ingest = job_obs.counter("records_in")
-    return ctl, job_obs, clk, ingest
-
-
-def controller_events(job_obs):
-    return [
-        e for e in job_obs.flight.events()
-        if e["kind"] == "controller_decision"
-    ]
-
-
-def test_adaptive_is_off_by_default():
-    assert ObsConfig().adaptive is False
-
-
-def test_controller_keeps_improving_probe():
-    ctl, job_obs, clk, ingest = make_controller()
-    start = dict(ctl.knobs)
-    clk[0] = 101.0
-    ingest.inc(1000)
-    clk[0] = 102.0
-    knobs = ctl.on_tick()  # probes the first knob up one step
-    assert knobs is not None
-    assert knobs["async_depth"] == start["async_depth"] + 1
-    clk[0] = 103.0
-    ingest.inc(4000)  # rate doubles well past the hysteresis band
-    clk[0] = 104.0
-    assert ctl.on_tick() is None  # keep: no further change to apply
-    assert ctl.knobs["async_depth"] == start["async_depth"] + 1
-    acts = [e["action"] for e in controller_events(job_obs)]
-    assert acts == ["probe", "keep"]
-    # every knob stayed inside its bounds
-    for k, v in ctl.knobs.items():
-        lo, hi = ctl.bounds[k]
-        assert lo <= v <= hi
-
-
-def test_controller_reverts_flat_probe_and_flips_direction():
-    ctl, job_obs, clk, ingest = make_controller()
-    start = dict(ctl.knobs)
-    clk[0] = 101.0
-    ingest.inc(1000)
-    clk[0] = 102.0
-    knobs = ctl.on_tick()
-    assert knobs["async_depth"] == start["async_depth"] + 1
-    clk[0] = 103.0
-    ingest.inc(1000)  # identical rate: inside the hysteresis band
-    clk[0] = 104.0
-    knobs = ctl.on_tick()
-    assert knobs is not None  # revert is itself a knob change to apply
-    assert knobs["async_depth"] == start["async_depth"]
-    assert ctl._dir["async_depth"] == -1
-    assert int(ctl._reverts.value) == 1
-    acts = [e["action"] for e in controller_events(job_obs)]
-    assert acts == ["probe", "revert"]
-
-
-def test_controller_backs_off_on_p99_breach():
-    ctl, job_obs, clk, ingest = make_controller(adaptive_p99_ms=300.0)
-    start = dict(ctl.knobs)
-    lat = job_obs.histogram("emit_latency_s")
-    clk[0] = 101.0
-    ingest.inc(1000)
-    lat.observe(0.5)  # 500 ms >> the 300 ms bound
-    clk[0] = 102.0
-    knobs = ctl.on_tick()
-    assert knobs is not None
-    for k in ("async_depth", "h2d_depth"):
-        assert knobs[k] == max(ctl.bounds[k][0], start[k] - 1)
-    evs = controller_events(job_obs)
-    assert evs and evs[-1]["action"] == "backoff"
-    assert evs[-1]["p99_ms"] == pytest.approx(500.0, rel=1e-6)
-
-
-def test_controller_respects_user_bounds():
-    ctl, job_obs, clk, ingest = make_controller(
-        adaptive_bounds={"async_depth": (1, 2), "bogus_knob": (0, 99)}
-    )
-    assert ctl.bounds["async_depth"] == (1, 2)
-    assert "bogus_knob" not in ctl.bounds
-    # walk many ticks with a rising objective: async_depth must never
-    # leave [1, 2] no matter how hard the objective pulls
-    total = 0
-    for i in range(12):
-        clk[0] = 101.0 + i
-        total += 1000 * (i + 1)
-        ingest.inc(1000 * (i + 1))
-        clk[0] += 0.5
-        ctl.on_tick()
-        assert 1 <= ctl.knobs["async_depth"] <= 2
-
-
-def test_controller_series_surface():
-    ctl, job_obs, clk, ingest = make_controller()
-    clk[0] = 101.0
-    ingest.inc(1000)
-    clk[0] = 102.0
-    ctl.on_tick()
-    reg = job_obs.registry
-    names = {s["name"] for s in reg.snapshot()["series"]}
-    for want in (
-        "controller_async_depth", "controller_fetch_group",
-        "controller_h2d_depth", "controller_decisions_total",
-        "controller_objective_rows_per_s",
-    ):
-        assert want in names, want
-
-
-# ---------------------------------------------------------------------------
-# Runner.apply_knobs plumbing (no device, unbound call on a stub)
-# ---------------------------------------------------------------------------
-
-
-def _stub_runner(**over):
-    from tpustream.runtime.executor import Runner
-
-    stub = types.SimpleNamespace(
-        cfg=StreamConfig(async_depth=2, fetch_group=1, h2d_depth=2),
-        program=types.SimpleNamespace(
-            emissions_reference_state=False, mesh=None
-        ),
-        _multiproc=False,
-        _h2d_sharding=None,
-        _max_inflight=1,
-        _h2d_ahead=1,
-    )
-    for k, v in over.items():
-        setattr(stub, k, v)
-    return stub, Runner.apply_knobs
-
-
-def test_apply_knobs_sets_depths_and_cfg():
-    stub, apply_knobs = _stub_runner()
-    apply_knobs(stub, {"async_depth": 4, "fetch_group": 3, "h2d_depth": 3})
-    assert stub._max_inflight == 3
-    assert stub._h2d_ahead == 2
-    assert stub.cfg.async_depth == 4
-    assert stub.cfg.fetch_group == 3
-    assert stub.cfg.h2d_depth == 3
-
-
-def test_apply_knobs_live_state_guard_wins():
-    """emissions_reference_state forces synchronous stepping at build
-    time; the controller may ask for depth, the guard still wins."""
-    stub, apply_knobs = _stub_runner(
-        program=types.SimpleNamespace(
-            emissions_reference_state=True, mesh=None
-        ),
-        _max_inflight=0,
-        _h2d_ahead=0,
-    )
-    apply_knobs(stub, {"async_depth": 4, "h2d_depth": 4})
-    assert stub._max_inflight == 0
-    assert stub._h2d_ahead == 0
-    # the cfg records the request; the live depths do not follow it
-    assert stub.cfg.async_depth == 4
-
-
-# ---------------------------------------------------------------------------
-# end to end: adaptive on vs off, single chip
-# ---------------------------------------------------------------------------
-
-
-def test_adaptive_controller_end_to_end_output_parity():
-    from tpustream import StreamExecutionEnvironment, Tuple2
-    from tpustream.runtime.sources import ReplaySource
-
-    def parse(line):
-        items = line.split(" ")
-        return Tuple2(items[1], int(items[2]))
-
-    lines = [f"1 k{i % 5} {(i * 7) % 97}" for i in range(60)]
-
-    def run(obs):
-        env = StreamExecutionEnvironment(
-            StreamConfig(batch_size=4, obs=obs)
-        )
-        handle = (
-            env.add_source(ReplaySource(lines))
-            .map(parse)
-            .key_by(0)
-            .sum(1)
-            .collect()
-        )
-        res = env.execute("adaptive-parity")
-        return [tuple(t) for t in handle.items], res
-
-    want, _ = run(ObsConfig(enabled=False))
-    got, res = run(ObsConfig(
-        enabled=True, adaptive=True, snapshot_interval_s=1e-4,
-        adaptive_cooldown_ticks=0,
-    ))
-    assert got == want  # knob moves never change output
-    snap = res.metrics.obs_snapshot()
-    names = {s["name"] for s in snap["metrics"]["series"]}
-    assert "controller_async_depth" in names
-    assert "controller_decisions_total" in names
-    evs = [
-        e for e in res.metrics.job_obs.flight.events()
-        if e["kind"] == "controller_decision"
-    ]
-    assert evs, "ticks at flood rate must produce at least one decision"
-    for e in evs:
-        assert e["action"] in ("probe", "keep", "revert", "backoff")

@@ -16,7 +16,7 @@ import pytest
 
 from tpustream import StreamExecutionEnvironment, TimeCharacteristic, Tuple2
 from tpustream.config import ObsConfig, StreamConfig
-from tpustream.runtime.sources import ReplaySource
+from tpustream.runtime.sources import AdvanceProcessingTime, ReplaySource
 
 # strictly synchronous reference: one batch in flight, no staging, no
 # compaction, no narrowing — every knob the tentpole added, off
@@ -24,7 +24,7 @@ SYNC = dict(
     async_depth=1, h2d_depth=1, compaction_capacity=0, packed_wire=False
 )
 # everything on, deeper than the defaults
-DEEP = dict(async_depth=4, h2d_depth=3, fetch_group=2)
+DEEP = dict(async_depth=4, h2d_depth=3)
 
 
 def parse(line: str) -> Tuple2:
@@ -92,9 +92,9 @@ def run_window(lines, **over):
         dict(async_depth=4, h2d_depth=3),
         dict(compaction_capacity=8),
         dict(packed_wire=False, h2d_depth=2),
-        DEEP,
+        dict(async_depth=8, h2d_depth=3),
     ],
-    ids=["defaults", "deep-h2d", "tight-compaction", "unpacked", "all-deep"],
+    ids=["defaults", "deep-h2d", "tight-compaction", "unpacked", "depth8"],
 )
 def test_rolling_parity_across_depths(variant):
     lines = rolling_lines()
@@ -104,12 +104,74 @@ def test_rolling_parity_across_depths(variant):
 
 
 @pytest.mark.parametrize(
-    "variant", [{}, DEEP], ids=["defaults", "all-deep"]
+    "variant",
+    [{}, DEEP] + [
+        dict(async_depth=a, h2d_depth=h) for a in (3, 8) for h in (1, 3)
+    ],
+    ids=["defaults", "all-deep", "depth3-h2d1", "depth3-h2d3",
+         "depth8-h2d1", "depth8-h2d3"],
 )
 def test_window_job_parity_across_depths(variant):
     want = run_window(CH3, **SYNC)
     got = run_window(CH3, **variant)
     assert got == want
+
+
+def median_lines():
+    """Three processing-time minutes of per-host cpu samples; each
+    minute's window fires at the clock advance that closes it."""
+    out = []
+    for m in range(3):
+        for i in range(12):
+            out.append(f"1 10.8.22.{i % 3} cpu0 {(31 * m + 7 * i) % 97}.5")
+        out.append(AdvanceProcessingTime((m + 1) * 61_000))
+    return out
+
+
+def run_median(lines, **over):
+    """The chapter-2 median: a full-window process(), whose emissions
+    the host evaluates against the live device state."""
+    from tpustream.jobs.chapter2_median import build
+
+    env = StreamExecutionEnvironment(StreamConfig(batch_size=4, **over))
+    handle = build(env, env.add_source(ReplaySource(lines))).collect()
+    env.execute("pipeline-parity-median")
+    return handle.items
+
+
+@pytest.mark.parametrize(
+    "cls, option", [(StreamConfig, "fetch_group"), (ObsConfig, "adaptive")]
+)
+def test_removed_pipeline_options_are_rejected(cls, option):
+    """The grouped fetch and the adaptive controller are gone: an old
+    configuration that sets them fails at construction instead of
+    running with another meaning."""
+    with pytest.raises(TypeError, match=option):
+        cls(**{option: 2})
+
+
+@pytest.mark.parametrize("depth", [2, 4])
+def test_live_state_job_keeps_no_step_in_flight(monkeypatch, depth):
+    """A program whose emissions read live state runs synchronously
+    at any configured depth: the constructor fixes both windows at 0,
+    no step is left in flight after a dispatch, and the rows are the
+    synchronous run's."""
+    from tpustream.runtime.executor import Runner
+
+    want = run_median(median_lines(), **SYNC)
+    run_step = Runner._run_step
+    seen = []  # (window, upload lead, steps in flight) after each step
+
+    def spy(runner, *args, **kw):
+        run_step(runner, *args, **kw)
+        seen.append(
+            (runner._max_inflight, runner._h2d_ahead, len(runner._inflight))
+        )
+
+    monkeypatch.setattr(Runner, "_run_step", spy)
+    got = run_median(median_lines(), async_depth=depth, h2d_depth=depth)
+    assert want and got == want
+    assert seen and set(seen) == {(0, 0, 0)}
 
 
 def test_final_checkpoint_identical_sync_vs_deep(tmp_path):

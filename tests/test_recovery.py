@@ -164,16 +164,14 @@ def test_every_fault_point_recovers(tmp_path):
 def test_deep_pipeline_fault_recovery_exactly_once(tmp_path):
     """device_step and sink_emit faults injected while the async
     pipeline is several batches deep — staged H2D uploads (h2d_depth),
-    a deep dispatch queue (async_depth), grouped count fetches, and
-    device-side compaction all in flight — must still recover
-    exactly-once from the latest checkpoint."""
+    a deep dispatch queue (async_depth), and device-side compaction
+    all in flight — must still recover exactly-once from the latest
+    checkpoint."""
     lines = [
         f"15634520{i:02d} 10.8.22.{i % 3} cpu{i % 2} {40 + (i * 17) % 55}.5"
         for i in range(16)
     ]
-    deep = dict(
-        async_depth=4, h2d_depth=3, fetch_group=2, compaction_capacity=64
-    )
+    deep = dict(async_depth=4, h2d_depth=3, compaction_capacity=64)
     _, sync_ref, _ = run_supervised(lines)
     _, full, _ = run_supervised(lines, **deep)
     assert full == sync_ref  # the pipeline itself is invisible

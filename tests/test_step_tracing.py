@@ -50,7 +50,7 @@ def run(obs, **over):
     return rows, res
 
 
-DEEP = dict(h2d_depth=2, async_depth=3, fetch_group=2)
+DEEP = dict(h2d_depth=2, async_depth=3)
 
 
 @pytest.fixture(scope="module")
@@ -112,8 +112,9 @@ def test_fetch_carries_the_last_step_of_its_group(traced):
     _, job_obs, _ = traced
     spans = spans_of(job_obs)
     # a step planned before the next dispatch records its own ``fetch``
-    # span then (its step's id); the group's completing ``fetch``, just
-    # before its emits, carries the group's last step
+    # span then (its step's id, or the last one's when it plans several
+    # finished steps); the retiring ``fetch``, just before the step's
+    # emit, carries that one step
     groups = []  # (steps of the fetch spans, steps emitted after them)
     for kind, step, _op, _t0, _dur in spans:
         if kind == "fetch":
@@ -123,13 +124,11 @@ def test_fetch_carries_the_last_step_of_its_group(traced):
         elif kind == "emit":
             groups[-1][1].append(step)
     assert groups
-    assert any(len(emitted) == 2 for _, emitted in groups)
     assert any(len(fetched) > 1 for fetched, _ in groups)
-    for fetched, emitted in groups:
-        assert emitted and len(emitted) <= 2
-        assert fetched[-1] == emitted[-1]
-        assert set(fetched[:-1]) <= set(emitted)
     dispatched = [s for k, s, *_ in spans if k == "dispatch"]
+    for fetched, emitted in groups:
+        assert emitted == fetched[-1:]  # one step retired per fetch
+        assert set(fetched) <= set(dispatched)
     emitted = [s for _, e in groups for s in e]
     assert emitted == dispatched  # each step emits once, in order
 
@@ -157,8 +156,8 @@ def test_bridged_events_twin_the_ring_on_one_clock(traced):
     _, job_obs, path = traced
     data = jax.profiler.ProfileData.from_file(path)
     # (kind, step) names one span, but for ``fetch``: a step planned
-    # before the next dispatch and its group's completing fetch can
-    # share a step id; such twins pair in time order
+    # before the next dispatch and its retiring fetch share a step id;
+    # such twins pair in time order
     twins = collections.defaultdict(list)
     for plane in data.planes:
         if not plane.name.startswith("/host:"):

@@ -77,8 +77,10 @@ class Rule:
 
 
 #: the full rule catalog, keyed by code. Codes are append-only: a rule
-#: that stops firing keeps its number (like E999 in linters), so saved
-#: baselines and suppression lists stay meaningful across versions.
+#: keeps its number (like E999 in linters), and a retired rule's number
+#: is never reused, so saved baselines and suppression lists stay
+#: meaningful across versions. Retired: TSM009, TSM011 and TSM012,
+#: with the grouped-fetch option and the adaptive controller they checked.
 CATALOG = {
     r.code: r
     for r in [
@@ -149,15 +151,6 @@ CATALOG = {
             "verify, changing only rule parameters per tenant",
         ),
         Rule(
-            "TSM009", WARN, "fetch_group exceeds the in-flight window",
-            "a fetch group equal to the full async_depth window drains "
-            "the pipeline empty on every grouped fetch, serializing "
-            "dispatch against the round trip it was meant to amortize; "
-            "the executor clamps the effective group to async_depth - 1.",
-            "raise async_depth alongside fetch_group (the effective "
-            "group is clamped to async_depth - 1)",
-        ),
-        Rule(
             "TSM010", INFO, "pipeline depth forced synchronous",
             "full-window process() emissions reference live device state "
             "and max_fires_per_step paces the step loop — either forces "
@@ -165,23 +158,6 @@ CATALOG = {
             "buy nothing for this plan.",
             "expect synchronous stepping, or restructure the window apply "
             "as reduce/aggregate to regain overlap",
-        ),
-        Rule(
-            "TSM011", ERROR, "adaptive controller misconfiguration",
-            "an adaptive_bounds entry with lo > hi (or lo < 1) can never "
-            "admit a legal knob value; unknown knob names are silently "
-            "ignored; the controller needs live obs to read rate history.",
-            "fix the (lo, hi) bounds, name only async_depth/fetch_group/"
-            "h2d_depth, and enable obs when adaptive=True",
-        ),
-        Rule(
-            "TSM012", INFO, "grouped fetch coarsens step_ms_p90",
-            "with fetch_group > 1 the blocking wait of one grouped fetch "
-            "is divided evenly over its G steps, so step_times_s (and the "
-            "step_ms_p90 summary) report per-group averages, not true "
-            "per-step latencies — tails are smoothed by up to G×.",
-            "interpret step_ms_p90 as a per-group average, or set "
-            "fetch_group=1 when profiling per-step tails",
         ),
         Rule(
             "TSM013", ERROR, "side output reads a tag its stream never emits",

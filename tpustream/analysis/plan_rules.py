@@ -508,20 +508,6 @@ def check_tenant_chain_matches_template(ctx) -> Iterable[Finding]:
 # -- config-consistency rules ------------------------------------------------
 
 @rule
-def check_fetch_group_vs_async_depth(ctx) -> Iterable[Finding]:
-    """TSM009: fetch_group past the in-flight window gets clamped."""
-    cfg = ctx.cfg
-    limit = max(1, cfg.async_depth - 1)
-    if cfg.fetch_group > limit:
-        yield make_finding(
-            "TSM009", None,
-            f"fetch_group={cfg.fetch_group} exceeds async_depth-1="
-            f"{limit}: the effective group is clamped to {limit} (a "
-            "full-window group would drain the pipeline every fetch)",
-        )
-
-
-@rule
 def check_depth_forced_synchronous(ctx) -> Iterable[Finding]:
     """TSM010: configured overlap depths that this plan forces to 1."""
     cfg = ctx.cfg
@@ -543,48 +529,6 @@ def check_depth_forced_synchronous(ctx) -> Iterable[Finding]:
             f"configured, but {reason}: the runtime forces depth 1 for "
             "this plan",
         )
-
-
-@rule
-def check_adaptive_bounds(ctx) -> Iterable[Finding]:
-    """TSM011: adaptive controller bounds that cannot work."""
-    obs = ctx.cfg.obs
-    if not getattr(obs, "adaptive", False):
-        return
-    if not obs.enabled:
-        yield make_finding(
-            "TSM011", None,
-            "adaptive=True with obs.enabled=False: the controller reads "
-            "the registry's rate history and never runs without obs",
-            severity=WARN,
-        )
-    bounds = getattr(obs, "adaptive_bounds", None) or {}
-    known = ("async_depth", "fetch_group", "h2d_depth")
-    for knob, bound in bounds.items():
-        try:
-            lo, hi = bound
-        except Exception:
-            yield make_finding(
-                "TSM011", None,
-                f"adaptive_bounds[{knob!r}]={bound!r} is not a (lo, hi) "
-                "pair",
-            )
-            continue
-        if knob not in known:
-            yield make_finding(
-                "TSM011", None,
-                f"adaptive_bounds names unknown knob {knob!r} (the knob "
-                f"set is closed: {', '.join(known)}); it is silently "
-                "ignored",
-                severity=WARN,
-            )
-            continue
-        if lo > hi or lo < 1:
-            yield make_finding(
-                "TSM011", None,
-                f"adaptive_bounds[{knob!r}]=({lo}, {hi}) admits no legal "
-                "value (need 1 <= lo <= hi)",
-            )
 
 
 @rule
@@ -621,22 +565,6 @@ def check_health_rule_series_exist(ctx) -> Iterable[Finding]:
                 "which no instrument mints: it evaluates \"absent\" "
                 "forever and the alert can never fire",
             )
-
-
-@rule
-def check_grouped_fetch_skew(ctx) -> Iterable[Finding]:
-    """TSM012: fetch_group > 1 coarsens the step-latency series."""
-    cfg = ctx.cfg
-    eff = max(1, min(cfg.fetch_group, max(1, cfg.async_depth - 1)))
-    if eff > 1 and cfg.obs.enabled:
-        yield make_finding(
-            "TSM012", None,
-            f"fetch_group={eff} (effective): one grouped fetch's "
-            "blocking wait is divided evenly over its steps, so "
-            "step_times_s / step_ms_p90 report per-group averages "
-            "(tails smoothed up to "
-            f"{eff}x) — see docs/observability.md",
-        )
 
 
 @rule

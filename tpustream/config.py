@@ -147,29 +147,6 @@ class ObsConfig:
     # re-derive + verify them (ledger_restore_digest_mismatch). One hash
     # update per emitted row — turn off to keep counting-only ledgers.
 
-    # -- adaptive pipeline controller (runtime/controller.py) ---------------
-    adaptive: bool = False
-    # master switch, STRICTLY off by default: at snapshot ticks an
-    # AdaptiveController hill-climbs async_depth/fetch_group/h2d_depth
-    # (the barrier-safe overlap depths — never semantics-bearing config)
-    # toward higher windowed ingest rate under the p99 bound below.
-    # Changes apply only at drained barriers; output bytes never change.
-    # Forced off under multi-host execution.
-    adaptive_bounds: Optional[dict] = None
-    # {knob: (lo, hi)} per-knob search bounds; None = controller
-    # defaults (runtime/controller.py DEFAULT_BOUNDS). Unknown knob
-    # names are ignored — the knob set is closed.
-    adaptive_cooldown_ticks: int = 2
-    # settle ticks between moves: each probe is judged against a
-    # baseline measured after the previous change took effect
-    adaptive_hysteresis: float = 0.05
-    # a probe is kept only if the objective improved by more than this
-    # fraction — measurement noise can't walk the knobs
-    adaptive_p99_ms: float = 300.0
-    # latency guard (ROADMAP's "sustainable-rate p99 under 300 ms"):
-    # probes that push e2e p99 past this revert; a steady-state breach
-    # steps every depth down one notch
-
     def replace(self, **kw) -> "ObsConfig":
         import dataclasses
 
@@ -282,28 +259,9 @@ class StreamConfig:
     # Sink output order is unchanged; only its wall-clock moment shifts.
     # Programs whose emissions are evaluated against live device state
     # (full-window process()) force depth 1. Raise past 2 when the
-    # host-device round trip exceeds a step's device time.
-
-    fetch_group: int = 1
-    # How many in-flight steps' emission-COUNT scalars fetch in ONE
-    # device_get round trip. 1 (default) fetches per step — right for
-    # PCIe hosts where a round trip is microseconds and per-step counts
-    # let the executor skip batch-sized emission buffers immediately.
-    # Where a host-device round trip is slow, the per-step scalar
-    # fetch becomes the binding full-path stage; grouping G steps
-    # amortizes that round trip G-ways. No emission dispatches later
-    # than at G=1 — the oldest in-flight entry finishes at the same
-    # feed either way and the rest finish earlier; the costs are a
-    # longer blocking wait per finish call and an effective in-flight
-    # depth that oscillates by G. Capped by what is actually in
-    # flight, so paced sources (which drain synchronously) are
-    # unaffected. Results are byte-identical either way — only
-    # wall-clock dispatch time shifts.
-    # The executor clamps the EFFECTIVE group to async_depth - 1 (at
-    # least 1): a group equal to the full in-flight window would drain
-    # the pipeline empty on every fetch, silently serializing dispatch
-    # against the round trip it was meant to amortize (ADVICE r5). Ask
-    # for a bigger group by raising async_depth alongside fetch_group.
+    # host-device round trip exceeds a step's device time. Fixed for
+    # the job's life, like h2d_depth: each dispatch past the window
+    # retires the oldest step in flight, one step per fetch.
 
     ingest_lanes: int = 1
     # Sharded host ingestion (runtime/ingest.py): > 1 splits source
@@ -389,7 +347,7 @@ class StreamConfig:
     # on the device ahead of the step that consumes them. 1 = the
     # classic path (the transfer rides the step call). 2 (default) =
     # double-buffered H2D: batch N+1's device_put is issued before batch
-    # N's step group fetch blocks, so its transfer crosses the wire
+    # N's fetch blocks, so its transfer crosses the wire
     # while the host waits on N's emission counts. Staged batches are
     # flushed at every pipeline barrier (checkpoint, rule update, key
     # growth, paced-source idle, end of stream), so checkpoint/recovery
@@ -466,28 +424,10 @@ class StreamConfig:
 
         Returns ``(resolved_cfg, notes)`` where each note is a dict
         ``{knob, requested, effective, reason}``; the executor records
-        one ``config_clamped`` flight breadcrumb per note. Currently one
-        constraint: ``fetch_group`` is clamped to ``async_depth - 1``
-        (at least 1) — a group spanning the full in-flight window would
-        drain the pipeline empty on every grouped fetch, serializing
-        dispatch against the round trip it exists to amortize (ADVICE
-        r5). The runtime keeps its live per-step clamp too (the
-        adaptive controller can move async_depth under a running job).
+        one ``config_clamped`` flight breadcrumb per note.
         """
         notes: list = []
-        limit = max(1, self.async_depth - 1)
-        eff = max(1, min(self.fetch_group, limit))
         cfg = self
-        if eff != self.fetch_group:
-            notes.append({
-                "knob": "fetch_group",
-                "requested": self.fetch_group,
-                "effective": eff,
-                "reason": f"clamped to async_depth-1={limit}: a "
-                          "full-window fetch group drains the pipeline "
-                          "on every grouped fetch",
-            })
-            cfg = self.replace(fetch_group=eff)
         if self.ingest_lanes < 1:
             notes.append({
                 "knob": "ingest_lanes",

@@ -12,7 +12,7 @@ Two tiers:
   runtime/obs/tenancy modules;
 * ``KNOWN_PATTERNS`` — families minted with computed names (per-sink
   latency histograms, operator-scoped instruments, per-state-component
-  gauges, controller knob gauges).
+  gauges).
 
 Keep this file in sync when adding an instrument: the TSM015 tests
 (tests/test_analysis.py) pin a sample of both tiers.
@@ -69,9 +69,6 @@ KNOWN_SERIES = frozenset({
     "restore_drill_failures_total",
     # health / SLO engine
     "health_rule_state", "slo_budget_burn",
-    # adaptive controller
-    "controller_decisions_total", "controller_reverts_total",
-    "controller_objective_rows_per_s", "controller_p99_ms",
     # continuous profiler
     "profile_stage_ms", "profile_stage_share", "profile_occupancy",
     "profile_binding_stage", "profile_spans_dropped",
@@ -102,7 +99,6 @@ KNOWN_PATTERNS = tuple(re.compile(p) for p in (
     r"side_sink.+_emitted",      # side-output sinks, keyed by tag id
     r"operator_[a-z0-9_]+",      # operator-scoped instruments
     r"state_[a-z0-9_]+",         # per-state-component HBM gauges
-    r"controller_[a-z0-9_]+",    # one gauge per adaptive knob
 ))
 
 

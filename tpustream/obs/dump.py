@@ -1288,12 +1288,6 @@ def _selftest() -> int:
     g.counter("compaction_spills").inc(1)
     g.gauge("compaction_ratio").set(0.015625)
     g.gauge("pipeline_occupancy").set_fn(lambda: 3)
-    # controller series surface (runtime/controller.py mints these; the
-    # algorithm itself is exercised in tests/test_obs_timeseries.py —
-    # importing it here would pull the tpustream package root)
-    g.gauge("controller_async_depth").set(3)
-    g.gauge("controller_objective_rows_per_s").set(123456.0)
-    g.counter("controller_decisions_total").inc(4)
     # sharded-ingestion series (runtime/ingest.py, docs/performance.md):
     # per-lane parse counters / ring-occupancy gauges plus the merge
     # stall histogram the IngestPlane mints through the same group path
@@ -1329,8 +1323,8 @@ def _selftest() -> int:
     og.histogram("tenant_e2e_latency_ms").observe(4.0)
     # pre-flight analysis series (docs/analysis.md): per-code finding
     # counters the executor mints when the analyzer reports
-    g.group(code="TSM009").counter("analysis_findings_total").inc()
-    g.group(code="TSM012").counter("analysis_findings_total").inc()
+    g.group(code="TSM010").counter("analysis_findings_total").inc()
+    g.group(code="TSM013").counter("analysis_findings_total").inc()
     # schema-inference (TSM03x) and checkpoint-audit (TSM04x) codes land
     # through the same per-code counter path
     g.group(code="TSM030").counter("analysis_findings_total").inc()
@@ -1517,11 +1511,6 @@ def _selftest() -> int:
          and dump["events"][-1]["operator"] == "window"),
         ("flight dump serializes", bool(_json.dumps(dump))),
         ("snapshot serializes", bool(_json.dumps(snap))),
-        ("prometheus carries the controller series",
-         'controller_async_depth{job="selftest"} 3' in prom
-         and 'controller_decisions_total{job="selftest"} 4' in prom
-         and 'controller_objective_rows_per_s{job="selftest"} 123456'
-         in prom),
         ("render names the tenancy series",
          "tenant_count" in text and "tenant_records_total" in text),
         ("prometheus carries the per-tenant labels",
@@ -1565,8 +1554,8 @@ def _selftest() -> int:
         ("render names the analysis findings counter",
          "analysis_findings_total" in text),
         ("prometheus carries the per-code analysis findings",
-         'analysis_findings_total{code="TSM009",job="selftest"} 1' in prom
-         and 'analysis_findings_total{code="TSM012",job="selftest"} 1'
+         'analysis_findings_total{code="TSM010",job="selftest"} 1' in prom
+         and 'analysis_findings_total{code="TSM013",job="selftest"} 1'
          in prom),
         ("prometheus carries the schema and audit finding codes",
          'analysis_findings_total{code="TSM030",job="selftest"} 1' in prom

@@ -13,7 +13,7 @@ stage and, at every snapshot tick, turns the lookback window into:
   offline decomposition's attribution;
 * the **binding stage** (largest share) as a live gauge
   (``profile_binding_stage``, valued by SPAN_KINDS index) — the signal
-  the adaptive controller and a dashboard alert both want;
+  a dashboard alert wants;
 * **occupancy** — summed stage time divided by the wall-clock span the
   samples cover. Under a well-overlapped pipeline this exceeds 1.0
   (stages run concurrently); ~1.0 means serialized; far below 1.0 means

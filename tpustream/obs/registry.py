@@ -17,9 +17,9 @@ same method surface, no state, no work.
 Every registry-minted instrument also carries a bounded
 :class:`~tpustream.obs.timeseries.TimeSeries` history (``inst.history``)
 recorded on writes, so windowed ``rate()``/``delta()``/``mean()``/
-``quantile()`` are available in-process — the profiler and the adaptive
-controller read these. Each series remembers its last-write timestamp
-(``_last_t``, registry clock), which the snapshot (``ts_ms``) and
+``quantile()`` are available in-process — the snapshot's
+``rate_per_s`` reads these. Each series remembers its last-write
+timestamp (``_last_t``, registry clock), which the snapshot (``ts_ms``) and
 Prometheus exposition (trailing millisecond timestamp) surface so a
 scrape-side consumer can compute rates too. Pulled (``set_fn``) gauges
 record history and refresh their timestamp only on explicit ``set()``
@@ -328,12 +328,6 @@ class _NullInstrument:
         return []
 
     def percentile(self, q: float) -> float:
-        return 0.0
-
-    def rate(self, window_s=None, now=None) -> float:
-        return 0.0
-
-    def quantile(self, q, window_s=None, now=None) -> float:
         return 0.0
 
 

@@ -39,7 +39,7 @@ class OperatorObs:
         self.records_emitted = group.counter("operator_records_emitted")
         self.steps = group.counter("operator_steps")
         # async enqueue time (the _run_step stopwatch) vs the blocking
-        # fetch wait (_finish_group, divided per step) — together they
+        # fetch wait (_retire and _prepare_fetch) — together they
         # are the job-level step_times_s series, split by phase here
         self.dispatch_time_s = group.histogram(
             "operator_dispatch_time_s", max_samples=self._hist_samples

@@ -593,8 +593,8 @@ class Runner:
         # -- double-buffered H2D (StreamConfig.h2d_depth) -----------------
         # packed batches stage onto the device via an async device_put
         # up to _h2d_ahead steps before the step that consumes them, so
-        # batch N+1's transfer crosses the wire while batch N's group
-        # fetch blocks the host. Forced synchronous (ahead = 0) under
+        # batch N+1's transfer crosses the wire while batch N's fetch
+        # blocks the host. Forced synchronous (ahead = 0) under
         # multi-host (the gshard path IS the transfer), for programs
         # whose emissions read live state, and when max_fires_per_step
         # interleaves drain steps with fed batches (a staged batch would
@@ -1532,8 +1532,7 @@ class Runner:
         self.obs.dispatch_time_s.observe(sw.elapsed)
         # markers ride this step's inflight entry: their source->edge
         # latency is recorded when the entry's emissions dispatch, so
-        # pipelining delay (async_depth, fetch_group) is measured, not
-        # hidden
+        # pipelining delay (async_depth) is measured, not hidden
         # detach, never alias: an empty ``_pending_markers`` must not ride
         # the entry as a live reference, or markers accepted while this
         # step is in flight would appear in it retroactively AND drain
@@ -1558,26 +1557,8 @@ class Runner:
         ))
         self.obs.inflight.set(len(self._inflight))
         while len(self._inflight) > self._max_inflight:
-            g = self._fetch_group
-            self._finish_group(self._inflight[:g])
-            del self._inflight[:g]
-
-    @property
-    def _fetch_group(self) -> int:
-        """Steps retired together, their rows (and the count scalars of
-        those not planned ahead) in one device_get round trip
-        (StreamConfig.fetch_group; >1 amortizes a high-latency link's
-        RTT). Multi-host keeps the per-step cadence: the fetch decision
-        drives collective-bearing paths and must stay step-aligned.
-
-        Clamped to the in-flight window minus one (= async_depth - 1,
-        at least 1): a group covering the FULL window would drain the
-        pipeline empty on every fetch — no step left in flight to
-        overlap the next round trip — silently serializing the very
-        path fetch_group exists to pipeline (ADVICE r5)."""
-        if self._multiproc:
-            return 1
-        return max(1, min(self.cfg.fetch_group, max(1, self._max_inflight)))
+            self._retire(self._inflight[0])
+            del self._inflight[0]
 
     @property
     def _slices_fetch(self) -> bool:
@@ -1597,53 +1578,9 @@ class Runner:
         end of stream). Staged uploads step first — their batches are
         consumed-but-unstepped and a barrier must settle them too."""
         self._flush_uploads()
-        if self._inflight:
-            entries, self._inflight = self._inflight, []
-            g = self._fetch_group
-            for s in range(0, len(entries), g):
-                self._finish_group(entries[s : s + g])
-
-    def apply_knobs(self, knobs: dict) -> None:
-        """Apply barrier-safe pipeline-depth knobs (async_depth,
-        fetch_group, h2d_depth) at a DRAINED barrier — the adaptive
-        controller's application point, using the same quiesce-then-
-        mutate pattern as rule updates. The caller must have drained the
-        chain: queues are empty here, so the new depths simply take
-        effect on the next feed. Every constructor-forced synchronous
-        mode (multi-host, live-state emissions, max_fires_per_step
-        pacing) stays forced — the controller can ask, but the build-time
-        guards still win, so output bytes never depend on a knob."""
-        kw = {}
-        if "async_depth" in knobs:
-            d = max(1, int(knobs["async_depth"]))
-            if d != self.cfg.async_depth:
-                kw["async_depth"] = d
-            if not self.program.emissions_reference_state:
-                self._max_inflight = max(0, d - 1)
-        if "fetch_group" in knobs:
-            g = max(1, int(knobs["fetch_group"]))
-            if g != self.cfg.fetch_group:
-                kw["fetch_group"] = g  # read live via the property
-        if "h2d_depth" in knobs:
-            d = max(1, int(knobs["h2d_depth"]))
-            if d != self.cfg.h2d_depth:
-                kw["h2d_depth"] = d
-            stage_ok = (
-                not self._multiproc
-                and not self.program.emissions_reference_state
-                and self.cfg.max_fires_per_step is None
-            )
-            self._h2d_ahead = max(0, d - 1) if stage_ok else 0
-            if self._h2d_ahead and self._h2d_sharding is None:
-                mesh = getattr(self.program, "mesh", None)
-                if mesh is not None:
-                    from jax.sharding import NamedSharding, PartitionSpec as P
-
-                    from ..parallel.mesh import AXIS
-
-                    self._h2d_sharding = NamedSharding(mesh, P(AXIS))
-        if kw:
-            self.cfg = self.cfg.replace(**kw)
+        entries, self._inflight = self._inflight, []
+        for e in entries:
+            self._retire(e)
 
     # -- latency markers (obs/latency.py) ----------------------------------
 
@@ -2161,21 +2098,19 @@ class Runner:
                 )
 
     def _prepare_ahead(self):
-        """Plan, before the next dispatch, the fetch of each step in
-        flight that this dispatch retires, and of each other one that
-        has finished on the device. The chip runs programs in launch
+        """Plan, before the next dispatch, the fetch of the oldest step
+        in flight when this dispatch retires it, and of each other one
+        that has finished on the device. The chip runs programs in launch
         order, so a slice launched after the dispatch would wait for
         that whole step. A step still running that this dispatch does
         not retire (a deep pipeline the device, not the host, paces) is
         planned when it is retired, its slices then behind the later
         steps (``fetch_slices_behind_step_total``)."""
-        n, retired = len(self._inflight) + 1, 0
-        while n - retired > self._max_inflight:
-            retired += self._fetch_group
+        retires = len(self._inflight) >= self._max_inflight
         todo = [
             e
             for i, e in enumerate(self._inflight)
-            if e.fetch is None and (i < retired or e.finished())
+            if e.fetch is None and ((i == 0 and retires) or e.finished())
         ]
         if todo:
             self._prepare_fetch(todo)
@@ -2184,9 +2119,9 @@ class Runner:
         """First half of the fetch of steps still in flight, run just
         before the next step's dispatch: plan them with their exact
         counts, launch their slices (ahead of that step on the chip)
-        and start the slices' copies to the host. The next
-        _finish_group of these entries then only collects rows already
-        computed. Timed as its own ``fetch`` span."""
+        and start the slices' copies to the host. The _retire of each
+        entry then only collects rows already computed. Timed as its own
+        ``fetch`` span."""
         with self.obs.span("fetch", entries[-1].step), Stopwatch() as sw:
             self._plan_entries(entries, speculate=False)
             for e in entries:
@@ -2200,50 +2135,38 @@ class Runner:
                     m.add_span("fetch", t0=sw.t0, dur=sw.elapsed,
                                group=len(entries))
 
-    def _finish_group(self, entries):
-        # the blocking wait on the device lives here and in
-        # _prepare_fetch, not in _run_step (dispatch is async): the
-        # ``fetch`` span times it, one span per group, carrying the
-        # group's last step. step_times_s gets both halves' waits
-        # divided over the group's entries, beside the dispatch walls
-        # _run_step appends, so it is not the device's time.
-        # Entries not yet planned (no later step was dispatched: the
-        # synchronous path, a drain) read their count scalars in ONE
-        # device_get here; then all still-needed emission streams fetch
-        # in a second one; dispatch order is unchanged.
-        with self.obs.span("fetch", entries[-1].step), Stopwatch() as sw:
-            todo = [e for e in entries if e.fetch is None]
-            if todo:
-                self._plan_entries(todo, speculate=True)
-            fetches = [e.fetch for e in entries]
-            if not any(fetches):
-                fetched_list = [{} for _ in fetches]
+    def _retire(self, entry: _Inflight):
+        """Fetch one step's emissions and hand them to the sinks. The
+        blocking wait on the device lives here and in _prepare_fetch,
+        not in _run_step (dispatch is async): the ``fetch`` span times
+        it. step_times_s gets both halves' waits, beside the dispatch
+        walls _run_step appends, so it is not the device's time. An
+        entry not yet planned (no later step was dispatched: the
+        synchronous path, a drain) reads its count scalars here, then
+        its still-needed emission streams in a second device_get."""
+        with self.obs.span("fetch", entry.step), Stopwatch() as sw:
+            if entry.fetch is None:
+                self._plan_entries([entry], speculate=True)
+            if not entry.fetch:
+                fetched = {}
             elif self._multiproc:
-                fetched_list = [
-                    self._fetch_local(f) if f else {} for f in fetches
-                ]
+                fetched = self._fetch_local(entry.fetch)
             else:
-                fetched_list = jax.device_get(fetches)
+                fetched = jax.device_get(entry.fetch)
         if self.obs.enabled:
-            self._account_fetch(entries, fetches, fetched_list)
-        # one sample PER STEP, not per fetch group: the group's blocking
-        # wait divides evenly across its entries, so the histogram's
-        # percentiles stay comparable across fetch_group settings while
-        # the sum (summary()'s device_time_s) is unchanged (ADVICE r5)
-        waits = [sw.elapsed / len(entries) + e.wait_s for e in entries]
-        self.metrics.step_times_s.extend(waits)
-        self.obs.step_time_s.observe_many(waits)
-        for entry, fetched in zip(entries, fetched_list):
-            fetched.update(entry.fetched)
-            for m in entry.markers:
-                if getattr(m, "trace_id", 0):
-                    m.add_span("fetch", t0=sw.t0, dur=sw.elapsed,
-                               group=len(entries))
-            self._dispatch(fetched, entry.t_batch, entry.step)
-            if entry.markers:
-                self._record_markers(entry.markers)
+            self._account_fetch(entry, fetched)
+        wait = sw.elapsed + entry.wait_s
+        self.metrics.step_times_s.append(wait)
+        self.obs.step_time_s.observe(wait)
+        fetched.update(entry.fetched)
+        for m in entry.markers:
+            if getattr(m, "trace_id", 0):
+                m.add_span("fetch", t0=sw.t0, dur=sw.elapsed)
+        self._dispatch(fetched, entry.t_batch, entry.step)
+        if entry.markers:
+            self._record_markers(entry.markers)
 
-    def _account_fetch(self, entries, fetches, fetched_list):
+    def _account_fetch(self, entry: _Inflight, fetched: dict):
         """fetch_bytes_total / compaction_ratio bookkeeping (obs-enabled
         runs only): actually-fetched bytes vs what the same streams
         would have cost as full [B] buffers. Ratio < 1 means the
@@ -2256,14 +2179,11 @@ class Runner:
                 if hasattr(a, "nbytes")
             )
 
-        fetched_b = sum(nbytes(f) for f in fetched_list)
         # the count scalars fetch every step regardless
-        fetched_b += sum(4 * len(e.counts) for e in entries)
+        fetched_b = nbytes(fetched) + 4 * len(entry.counts)
         self._fetch_bytes.inc(fetched_b)
         full_b = sum(
-            nbytes(entry.emissions.get(name))
-            for entry, plan in zip(entries, fetches)
-            for name in plan
+            nbytes(entry.emissions.get(name)) for name in entry.fetch
         )
         if full_b:
             self._compaction_gauge.set(fetched_b / full_b)
@@ -2978,21 +2898,6 @@ def _execute_job(env, sink_nodes) -> JobResult:
                     node=repr(f.node) if f.node is not None else None,
                     message=f.message,
                 )
-    # adaptive pipeline controller (runtime/controller.py): opt-in
-    # closed-loop tuning of the barrier-safe overlap depths at snapshot
-    # ticks. Requires live obs (it reads the registry's series history)
-    # and single-host execution — locally-timed decisions would diverge
-    # across processes and desynchronize the collective schedule.
-    controller = None
-    if job_obs.enabled and getattr(cfg.obs, "adaptive", False):
-        if jax.process_count() == 1:
-            from .controller import AdaptiveController
-
-            controller = AdaptiveController(cfg, job_obs)
-        else:
-            job_obs.flight.record(
-                "controller_disabled", reason="multiprocess"
-            )
     # dead-letter quarantine output (StreamConfig.dead_letter); lives on
     # the env so it survives restarts and the user reads it after execute
     dead_letters = getattr(env, "dead_letters", None)
@@ -3767,15 +3672,7 @@ def _execute_job(env, sink_nodes) -> JobResult:
                     jump_ms=wm_now - wm_prev,
                 )
             wm_prev = wm_now
-        tick_snap = job_obs.maybe_snapshot()
-        if controller is not None and tick_snap is not None and runner is not None:
-            knobs = controller.on_tick()
-            if knobs:
-                # quiesce first: depth changes land between fully
-                # retired steps, so output bytes never depend on them
-                runner.drain_chain(proc_now)
-                for r in runner.chain():
-                    r.apply_knobs(knobs)
+        job_obs.maybe_snapshot()
         if sb.proc_ts.size:
             proc_now = max(proc_now, int(sb.proc_ts.max()))
         if sb.advance_proc_to is not None:
