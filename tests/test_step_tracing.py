@@ -1,7 +1,7 @@
 """Step-tagged tracing of the flagship event-time job: every span a
 pulled batch records carries that batch's step id from the source pull
 to the sink, ``parse`` holds the native call and the id remap as
-sub-spans, the bridged profiler events are the ring's spans one to one
+sub-spans and ``emit`` the column conversion, the bridged profiler events are the ring's spans one to one
 on a fixed clock offset, and the compiled step's named scopes come back
 as an op-to-scope map. With obs off nothing is recorded."""
 
@@ -152,6 +152,21 @@ def test_parse_sub_spans_nest_inside_parse(traced):
         assert lo <= t0 and t0 + d <= hi
 
 
+def test_emit_format_nests_inside_its_emit(traced):
+    """``emit.format`` (the main stream's fetched columns converted to
+    Python values) sits inside the ``emit`` span of its step, once for
+    each emit that handed rows to the sink."""
+    _, job_obs, _ = traced
+    spans = spans_of(job_obs)
+    emits = {s: (t0, t0 + d) for k, s, _o, t0, d in spans if k == "emit"}
+    fmts = [(s, t0, d) for k, s, _o, t0, d in spans if k == "emit.format"]
+    assert fmts
+    assert len({s for s, _, _ in fmts}) == len(fmts)
+    for s, t0, d in fmts:
+        lo, hi = emits[s]
+        assert lo <= t0 and t0 + d <= hi
+
+
 def test_bridged_events_twin_the_ring_on_one_clock(traced):
     _, job_obs, path = traced
     data = jax.profiler.ProfileData.from_file(path)
@@ -221,12 +236,15 @@ def test_profiler_leaves_waits_and_sub_spans_out_of_stage_shares():
     t = [100.0]
     prof = PipelineProfiler(tr, window_s=1e6, clock=lambda: t[0])
     for kind, dur in (("source", 5.0), ("parse", 2.0), ("parse.native", 1.5),
-                      ("parse.remap", 0.4), ("dispatch", 2.0)):
+                      ("parse.remap", 0.4), ("dispatch", 2.0),
+                      ("emit.format", 0.3)):
         tr._record(kind, 1, "", tr.epoch, dur)
     t[0] = tr.epoch + 10.0
     p = prof.profile()
-    assert set(p["waits"]) == {"source", "parse.native", "parse.remap"}
+    assert set(p["waits"]) == {"source", "parse.native", "parse.remap",
+                               "emit.format"}
     assert "source" not in p["stages"] and "parse.native" not in p["stages"]
+    assert "emit.format" not in p["stages"]
     assert p["stages"]["parse"]["share"] == pytest.approx(0.5)
     assert sum(s["share"] for s in p["stages"].values()) == pytest.approx(1.0)
     assert p["waits"]["source"]["total_ms"] == pytest.approx(5000.0)

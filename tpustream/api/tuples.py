@@ -76,19 +76,50 @@ def _java_str(v: Any) -> str:
     return str(v)
 
 
+_new = object.__new__
+
+
 class Tuple2(TupleBase):
     ARITY = 2
     _FIELDS = ("f0", "f1")
+
+    @staticmethod
+    def trusted(f0, f1) -> "Tuple2":
+        """``Tuple2(f0, f1)`` without the arity check and the generic
+        field loop: the host's emit path builds one per alert row."""
+        t = _new(Tuple2)
+        t.f0 = f0
+        t.f1 = f1
+        return t
 
 
 class Tuple3(TupleBase):
     ARITY = 3
     _FIELDS = ("f0", "f1", "f2")
 
+    @staticmethod
+    def trusted(f0, f1, f2) -> "Tuple3":
+        """``Tuple3(f0, f1, f2)``, built as :meth:`Tuple2.trusted`."""
+        t = _new(Tuple3)
+        t.f0 = f0
+        t.f1 = f1
+        t.f2 = f2
+        return t
+
 
 class Tuple4(TupleBase):
     ARITY = 4
     _FIELDS = ("f0", "f1", "f2", "f3")
+
+    @staticmethod
+    def trusted(f0, f1, f2, f3) -> "Tuple4":
+        """``Tuple4(f0, f1, f2, f3)``, built as :meth:`Tuple2.trusted`."""
+        t = _new(Tuple4)
+        t.f0 = f0
+        t.f1 = f1
+        t.f2 = f2
+        t.f3 = f3
+        return t
 
 
 TUPLE_CLASSES = {2: Tuple2, 3: Tuple3, 4: Tuple4}

@@ -32,6 +32,7 @@ KNOWN_SERIES = frozenset({
     "chain_buffer_entries", "exchange_buffer_bytes", "exchange_capacity_rows",
     "compaction_ratio", "compaction_spills", "latency_markers_emitted",
     "fetch_slices_total", "fetch_slices_behind_step_total",
+    "emit_rows_batched_total",
     # native parse (native/): ids appended to the intern remap
     "intern_new_ids_total",
     # sharded ingestion (runtime/ingest.py), lane-labelled

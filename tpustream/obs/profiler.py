@@ -19,8 +19,8 @@ stage and, at every snapshot tick, turns the lookback window into:
   (stages run concurrently); ~1.0 means serialized; far below 1.0 means
   the pipeline is starved (source-bound).
 
-The ``source`` wait and the sub-spans nested inside ``parse``
-(``NON_STAGE_KINDS``) are not stage work: they are reported under
+The ``source`` wait and the sub-spans nested inside ``parse`` and
+``emit`` (``NON_STAGE_KINDS``) are not stage work: they are reported under
 ``waits`` with their own n/ms, and left out of the shares and the
 occupancy, so a parse is not counted twice and a starved pipeline
 still reads as starved.

@@ -24,7 +24,9 @@ that batch's ``source``, ``parse``, ``pack``, ``h2d``, ``dispatch`` and
 its group, and steps with no batch (deferred fires, end of stream) take
 fresh ids when they are dispatched. ``parse.native`` and
 ``parse.remap`` are sub-spans nested inside ``parse`` (the native
-tokenize + C-side intern, and the id remap into the plan's tables).
+tokenize + C-side intern, and the id remap into the plan's tables), and
+``emit.format`` nests inside ``emit`` (converting the main stream's
+fetched columns into Python values, before the sinks take the rows).
 Under the bridge each annotation carries ``step=`` (and ``emit`` its
 ``rows=``) as trace-event stats, so ring spans and trace events can be
 joined one to one.
@@ -40,16 +42,18 @@ from typing import List, Optional
 
 # "lane_parse" is the ingest-lane worker's parse span (runtime/ingest.py
 # re-records it at the merge point with the worker-measured duration);
-# "source" is the executor's wait for its next source batch, and the
-# two "parse." kinds nest inside "parse". New kinds are appended LAST
-# so the profiler's binding-stage gauge keeps its historical index
-# values for the original stages.
+# "source" is the executor's wait for its next source batch, the two
+# "parse." kinds nest inside "parse", and "emit.format" inside "emit".
+# New kinds are appended LAST so the profiler's binding-stage gauge
+# keeps its historical index values for the original stages.
 SPAN_KINDS = ("parse", "pack", "h2d", "dispatch", "fetch", "emit",
-              "lane_parse", "source", "parse.native", "parse.remap")
-# spans that time a wait (source) or part of another span (parse.*):
-# the profiler leaves them out of stage shares and occupancy, so no
-# host time is counted twice and idle time does not read as work
-NON_STAGE_KINDS = ("source", "parse.native", "parse.remap")
+              "lane_parse", "source", "parse.native", "parse.remap",
+              "emit.format")
+# spans that time a wait (source) or part of another span (parse.*,
+# emit.format): the profiler leaves them out of stage shares and
+# occupancy, so no host time is counted twice and idle time does not
+# read as work
+NON_STAGE_KINDS = ("source", "parse.native", "parse.remap", "emit.format")
 
 
 class _Span:

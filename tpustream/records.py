@@ -59,9 +59,10 @@ class StringTable:
     def lookup(self, i: int) -> str:
         return self._to_str[i]
 
-    def lookup_many(self, ids: np.ndarray) -> List[str]:
-        table = self._to_str
-        return [table[i] for i in ids]
+    def lookup_many(self, ids) -> List[Optional[str]]:
+        """``lookup`` over many ids; a negative id (``NONE_ID``) reads
+        None."""
+        return _gather(self._to_str, ids)
 
     def state_dict(self) -> dict:
         return {"strings": list(self._to_str)}
@@ -69,6 +70,13 @@ class StringTable:
     def load_state_dict(self, state: dict) -> None:
         self._to_str = list(state["strings"])
         self._to_id = {s: i for i, s in enumerate(self._to_str)}
+
+
+def _gather(values: list, ids) -> list:
+    """``values[i]`` for each id, None where the id is negative."""
+    if len(ids) and min(ids) < 0:
+        return [values[i] if i >= 0 else None for i in ids]
+    return [values[i] for i in ids]
 
 
 class _Remap:
@@ -170,6 +178,9 @@ class DerivedKeyTable(StringTable):
 
     def lookup(self, i: int):
         return self._originals[i]
+
+    def lookup_many(self, ids) -> List:
+        return _gather(self._originals, ids)
 
     def state_dict(self) -> dict:
         # capture-then-truncate UNDER the intern mutex: the parse-ahead

@@ -11,6 +11,7 @@ the reference READMEs replays exactly (SURVEY.md §4).
 
 from __future__ import annotations
 
+import itertools
 import os
 import time
 from typing import Dict, List, Optional
@@ -38,6 +39,7 @@ from .sinks import (
     LedgerSink,
     PrintSink,
     RetryingSink,
+    iter_rows,
 )
 from .sources import SourceBatch
 from .step import LONG_MIN, RULE_VERSION_KEY, RULES_KEY, build_program
@@ -381,6 +383,20 @@ def _apply_ops(bound_ops, item):
     return item, True
 
 
+def _apply_ops_many(bound_ops, rows, subtasks=None):
+    """:func:`_apply_ops` over many rows: ``(kept items, their
+    subtasks)``, the subtasks None where none were given."""
+    items = []
+    kept = [] if subtasks is not None else None
+    for j, row in enumerate(rows):
+        item, keep = _apply_ops(bound_ops, row)
+        if keep:
+            items.append(item)
+            if kept is not None:
+                kept.append(subtasks[j])
+    return items, kept
+
+
 class JobResult:
     def __init__(self, metrics: Metrics):
         self.metrics = metrics
@@ -646,6 +662,9 @@ class Runner:
         self._slices_behind = self.obs.counter(
             "fetch_slices_behind_step_total"
         )
+        # rows handed to a sink in one emit_many call per branch (the
+        # per-row fan-out of chained stages and the ledger counts none)
+        self._rows_batched = self.obs.counter("emit_rows_batched_total")
         self._compaction_gauge = self.obs.gauge("compaction_ratio")
         # chained stages: emissions feed the downstream runner as
         # columnar batches instead of the sinks (build_plan_chain).
@@ -2344,11 +2363,29 @@ class Runner:
 
     def _dispatch(self, emissions, t_batch=None, step: int = -1):
         with self.obs.span("emit", step) as sp:
-            rows = self._dispatch_inner(emissions, t_batch)
+            rows = self._dispatch_inner(emissions, t_batch, step)
             if self.obs.enabled:
                 sp.note(rows=rows)
 
-    def _dispatch_inner(self, emissions, t_batch=None) -> int:
+    def _hand_off(self, branches, columns, subtasks=None):
+        """Hand one step's rows (``columns``, converted) to each
+        ``(ops, sink)`` branch in turn, in one ``emit_many`` call per
+        branch; a branch's map/filter tail runs per row first.
+        ``subtasks`` (numpy, one per row) converts only for a sink that
+        reads it."""
+        sts = None
+        for ops, sink in branches:
+            st_items = None
+            if sink.reads_subtask and subtasks is not None:
+                if sts is None:
+                    sts = subtasks.tolist()
+                st_items = sts
+            items = iter_rows(columns)
+            if ops:
+                items, st_items = _apply_ops_many(ops, items, st_items)
+            self._rows_batched.inc(sink.emit_many(items, st_items))
+
+    def _dispatch_inner(self, emissions, t_batch=None, step: int = -1) -> int:
         """Hand one step's fetched emissions to the sinks (or the next
         stage); returns the rows the sinks received."""
         # step epoch for host-evaluated fire ordering: the per-step
@@ -2439,9 +2476,19 @@ class Runner:
                     subtask = (
                         take(subtask) if subtask is not None else None
                     )
-                    for j, row in enumerate(self.formatter.rows(cols)):
-                        st = int(subtask[j]) if subtask is not None else None
-                        self._emit_row(row, st)
+                    with self.obs.span("emit.format", step):
+                        columns = self.formatter.columns(cols)
+                    if chained or self._ledger_edges is not None:
+                        # per row: a process() chain buffers each row,
+                        # and the ledger accounts each one as it lands
+                        sts = (
+                            subtask.tolist() if subtask is not None
+                            else itertools.repeat(None)
+                        )
+                        for row, st in zip(iter_rows(columns), sts):
+                            self._emit_row(row, st)
+                    else:
+                        self._hand_off(self.sinks, columns, subtask)
                     self.metrics.records_emitted += sel.size
         late = emissions.get("late")
         if late is not None and self.side_sinks:
@@ -2471,21 +2518,10 @@ class Runner:
         )
         # the CEP timeout tag's sink receives ONLY the timeout stream
         tt = getattr(self.program, "timeout_tag", None)
-        for tag_id, (ops, sink) in self.side_sinks.items():
-            if tt is not None and tag_id == tt.id:
-                continue
-            edge = (
-                self._ledger_side.get(tag_id)
-                if self._ledger_side is not None else None
-            )
-            for row in fmt.rows(cols):
-                item, keep = _apply_ops(ops, row)
-                if keep:
-                    sink.emit(item)
-                elif edge is not None:
-                    edge["filtered"] += 1
-                if edge is not None:
-                    edge["in"] += 1
+        columns = fmt.columns(cols)
+        for tag_id, branch in self.side_sinks.items():
+            if tt is None or tag_id != tt.id:
+                self._side_hand_off(tag_id, branch, columns)
 
     def _dispatch_timeout(self, timeout):
         """Route within()-expired partial matches to the pattern's
@@ -2501,19 +2537,27 @@ class Runner:
         fmt = EmissionFormatter(
             self.program.timeout_kinds, self.program.timeout_tables
         )
-        ops, sink = entry
+        self._side_hand_off(tt.id, entry, fmt.columns(cols))
+
+    def _side_hand_off(self, tag_id, branch, columns):
+        """Hand a side output's rows to its branch: in one call, or
+        with the ledger on row by row, each row accounted as it
+        lands."""
         edge = (
-            self._ledger_side.get(tt.id)
+            self._ledger_side.get(tag_id)
             if self._ledger_side is not None else None
         )
-        for row in fmt.rows(cols):
+        if edge is None:
+            self._hand_off((branch,), columns)
+            return
+        ops, sink = branch
+        for row in iter_rows(columns):
             item, keep = _apply_ops(ops, row)
             if keep:
                 sink.emit(item)
-            elif edge is not None:
+            else:
                 edge["filtered"] += 1
-            if edge is not None:
-                edge["in"] += 1
+            edge["in"] += 1
 
 
 def _reject_count_ts(st):

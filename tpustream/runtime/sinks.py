@@ -11,46 +11,69 @@ print parallelism is 1.
 
 from __future__ import annotations
 
+import itertools
 from typing import Callable, List, Optional
 
 import numpy as np
 
-from ..api.tuples import _java_str, make_tuple
+from ..api.tuples import TUPLE_CLASSES, _java_str
 from ..obs.registry import NULL_COUNTER
-from ..records import BOOL, F64, I64, STR
+from ..records import BOOL, F64, STR
 
 
 class EmissionFormatter:
     """Turns emission columns (numpy, already masked/compacted) into Python
-    row values using the planned field kinds and string tables."""
+    row values using the planned field kinds and string tables.
+
+    Each column converts once (``tolist``, or one gather through its
+    string table), and :func:`iter_rows` builds the rows from the
+    converted lists as a sink takes them, so a row costs a few
+    interpreter steps rather than a cast and a dispatch per field."""
 
     def __init__(self, kinds: List[str], tables: List[Optional[object]]):
         self.kinds = kinds
         self.tables = tables
 
-    def rows(self, cols: List[np.ndarray]):
-        n = len(cols[0]) if cols else 0
-        converted = []
-        for kind, col, table in zip(self.kinds, cols, self.tables):
-            if kind == STR:
-                converted.append(
-                    [table.lookup(int(i)) if int(i) >= 0 else None for i in col]
-                )
-            elif kind == F64:
-                converted.append([float(v) for v in col])
-            elif kind == BOOL:
-                converted.append([bool(v) for v in col])
-            else:
-                converted.append([int(v) for v in col])
-        for j in range(n):
-            vals = tuple(c[j] for c in converted)
-            if len(vals) == 1:
-                yield vals[0]
-            elif len(vals) <= 4:
-                yield make_tuple(*vals)
-            else:
-                # wider than Tuple4 (e.g. CEP timeout records): plain tuple
-                yield vals
+    def columns(self, cols: List[np.ndarray]) -> List[list]:
+        """Each column as a list of Python values of its kind: ``str``
+        (None for a negative id), ``float``, ``bool`` or ``int``."""
+        return [
+            _column_values(kind, np.asarray(col), table)
+            for kind, col, table in zip(self.kinds, cols, self.tables)
+        ]
+
+
+def _column_values(kind: str, col: np.ndarray, table) -> list:
+    if kind == F64:
+        return col.astype(np.float64, copy=False).tolist()
+    if kind == BOOL:
+        return col.astype(np.bool_, copy=False).tolist()
+    ints = col.astype(np.int64, copy=False).tolist()
+    return table.lookup_many(ints) if kind == STR else ints
+
+
+def iter_rows(columns: List[list]):
+    """The rows of converted columns, in order: the bare value at arity
+    1, a ``TupleN`` at arity 2-4, a plain tuple above (e.g. CEP timeout
+    records). Built one at a time as they are taken: a step's ~10k rows
+    alive at once would be ~10k objects for Python's cyclic collector
+    to promote, and its full collections then stall the loop."""
+    if not columns:
+        return iter(())
+    if len(columns) == 1:
+        return iter(columns[0])
+    cls = TUPLE_CLASSES.get(len(columns))
+    if cls is None:
+        return zip(*columns)
+    return map(cls.trusted, *columns)
+
+
+def _body(value) -> str:
+    if isinstance(value, str):
+        return value
+    if hasattr(value, "_FIELDS"):
+        return repr(value)
+    return _java_str(value)
 
 
 class PrintSink:
@@ -67,24 +90,41 @@ class PrintSink:
         self._rr = 0
         self.lines: List[str] = []  # retained for tests/inspection
 
+    @property
+    def reads_subtask(self) -> bool:
+        return self.parallelism > 1
+
     def emit(self, value, subtask: Optional[int] = None) -> None:
-        body = repr(value) if not isinstance(value, str) else value
-        if not isinstance(value, (str,)) and not hasattr(value, "_FIELDS"):
-            body = _java_str(value)
-        if self.parallelism > 1:
-            if subtask is None:
-                subtask = self._rr
-                self._rr = (self._rr + 1) % self.parallelism
-            line = f"{(subtask % self.parallelism) + 1}> {body}"
-        else:
-            line = body
-        self.lines.append(line)
-        print(line, file=self.stream)
-        self.obs_counter.inc()
+        self.emit_many(
+            (value,), None if subtask is None else (subtask,)
+        )
+
+    def emit_many(self, values, subtasks=None) -> int:
+        """Print each value on its own line and return how many;
+        ``subtasks`` (one per value) names each line's ``n>`` prefix,
+        and without them the prefixes rotate over the parallelism."""
+        lines = [_body(v) for v in values]
+        if not lines:
+            return 0
+        p = self.parallelism
+        if p > 1:
+            if subtasks is None:
+                rr = self._rr
+                subtasks = range(rr, rr + len(lines))
+                self._rr = (rr + len(lines)) % p
+            lines = [
+                f"{(st % p) + 1}> {body}"
+                for st, body in zip(subtasks, lines)
+            ]
+        self.lines.extend(lines)
+        print("\n".join(lines), file=self.stream)
+        self.obs_counter.inc(len(lines))
+        return len(lines)
 
 
 class CollectSink:
     obs_counter = NULL_COUNTER
+    reads_subtask = False
 
     def __init__(self, handle):
         self.handle = handle
@@ -93,9 +133,18 @@ class CollectSink:
         self.handle.append(value)
         self.obs_counter.inc()
 
+    def emit_many(self, values, subtasks=None) -> int:
+        append = self.handle.append
+        n = 0
+        for n, v in enumerate(values, 1):
+            append(v)
+        self.obs_counter.inc(n)
+        return n
+
 
 class FnSink:
     obs_counter = NULL_COUNTER
+    reads_subtask = False
 
     def __init__(self, fn: Callable):
         self.fn = fn
@@ -103,6 +152,14 @@ class FnSink:
     def emit(self, value, subtask: Optional[int] = None) -> None:
         self.fn(value)
         self.obs_counter.inc()
+
+    def emit_many(self, values, subtasks=None) -> int:
+        fn = self.fn
+        n = 0
+        for n, v in enumerate(values, 1):
+            fn(v)
+        self.obs_counter.inc(n)
+        return n
 
 
 class RetryingSink:
@@ -144,6 +201,20 @@ class RetryingSink:
     @obs_counter.setter
     def obs_counter(self, counter) -> None:
         self.inner.obs_counter = counter
+
+    @property
+    def reads_subtask(self) -> bool:
+        return self.inner.reads_subtask
+
+    def emit_many(self, values, subtasks=None) -> int:
+        """Row by row through :meth:`emit`: each row retries (and meets
+        an injected fault) on its own."""
+        if subtasks is None:
+            subtasks = itertools.repeat(None)
+        n = 0
+        for n, (v, st) in enumerate(zip(values, subtasks), 1):
+            self.emit(v, subtask=st)
+        return n
 
     def emit(self, value, subtask: Optional[int] = None) -> None:
         import time
