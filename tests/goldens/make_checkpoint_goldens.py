@@ -82,8 +82,10 @@ def main():
     from tpustream.runtime.checkpoint import CHUNK_DIR, FORMAT_VERSION
 
     assert FORMAT_VERSION == 12, (
-        f"FORMAT_VERSION moved to {FORMAT_VERSION}: re-point the fixture "
-        "names/versions below and update tests/test_schema_audit.py"
+        f"FORMAT_VERSION is {FORMAT_VERSION}: the fixtures are the v12 "
+        "snapshots this build restores by migration (tests/goldens/"
+        "README.md); re-point the names/versions below and "
+        "tests/test_schema_audit.py before regenerating"
     )
     # inline self-contained form + the version ladder derived from it
     d = tempfile.mkdtemp()

@@ -140,10 +140,10 @@ def test_old_format_checkpoint_rejected(tmp_path):
 
     import numpy as np
 
-    from tpustream.runtime.checkpoint import FORMAT_VERSION, load_checkpoint
+    from tpustream.runtime.checkpoint import OLDEST_READABLE, load_checkpoint
 
     meta = {
-        "version": FORMAT_VERSION - 1,
+        "version": OLDEST_READABLE - 1,
         "record_kinds": [], "tables": [], "source_pos": 0,
         "proc_now": 0, "emitted": 0, "batches": 1,
     }

@@ -492,7 +492,7 @@ def test_mixed_version_directory_skips_older_format(tmp_path):
     current-FORMAT_VERSION snapshots as valid while skipping the
     older-version ones with a ``checkpoint_skipped`` breadcrumb — never
     handing the supervisor an unloadable path."""
-    from tpustream.runtime.checkpoint import FORMAT_VERSION
+    from tpustream.runtime.checkpoint import OLDEST_READABLE
 
     run_supervised(LINES, ckdir=tmp_path)
     snaps = _snaps(tmp_path)
@@ -500,7 +500,7 @@ def test_mixed_version_directory_skips_older_format(tmp_path):
     newest, older = snaps[-1], snaps[-2]
     # this build's snapshots ARE the current format — valid as written
     assert validate_checkpoint(newest) is None
-    _rewrite_format_version(newest, FORMAT_VERSION - 1)
+    _rewrite_format_version(newest, OLDEST_READABLE - 1)
     reason = validate_checkpoint(newest)
     assert reason is not None and "version" in reason
 
@@ -528,11 +528,11 @@ def test_recovery_survives_mixed_version_directory(tmp_path):
     pre-upgrade run left it behind), the job crashes — the restart
     restores from the newest CURRENT-version snapshot and the output is
     still byte-identical to an uninterrupted run."""
-    from tpustream.runtime.checkpoint import FORMAT_VERSION
+    from tpustream.runtime.checkpoint import OLDEST_READABLE
 
     _, full, _ = run_supervised(LINES)
     run_supervised(LINES, ckdir=tmp_path)
-    _rewrite_format_version(_snaps(tmp_path)[-1], FORMAT_VERSION - 1)
+    _rewrite_format_version(_snaps(tmp_path)[-1], OLDEST_READABLE - 1)
 
     inj = FaultInjector(FaultPoint("device_step", at=2))
     _, out, _ = run_supervised(

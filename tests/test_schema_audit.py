@@ -54,6 +54,7 @@ from tpustream.runtime.checkpoint import (
     FORMAT_VERSION,
     latest_checkpoint,
     load_checkpoint,
+    readable_version,
     validate_checkpoint,
 )
 from tpustream.runtime.supervisor import _layout_audit
@@ -360,11 +361,17 @@ def test_audit_identical_job_is_compatible(tmp_path):
     report = env.audit_checkpoint(fixture(12))
     assert isinstance(report, AuditReport)
     assert report.verdict == "compatible"
-    assert report.findings == []
+    # a v12 snapshot: its float64 max plane migrates to the image on
+    # restore, which the audit names (visible, never blocking)
+    assert set(codes(report.findings)) == {"TSM042", "TSM045"}
+    assert all(f.severity == INFO for f in report.findings)
     assert report.reason is None
-    # the expected tree is fully derived and matches the manifest 1:1
-    assert len(report.expected.leaves) == len(report.manifest.leaves) == 4
-    assert report.expected.format_version == FORMAT_VERSION == 12
+    # the expected tree is fully derived; the manifest matches its v12
+    # form 1:1 (the usage plane in float64, not two int32 image planes)
+    assert len(report.expected.legacy_leaves) == len(
+        report.manifest.leaves) == 4
+    assert len(report.expected.leaves) == 5
+    assert report.expected.format_version == FORMAT_VERSION == 13
 
 
 def test_audit_symbolic_shapes_name_the_key_axis(tmp_path):
@@ -380,7 +387,7 @@ def test_audit_grown_key_capacity_stays_compatible(tmp_path):
     report = env.audit_checkpoint(fixture(12))
     assert report.verdict == "compatible"
     assert report.findings  # visible, not silent
-    assert set(codes(report.findings)) == {"TSM043"}
+    assert set(codes(report.findings)) == {"TSM042", "TSM043", "TSM045"}
     assert all(f.severity == INFO for f in report.findings)
     assert report.reason is None
 
@@ -403,7 +410,8 @@ def test_audit_missing_leaves_tsm040(tmp_path):
     )
     report = env.audit_checkpoint(fixture(12))
     assert report.verdict == "incompatible"
-    assert codes(report.findings) == ["TSM040"]
+    # (the fixture is a v12 snapshot: TSM045 notes the migration)
+    assert codes(report.findings) == ["TSM040", "TSM045"]
     assert report.reason.startswith("TSM040")
     assert "stage1/" in report.reason  # names the missing tail
 
@@ -416,7 +424,8 @@ def test_audit_orphaned_leaves_tsm041(tmp_path):
     build1(env, env.from_collection([])).collect()
     report = env.audit_checkpoint(fixture(12))
     assert report.verdict == "incompatible"
-    assert codes(report.findings) == ["TSM041"]
+    # (the fixture is a v12 snapshot: TSM045 notes the migration)
+    assert codes(report.findings) == ["TSM041", "TSM045"]
     assert "orphaned" in report.reason
 
 
@@ -443,7 +452,8 @@ def test_audit_leaf_dtype_change_tsm042(tmp_path):
     env = golden_env(tmp_path / "ck")
     report = env.audit_checkpoint(str(doctored))
     assert report.verdict == "incompatible"
-    assert codes(report.findings) == ["TSM042"]
+    # (the fixture is a v12 snapshot: TSM045 notes the migration)
+    assert codes(report.findings) == ["TSM042", "TSM045"]
     f = next(f for f in report.findings if f.code == "TSM042")
     assert "float64" in f.message and "float32" in f.message
     assert report.reason.startswith("TSM042")
@@ -472,17 +482,39 @@ def test_audit_unreadable_snapshot_tsm046(tmp_path):
     assert env.audit_checkpoint(str(p)).verdict == "incompatible"
 
 
-@pytest.mark.parametrize("version", [8, 9, 10, 11, 13])
+def versioned(tmp_path, version: int) -> str:
+    """The golden fixture of ``version``; past the newest fixture, the
+    v12 payload rewritten to ``version`` (a future build's snapshot)."""
+    path = fixture(version)
+    if not os.path.exists(path):
+        path = str(tmp_path / os.path.basename(path))
+        shutil.copy(fixture(12), path)
+        _goldens_mod().rewrite_format_version(path, version)
+    return path
+
+
+@pytest.mark.parametrize("version", [8, 9, 10, 11, 13, 14])
 def test_audit_version_verdict_matches_real_restore(tmp_path, version):
-    """TSM045 parity: every surface agrees a cross-version snapshot
-    cannot restore — the auditor, validate_checkpoint, and the loader."""
-    env = golden_env(tmp_path)
-    report = env.audit_checkpoint(fixture(version))
+    """TSM045 parity: every surface agrees whether a snapshot of another
+    format version restores — the auditor, validate_checkpoint, the
+    loader and a real restore. This build writes v13 and migrates v12
+    (fv13 holds the v12 payload under this build's number, and restores
+    by its leaves); v8-v11 and a future v14 cannot restore."""
+    path = versioned(tmp_path, version)
+    env = golden_env(tmp_path / "ck")
+    report = env.audit_checkpoint(path)
+    if readable_version(version):
+        assert report.verdict == "compatible"
+        assert validate_checkpoint(path) is None
+        load_checkpoint(path)
+        env.restore_from_checkpoint(path)
+        env.execute("golden-resume")
+        return
     assert report.verdict == "incompatible"
     assert "TSM045" in codes(report.findings)
     f = next(f for f in report.findings if f.code == "TSM045")
     assert f"v{version}" in f.message
-    if version == 13:
+    if version > FORMAT_VERSION:
         # a snapshot from the FUTURE: no migration narrative exists
         assert "future format" in f.message
     else:
@@ -490,12 +522,10 @@ def test_audit_version_verdict_matches_real_restore(tmp_path, version):
         assert f"v{version + 1}:" in f.message
 
     # restore-path parity
-    assert f"format version {version}" in validate_checkpoint(
-        fixture(version)
-    )
+    assert f"format version {version}" in validate_checkpoint(path)
     with pytest.raises(ValueError, match="format version"):
-        load_checkpoint(fixture(version))
-    env.restore_from_checkpoint(fixture(version))
+        load_checkpoint(path)
+    env.restore_from_checkpoint(path)
     with pytest.raises(ValueError, match="format version"):
         env.execute("doomed-restore")
 
@@ -511,15 +541,17 @@ def test_audit_compatible_verdict_matches_real_restore(tmp_path):
 
 
 def test_latest_checkpoint_skips_future_format(tmp_path):
-    # fv13 sorts newest; validation rejects it and recovery falls back
+    # a future v14 sorts newest; validation rejects it and recovery
+    # falls back to the newest snapshot this build restores
     for v in (12, 13):
         shutil.copy(fixture(v), tmp_path / os.path.basename(fixture(v)))
+    future = versioned(tmp_path, FORMAT_VERSION + 1)
     ring = Ring()
     picked = latest_checkpoint(str(tmp_path), flight=ring)
-    assert picked == str(tmp_path / "ckpt-fv12.npz")
+    assert picked == str(tmp_path / "ckpt-fv13.npz")
     (skip,) = [p for k, p in ring.events if k == "checkpoint_skipped"]
-    assert skip["path"].endswith("ckpt-fv13.npz")
-    assert "format version 13" in skip["reason"]
+    assert skip["path"] == future
+    assert f"format version {FORMAT_VERSION + 1}" in skip["reason"]
 
 
 def test_supervisor_audit_hook_preempts_doomed_restore(tmp_path):
@@ -550,7 +582,9 @@ def test_supervisor_audit_passes_compatible_snapshot(tmp_path):
     picked = latest_checkpoint(str(tmp_path), flight=ring, audit=audit)
     assert picked == str(tmp_path / "ckpt-fv12.npz")
     audits = [p for k, p in ring.events if k == "checkpoint_audit"]
-    assert audits[0]["verdict"] == "compatible" and audits[0]["codes"] == []
+    # the v12 fixture's only findings are the migration notes (INFO)
+    assert audits[0]["verdict"] == "compatible"
+    assert audits[0]["codes"] == ["TSM042", "TSM045"]
     assert not [p for k, p in ring.events if k == "checkpoint_skipped"]
 
 

@@ -51,17 +51,27 @@ def run(obs, **over):
 
 
 DEEP = dict(h2d_depth=2, async_depth=3)
+# the widest a run's bracket around an annotation's clock read may be
+# for it to test the twinning (StepTracer.bridge_skew_max_s): a thread
+# descheduled there by other processes on the cores moves one twin by
+# the whole pause, which says nothing about the tracer
+SKEW_S = 50e-6
 
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    """One deep-pipelined run with the bridge on, under the profiler."""
-    logdir = str(tmp_path_factory.mktemp("prof"))
-    with jax.profiler.trace(logdir):
-        rows, res = run(
-            ObsConfig(enabled=True, profiler_bridge=True,
-                      trace_ring_size=1 << 14), **DEEP,
-        )
+    """One deep-pipelined run with the bridge on, under the profiler:
+    the first of up to five whose every span read the two clocks within
+    ``SKEW_S`` of each other."""
+    for _ in range(5):
+        logdir = str(tmp_path_factory.mktemp("prof"))
+        with jax.profiler.trace(logdir):
+            rows, res = run(
+                ObsConfig(enabled=True, profiler_bridge=True,
+                          trace_ring_size=1 << 14), **DEEP,
+            )
+        if res.metrics.job_obs.tracer.bridge_skew_max_s < SKEW_S:
+            break
     path = sorted(glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True))[-1]
     return rows, res.metrics.job_obs, path
 
@@ -169,6 +179,7 @@ def test_emit_format_nests_inside_its_emit(traced):
 
 def test_bridged_events_twin_the_ring_on_one_clock(traced):
     _, job_obs, path = traced
+    assert job_obs.tracer.bridge_skew_max_s < SKEW_S
     data = jax.profiler.ProfileData.from_file(path)
     # (kind, step) names one span, but for ``fetch``: a step planned
     # before the next dispatch and its retiring fetch share a step id;
@@ -211,6 +222,24 @@ def test_scope_map_names_each_phase_of_the_step(traced):
     assert scopes
     named = set(scopes.values())
     for want in ("unpack", "scatter", "fire", "compact"):
+        assert want in named, (want, sorted(named))
+
+
+def test_scope_map_names_the_rolling_phases():
+    """The rolling step (chapter-2 running max) opens ``combine`` (sort,
+    scan, compare) and ``state`` (gathers, plane scatters) between the
+    executor's ``unpack`` and ``compact``."""
+    from tpustream.jobs.chapter2_max import build as build_max
+
+    env = StreamExecutionEnvironment(StreamConfig(
+        batch_size=8, key_capacity=64,
+        obs=ObsConfig(enabled=True, profiler_bridge=True)))
+    lines = [f"1566957600 host{i % 5} cpu{i % 4} {10 + i}.5"
+             for i in range(24)]
+    build_max(env, env.from_collection(lines)).collect()
+    res = env.execute("rolling-scopes")
+    named = set(res.metrics.job_obs.op_scopes["jit_step"].values())
+    for want in ("unpack", "combine", "state", "compact"):
         assert want in named, (want, sorted(named))
 
 

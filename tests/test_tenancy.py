@@ -428,7 +428,7 @@ def test_tenant_apply_crash_recovers_byte_identical(tmp_path):
 def test_checkpoint_carries_tenant_table_and_rule_vectors(tmp_path):
     from tpustream.runtime.checkpoint import FORMAT_VERSION, load_checkpoint
 
-    assert FORMAT_VERSION == 12
+    assert FORMAT_VERSION == 13
     srv = _durable_fleet(ckdir=tmp_path)
     srv.run("fleet-ckpt")
     snaps = sorted(glob.glob(os.path.join(str(tmp_path), "ckpt-*.npz")))

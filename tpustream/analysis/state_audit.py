@@ -79,6 +79,10 @@ class ExpectedLayout:
     #: True when a host-evaluated stage blocks static derivation of the
     #: downstream stages' leaves — structural diffs are skipped then
     partial: bool = False
+    #: the leaf tree a format-v12 build wrote for this job (each rolling
+    #: order-only float64 field in one float64 plane, not its two image
+    #: planes); restore migrates it (runtime/checkpoint.py MIGRATIONS[13])
+    legacy_leaves: List[ExpectedLeaf] = field(default_factory=list)
 
 
 @dataclass(frozen=True)
@@ -117,17 +121,19 @@ class AuditReport:
 
 # -- expected layout ----------------------------------------------------------
 
-def _abstract_state(prog):
-    """Leaf tree of ``prog.init_state()`` as (path, ShapeDtypeStruct)
-    pairs — via ``jax.eval_shape`` (nothing materializes, nothing
-    compiles); falls back to building the concrete tiny state on
-    backends where an init uses primitives eval_shape can't abstract."""
+def _abstract_state(prog, init=None):
+    """Leaf tree of ``prog.init_state()`` (or ``init()``) as (path,
+    ShapeDtypeStruct) pairs — via ``jax.eval_shape`` (nothing
+    materializes, nothing compiles); falls back to building the concrete
+    tiny state on backends where an init uses primitives eval_shape
+    can't abstract."""
     import jax
 
+    init = init or prog.init_state
     try:
-        tree = jax.eval_shape(prog.init_state)
+        tree = jax.eval_shape(init)
     except Exception:
-        tree = prog.init_state()
+        tree = init()
     return jax.tree_util.tree_flatten_with_path(tree)[0], tree
 
 
@@ -166,9 +172,8 @@ def expected_layout(env, sink_nodes=None, key_capacities=None) -> ExpectedLayout
     caller) — restore rebuilds each stage at that capacity, so the
     audit must derive shapes the same way.
     """
-    from ..parallel.mesh import AXIS
     from ..runtime.plan import build_plan_chain
-    from ..runtime.step import RULES_KEY, build_program
+    from ..runtime.step import build_program
     from ..records import STR
     from ..records import DerivedKeyTable
 
@@ -208,38 +213,51 @@ def expected_layout(env, sink_nodes=None, key_capacities=None) -> ExpectedLayout
         except Exception:
             layout.partial = True
             break
-        leaves, tree = _abstract_state(prog)
-        components = prog.state_components()
-        try:
-            spec_leaves = prog.state_specs(tree)
-            import jax
-
-            specs = jax.tree_util.tree_leaves(
-                spec_leaves,
-                is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec),
-            )
-        except Exception:
-            specs = [None] * len(leaves)
-        for (path, leaf), spec in zip(leaves, specs):
-            key = _path_key(path)
-            top = key.split("/")[0]
-            if top == RULES_KEY or key.startswith(RULES_KEY):
-                comp = "rules"
-            else:
-                comp = components.get(top, "scalars")
-            layout.leaves.append(ExpectedLeaf(
-                name=f"stage{i}/{comp}/{key}",
-                stage=i,
-                component=comp,
-                dtype=np.dtype(leaf.dtype).name,
-                shape=tuple(int(d) for d in leaf.shape),
-                symbolic=_symbolic(
-                    leaf.shape, cfg, cap, layout.tenant_capacity
-                ),
-                key_sharded=bool(spec is not None and len(spec) and spec[0] == AXIS),
-            ))
+        leaves = _stage_leaves(prog, prog.init_state, i, cfg, cap,
+                               layout.tenant_capacity)
+        layout.leaves.extend(leaves)
+        if prog.image_fields:
+            leaves = _stage_leaves(prog, prog.legacy_init_state, i, cfg,
+                                   cap, layout.tenant_capacity)
+        layout.legacy_leaves.extend(leaves)
         upstream = prog
     return layout
+
+
+def _stage_leaves(prog, init, stage, cfg, cap, tenant_capacity):
+    """The expected leaves of one stage's state as ``init`` builds it."""
+    import jax
+
+    from ..parallel.mesh import AXIS
+    from ..runtime.step import RULES_KEY
+
+    leaves, tree = _abstract_state(prog, init)
+    components = prog.state_components()
+    try:
+        specs = jax.tree_util.tree_leaves(
+            prog.state_specs(tree),
+            is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec),
+        )
+    except Exception:
+        specs = [None] * len(leaves)
+    out = []
+    for (path, leaf), spec in zip(leaves, specs):
+        key = _path_key(path)
+        top = key.split("/")[0]
+        if top == RULES_KEY or key.startswith(RULES_KEY):
+            comp = "rules"
+        else:
+            comp = components.get(top, "scalars")
+        out.append(ExpectedLeaf(
+            name=f"stage{stage}/{comp}/{key}",
+            stage=stage,
+            component=comp,
+            dtype=np.dtype(leaf.dtype).name,
+            shape=tuple(int(d) for d in leaf.shape),
+            symbolic=_symbolic(leaf.shape, cfg, cap, tenant_capacity),
+            key_sharded=bool(spec is not None and len(spec) and spec[0] == AXIS),
+        ))
+    return out
 
 
 def _format_version() -> int:
@@ -368,17 +386,29 @@ def _rank(sev: str) -> int:
 
 
 def _meta_findings(meta, expected, env) -> List[Finding]:
-    from ..runtime.checkpoint import FORMAT_VERSION, MIGRATIONS
+    from ..runtime.checkpoint import (
+        FORMAT_VERSION,
+        MIGRATIONS,
+        readable_version,
+    )
 
     out: List[Finding] = []
     version = meta.get("version")
     if version != FORMAT_VERSION:
         gap = _migration_narrative(version, FORMAT_VERSION, MIGRATIONS)
-        out.append(make_finding(
-            "TSM045", None,
-            f"snapshot format v{version} != this build's "
-            f"v{FORMAT_VERSION}{gap}",
-        ))
+        if readable_version(version):
+            out.append(make_finding(
+                "TSM045", None,
+                f"snapshot format v{version} is older than this build's "
+                f"v{FORMAT_VERSION}; restore migrates it{gap}",
+                severity=INFO,
+            ))
+        else:
+            out.append(make_finding(
+                "TSM045", None,
+                f"snapshot format v{version} != this build's "
+                f"v{FORMAT_VERSION}{gap}",
+            ))
     if expected is not None:
         saved_t = (meta.get("tenancy") or {}).get("capacity", 0)
         if expected.tenant_capacity and saved_t and (
@@ -420,6 +450,21 @@ def _migration_narrative(saved, current, migrations) -> str:
 def _diff_leaves(expected: ExpectedLayout, manifest: Manifest) -> List[Finding]:
     out: List[Finding] = []
     exp, got = expected.leaves, manifest.leaves
+    legacy = expected.legacy_leaves
+    if len(legacy) != len(exp) and len(got) == len(legacy):
+        # the format-v12 layout: diffed as such, and migrated on restore
+        out = _diff_leaves(replace(expected, leaves=legacy,
+                                   legacy_leaves=legacy), manifest)
+        if not any(f.severity == ERROR for f in out):
+            out.append(make_finding(
+                "TSM042", None,
+                f"snapshot holds {len(got)} state leaves in the v12 "
+                f"layout (the job's {len(exp)} in v13): restore migrates "
+                "each order-only float64 rolling plane to the two int32 "
+                "planes of its int64 image",
+                severity=INFO,
+            ))
+        return out
     if len(got) < len(exp):
         missing = ", ".join(l.name for l in exp[len(got):][:6])
         out.append(make_finding(

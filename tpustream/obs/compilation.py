@@ -60,10 +60,11 @@ CAUSE_CONFIG = "config_change"
 
 
 # the named scopes the step programs open (runtime/executor.py
-# _counted_step, runtime/window_program.py _step/_sweep, and on a mesh
-# runtime/sharded.py's keyBy exchange and all-reduces), innermost wins
+# _counted_step, runtime/window_program.py _step/_sweep, ops/rolling.py's
+# rolling step, and on a mesh runtime/sharded.py's keyBy exchange and
+# all-reduces), innermost wins
 STEP_SCOPES = ("unpack", "retarget", "scatter", "fire", "sweep", "compact",
-               "exchange", "allreduce")
+               "exchange", "allreduce", "combine", "state")
 _OP_NAME = 'op_name="'
 
 

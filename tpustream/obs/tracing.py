@@ -70,14 +70,27 @@ class _Span:
         self._ann = None
 
     def __enter__(self) -> "_Span":
-        if self._tracer._annotate is not None:
+        # the bridged annotation reads its start when it is built, not
+        # in __enter__, and its end first thing in __exit__: the ring's
+        # clock is read right beside each, so whatever __enter__ does
+        # stays out of the gap between the twins' clock reads. A read
+        # on the annotation's other side bounds that gap, which a thread
+        # descheduled there widens (StepTracer.bridge_skew_max_s)
+        tracer = self._tracer
+        if tracer._annotate is not None:
             name = f"tpustream.{self.kind}"
-            self._ann = (
-                self._tracer._annotate(name, step=self.step)
-                if self.step >= 0 else self._tracer._annotate(name)
+            before = time.perf_counter()
+            ann = (
+                tracer._annotate(name, step=self.step)
+                if self.step >= 0 else tracer._annotate(name)
             )
-            self._ann.__enter__()
-        self._t0 = time.perf_counter()
+            self._t0 = time.perf_counter()
+            ann.__enter__()
+            self._ann = ann
+            if self._t0 - before > tracer.bridge_skew_max_s:
+                tracer.bridge_skew_max_s = self._t0 - before
+        else:
+            self._t0 = time.perf_counter()
         return self
 
     def note(self, step: Optional[int] = None, **stats) -> None:
@@ -91,10 +104,17 @@ class _Span:
             self._ann.set_metadata(**stats)
 
     def __exit__(self, *exc) -> None:
-        t1 = time.perf_counter()
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
+        ann = self._ann
+        if ann is not None:
+            ann_exit = ann.__exit__
+            t1 = time.perf_counter()
+            ann_exit(*exc)
+            skew = time.perf_counter() - t1
             self._ann = None
+            if skew > self._tracer.bridge_skew_max_s:
+                self._tracer.bridge_skew_max_s = skew
+        else:
+            t1 = time.perf_counter()
         self._tracer._record(self.kind, self.step, self.operator, self._t0, t1 - self._t0)
 
 
@@ -108,6 +128,10 @@ class StepTracer:
     """
 
     enabled = True
+    # bridged spans: the widest bracket of reads around an annotation's
+    # own clock read, which bounds how far any span and its trace event
+    # can disagree
+    bridge_skew_max_s = 0.0
 
     def __init__(self, capacity: int = 4096, profiler_bridge: bool = False):
         self.capacity = max(1, int(capacity))
@@ -224,6 +248,7 @@ class _NullTracer:
 
     enabled = False
     bridged = False
+    bridge_skew_max_s = 0.0
     capacity = 0
     total_spans = 0
     epoch = 0.0

@@ -12,7 +12,11 @@ leaves split into two int32 planes so the per-batch scatter takes the
 fast 32-bit path (v5e emulates 64-bit scatters ~8x slower), with the
 optional ``compact32`` accumulator mode storing 64-bit leaves in one
 32-bit plane. Batches combine via the segmented sort+scan kernel, so
-throughput is O(B log B) regardless of key skew.
+throughput is O(B log B) regardless of key skew. A float64 field the
+step only orders or passes through arrives as its int64 image
+(``records.f64_image``, ``RollingProgram.image_fields``), so ``max``/
+``min``/``max_by``/``min_by`` compare plain integers in Java's
+``Double.compareTo`` order and store them on the 32-bit planes.
 """
 
 from __future__ import annotations
@@ -41,6 +45,12 @@ from .wordplanes import (
 # state-dict keys this module owns, for the obs/memory.py component
 # accounting: the dense per-key storage planes plus the occupancy bitmap
 ROLLING_STATE_KEYS = ("seen", "planes")
+
+# the step's named scopes (obs/compilation.py STEP_SCOPES): COMBINE is
+# the sort by key, the segmented scan and the compare with stored
+# values; STATE the gathers of stored rows and the plane scatters
+COMBINE = "combine"
+STATE = "state"
 
 
 def init_rolling_state(
@@ -157,37 +167,44 @@ def rolling_step(
             sort_also,
         )
     K = state["seen"].shape[0]
-    perm, sk, sv, seg_starts = sort_by_key(keys, valid, max_key=K)
-    sorted_cols = tuple(c[perm] for c in cols)
-
-    # within-batch inclusive per-key combine (arrival order preserved)
-    prefix = segmented_scan(sorted_cols, seg_starts, combine)
+    with jax.named_scope(COMBINE):
+        perm, sk, sv, seg_starts = sort_by_key(keys, valid, max_key=K)
+        sorted_cols = tuple(c[perm] for c in cols)
+        # within-batch inclusive per-key combine (arrival order preserved)
+        prefix = segmented_scan(sorted_cols, seg_starts, combine)
+        safe_keys = jnp.where(sv, sk, 0).astype(jnp.int32)
 
     # fold prior state in: for seen keys the carry is state ⊕ prefix
-    safe_keys = jnp.where(sv, sk, 0).astype(jnp.int32)
-    seen = state["seen"][safe_keys] & sv
-    stored_words = [p[safe_keys] for p in state["planes"]]
-    stored = tuple(unpack_words(stored_words, kinds, compact32))
-    combined = combine(stored, prefix)
-    emis_sorted = tuple(
-        jnp.where(seen, c, p) for c, p in zip(combined, prefix)
-    )
+    with jax.named_scope(STATE):
+        seen = state["seen"][safe_keys] & sv
+        stored_words = [p[safe_keys] for p in state["planes"]]
+        stored = tuple(unpack_words(stored_words, kinds, compact32))
+    with jax.named_scope(COMBINE):
+        combined = combine(stored, prefix)
+        emis_sorted = tuple(
+            jnp.where(seen, c, p) for c, p in zip(combined, prefix)
+        )
+        tails = segment_tails(seg_starts) & sv
+        idx = jnp.where(tails, sk, K).astype(jnp.int32)
 
     # scatter segment tails back into state (one tail per key; non-tails are
     # routed out of bounds and dropped)
-    tails = segment_tails(seg_starts) & sv
-    idx = jnp.where(tails, sk, K).astype(jnp.int32)
-    new_words = pack_words(list(emis_sorted), kinds, compact32)
-    new_planes = [
-        p.at[idx].set(w.astype(p.dtype), mode="drop", unique_indices=True)
-        for p, w in zip(state["planes"], new_words)
-    ]
-    new_seen = state["seen"].at[idx].set(True, mode="drop", unique_indices=True)
+    with jax.named_scope(STATE):
+        new_words = pack_words(list(emis_sorted), kinds, compact32)
+        new_planes = [
+            p.at[idx].set(w.astype(p.dtype), mode="drop", unique_indices=True)
+            for p, w in zip(state["planes"], new_words)
+        ]
+        new_seen = state["seen"].at[idx].set(
+            True, mode="drop", unique_indices=True
+        )
 
-    inv = inverse_permutation(perm)
-    out = ({"seen": new_seen, "planes": new_planes}, emis_sorted, sv, sk, inv)
-    if sort_also:
-        out = out + (tuple(x[perm] for x in sort_also),)
+    with jax.named_scope(COMBINE):
+        inv = inverse_permutation(perm)
+        out = ({"seen": new_seen, "planes": new_planes}, emis_sorted, sv,
+               sk, inv)
+        if sort_also:
+            out = out + (tuple(x[perm] for x in sort_also),)
     return out
 
 
@@ -215,10 +232,16 @@ def _rolling_step_commutative(
     ):
         sentinel_leaf = None
 
-    perm, sk, sv, seg_starts = sort_by_key(keys, valid, max_key=K)
-    safe_keys = jnp.where(sv, sk, 0).astype(jnp.int32)
-    tails = segment_tails(seg_starts) & sv
-    tail_idx = jnp.where(tails, sk, K).astype(jnp.int32)
+    with jax.named_scope(COMBINE):
+        perm, sk, sv, seg_starts = sort_by_key(keys, valid, max_key=K)
+        safe_keys = jnp.where(sv, sk, 0).astype(jnp.int32)
+        tails = segment_tails(seg_starts) & sv
+        tail_idx = jnp.where(tails, sk, K).astype(jnp.int32)
+        # aggregated column: within-batch inclusive per-key prefix
+        agg_sorted = cols[pos][perm]
+        (agg_prefix,) = segmented_scan(
+            (agg_sorted,), seg_starts, lambda a, b: (reducer(a[0], b[0]),)
+        )
 
     n_planes = len(state["planes"])
 
@@ -230,30 +253,28 @@ def _rolling_step_commutative(
         return unpack_words(words, [kinds[i]], [c32[i]])[0]
 
     keep = [i for i in range(len(kinds)) if i != pos and i != key_col]
-    stored_keep = [gather_leaf(i) for i in keep]
-
-    # aggregated column: within-batch inclusive per-key prefix
-    agg_sorted = cols[pos][perm]
-    (agg_prefix,) = segmented_scan(
-        (agg_sorted,), seg_starts, lambda a, b: (reducer(a[0], b[0]),)
-    )
-    if sentinel_leaf is not None:
-        # occupancy from the sentinel keep leaf (gathered anyway):
-        # interned ids are >= 0, -1 marks a never-written key row
-        seen_sorted = (stored_keep[keep.index(sentinel_leaf)] >= 0) & sv
-    else:
-        seen_sorted = state["seen"][safe_keys] & sv
-    stored_agg = gather_leaf(pos)
-    combined_agg = reducer(stored_agg, agg_prefix)
-    emis_agg = jnp.where(seen_sorted, combined_agg, agg_prefix)
+    with jax.named_scope(STATE):
+        stored_keep = [gather_leaf(i) for i in keep]
+        if sentinel_leaf is not None:
+            # occupancy from the sentinel keep leaf (gathered anyway):
+            # interned ids are >= 0, -1 marks a never-written key row
+            seen_sorted = (stored_keep[keep.index(sentinel_leaf)] >= 0) & sv
+        else:
+            seen_sorted = state["seen"][safe_keys] & sv
+        stored_agg = gather_leaf(pos)
+    with jax.named_scope(COMBINE):
+        combined_agg = reducer(stored_agg, agg_prefix)
+        emis_agg = jnp.where(seen_sorted, combined_agg, agg_prefix)
 
     # per-batch state value for the aggregated leaf IS its tail emission
     new_planes = list(state["planes"])
-    agg_words = pack_words([emis_agg], [kinds[pos]], [c32[pos]])
-    for p, w in zip(range(*slices[pos].indices(n_planes)), agg_words):
-        new_planes[p] = state["planes"][p].at[tail_idx].set(
-            w.astype(state["planes"][p].dtype), mode="drop", unique_indices=True
-        )
+    with jax.named_scope(STATE):
+        agg_words = pack_words([emis_agg], [kinds[pos]], [c32[pos]])
+        for p, w in zip(range(*slices[pos].indices(n_planes)), agg_words):
+            new_planes[p] = state["planes"][p].at[tail_idx].set(
+                w.astype(state["planes"][p].dtype), mode="drop",
+                unique_indices=True,
+            )
 
     any_new = jnp.any(sv & ~seen_sorted)
 
@@ -299,13 +320,14 @@ def _rolling_step_commutative(
     def no_new(keep_planes, seen):
         return tuple(stored_keep), tuple(keep_planes), seen
 
-    keep_emis, keep_planes_out, new_seen = jax.lax.cond(
-        any_new,
-        with_new,
-        no_new,
-        tuple(state["planes"][p] for p in keep_plane_ids),
-        state["seen"],
-    )
+    with jax.named_scope(STATE):
+        keep_emis, keep_planes_out, new_seen = jax.lax.cond(
+            any_new,
+            with_new,
+            no_new,
+            tuple(state["planes"][p] for p in keep_plane_ids),
+            state["seen"],
+        )
     for flat, p in enumerate(keep_plane_ids):
         new_planes[p] = keep_planes_out[flat]
 
@@ -321,16 +343,17 @@ def _rolling_step_commutative(
             emis_sorted.append(keep_emis[kj])
             kj += 1
 
-    inv = inverse_permutation(perm)
-    out = (
-        {"seen": new_seen, "planes": new_planes},
-        tuple(emis_sorted),
-        sv,
-        sk,
-        inv,
-    )
-    if sort_also:
-        out = out + (tuple(x[perm] for x in sort_also),)
+    with jax.named_scope(COMBINE):
+        inv = inverse_permutation(perm)
+        out = (
+            {"seen": new_seen, "planes": new_planes},
+            tuple(emis_sorted),
+            sv,
+            sk,
+            inv,
+        )
+        if sort_also:
+            out = out + (tuple(x[perm] for x in sort_also),)
     return out
 
 

@@ -9,11 +9,16 @@ fuse for free, and all arithmetic (user combiners, finalize, the post
 chain) runs on the reconstructed full-precision values.
 
 float64 leaves keep a native f64 plane: this TPU's XLA rejects f64
-bitcasts outright (x64 rewriter limitation, verified), and the only f64
-accumulators in the reference surface are aggregate-function state like
-the windowed-average (count, sum) pair (chapter2/.../ComputeCpuAvg.java:
-33-36) — jobs whose golden tests run at tiny key counts where the slow
-emulated scatter is irrelevant.
+bitcasts outright (x64 rewriter limitation, verified), so a float64
+cannot be split into words on the device. Where a job's device work on
+a float64 is only ordering or passing it through (the rolling
+``max``/``min``/``max_by``/``min_by`` fields and keep-first fields,
+``RollingProgram.image_fields``), the host makes its exact int64 image
+at pack time (``records.f64_image``) and the leaf is an I64 one here: two
+int32 planes on the fast scatter, at any key count (the chapter-2
+running max holds 2^20 hosts on one chip). Float64 leaves the device
+does arithmetic on (sums, averages, the windowed (count, sum) pair of
+chapter2/.../ComputeCpuAvg.java:33-36) keep the emulated f64 plane.
 """
 
 from __future__ import annotations

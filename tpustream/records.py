@@ -24,6 +24,32 @@ BOOL = "bool"
 
 NUMPY_DTYPES = {F64: np.float64, I64: np.int64, STR: np.int32, BOOL: np.bool_}
 
+_CANONICAL_NAN_BITS = 0x7FF8_0000_0000_0000
+_LOW_63 = 0x7FFF_FFFF_FFFF_FFFF
+
+
+def f64_image(values: np.ndarray) -> np.ndarray:
+    """The exact int64 image of float64 values whose signed-integer
+    order is Java's ``Double.compareTo`` (Flink's ``ComparableAggregator``
+    order): -0.0 below 0.0, every NaN one value above +inf. The bits of
+    a negative double are flipped below the sign, so larger magnitudes
+    sort lower; NaN takes its canonical bits first
+    (``Double.doubleToLongBits``). The device compares and selects
+    images as plain int64, with no emulated float64 in the way."""
+    v = np.asarray(values, dtype=np.float64)
+    bits = v.view(np.int64)
+    nan = np.isnan(v)
+    if nan.any():
+        bits = np.where(nan, np.int64(_CANONICAL_NAN_BITS), bits)
+    return bits ^ ((bits >> 63) & _LOW_63)
+
+
+def f64_from_image(image: np.ndarray) -> np.ndarray:
+    """Inverse of :func:`f64_image` (the flip is its own inverse): the
+    float64 values, bit for bit, NaN as the canonical NaN."""
+    i = np.asarray(image, dtype=np.int64)
+    return (i ^ ((i >> 63) & _LOW_63)).view(np.float64)
+
 
 class StringTable:
     """Bidirectional string <-> dense int32 id map.

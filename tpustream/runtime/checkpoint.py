@@ -104,8 +104,25 @@ MIGRATIONS = {
         "arrays; unchanged leaves re-use chunks written by earlier "
         "snapshots, so a v12 manifest is only restorable next to its "
         "chunk store (self-contained inline snapshots remain valid v12)",
+    13: "order-only float64 rolling fields (the max/min/max_by/min_by "
+        "fields and keep-first fields, RollingProgram.image_fields) are "
+        "state as their exact int64 image (records.f64_image) in two "
+        "int32 planes instead of one float64 plane; this build restores "
+        "a v12 snapshot by migrating the float64 plane to the image "
+        "(Checkpoint.restore_chain)",
 }
 FORMAT_VERSION = max(MIGRATIONS)
+#: the oldest format this build restores: a v12 snapshot's float64
+#: rolling planes migrate to their images on restore (MIGRATIONS[13]);
+#: older formats are refused with the version message
+OLDEST_READABLE = 12
+
+
+def readable_version(version) -> bool:
+    """Whether this build restores a snapshot of format ``version``."""
+    return isinstance(version, int) and (
+        OLDEST_READABLE <= version <= FORMAT_VERSION
+    )
 _META_KEY = "__meta__"
 CHUNK_DIR = "chunks"
 GC_MARK = "gc-mark.json"
@@ -237,8 +254,9 @@ class Checkpoint:
         offset = 0
         for i, prog in enumerate(programs):
             n = len(jax.tree_util.tree_leaves(prog.init_state()))
+            leaves, used = _migrated_leaves(prog, self.leaves[offset:], n)
             sub = Checkpoint(
-                leaves=self.leaves[offset : offset + n],
+                leaves=leaves,
                 record_kinds=self.record_kinds,
                 tables=self.tables,
                 source_pos=self.source_pos,
@@ -249,7 +267,7 @@ class Checkpoint:
                 parallelism=self.parallelism,
             )
             states.append(sub.restore_state(prog))
-            offset += n
+            offset += used
         if offset != len(self.leaves):
             raise ValueError(
                 f"checkpoint has {len(self.leaves)} state arrays but the "
@@ -370,6 +388,34 @@ class Checkpoint:
         for table, saved in zip(plan.tables, self.tables):
             if table is not None and saved is not None:
                 table.load_state_dict(saved)
+
+
+def _dtypes(leaves) -> list:
+    return [np.dtype(l.dtype).name for l in leaves]
+
+
+def _migrated_leaves(program, saved: list, n: int):
+    """``(leaves, taken)``: ``program``'s ``n`` state leaves from the
+    head of ``saved``, and how many saved leaves they took. A program
+    whose state changed layout in format v13 (``image_fields``: the
+    rolling float64 planes now held as images) takes the older layout
+    (``legacy_init_state``) where the saved dtypes read as it, and
+    converts it (``upgrade_legacy_state``); the snapshot's leaves
+    decide, not its version number."""
+    if not program.image_fields:
+        return saved[:n], n
+    current = jax.tree_util.tree_leaves(jax.eval_shape(program.init_state))
+    old_leaves, treedef = jax.tree_util.tree_flatten(
+        jax.eval_shape(program.legacy_init_state))
+    m = len(old_leaves)
+    if _dtypes(saved[:n]) == _dtypes(current) or (
+        _dtypes(saved[:m]) != _dtypes(old_leaves)
+    ):
+        return saved[:n], n
+    tree = jax.tree_util.tree_unflatten(
+        treedef, [np.asarray(l) for l in saved[:m]]
+    )
+    return jax.tree_util.tree_leaves(program.upgrade_legacy_state(tree)), m
 
 
 # ---------------------------------------------------------------------------
@@ -908,10 +954,11 @@ def validate_checkpoint(path: str) -> Optional[str]:
         return "no metadata (partial or foreign file)"
     except Exception as e:
         return f"unreadable ({type(e).__name__}: {e})"
-    if meta.get("version") != FORMAT_VERSION:
+    if not readable_version(meta.get("version")):
         return (
             f"format version {meta.get('version')} != this build's "
-            f"{FORMAT_VERSION}"
+            f"{FORMAT_VERSION} (restores v{OLDEST_READABLE}-"
+            f"v{FORMAT_VERSION})"
         )
     chunks = meta.get("chunks")
     if chunks is not None:
@@ -1017,7 +1064,7 @@ def load_checkpoint(path: str) -> Checkpoint:
             raise FileNotFoundError(f"no checkpoint found in {path}")
         path = p
     meta, leaves = _read_npz(path)
-    if meta.get("version") != FORMAT_VERSION:
+    if not readable_version(meta.get("version")):
         raise ValueError(
             f"checkpoint format version {meta.get('version')} does not "
             f"match this build's {FORMAT_VERSION} — the snapshot was "

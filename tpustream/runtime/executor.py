@@ -27,7 +27,15 @@ from ..api.watermarks import (
 )
 from ..config import StreamConfig
 from ..hostparse import PlanEvaluator, run_fallback_map
-from ..records import STR, Batch, Column, DerivedKeyTable, StringTable
+from ..records import (
+    STR,
+    Batch,
+    Column,
+    DerivedKeyTable,
+    StringTable,
+    f64_from_image,
+    f64_image,
+)
 from ..api.timeapi import TimeCharacteristic
 from ..obs.tracing import NULL_TRACER
 from .metrics import Metrics, Stopwatch
@@ -532,7 +540,8 @@ class Runner:
         self.state = self.program.init_state()
         self.sinks, self.side_sinks = _make_sinks(plan, cfg)
         self.formatter = EmissionFormatter(
-            self.program.out_kinds, self.program.out_tables
+            self.program.out_kinds, self.program.out_tables,
+            images=self.program.image_fields,
         )
         self.in_kinds = plan.record_kinds
         self._empty_cache = None
@@ -665,6 +674,8 @@ class Runner:
         # rows handed to a sink in one emit_many call per branch (the
         # per-row fan-out of chained stages and the ledger counts none)
         self._rows_batched = self.obs.counter("emit_rows_batched_total")
+        # float64 values packed as their int64 image (_pack "img" mode)
+        self._images_packed = self.obs.counter("f64_ordered_values_total")
         self._compaction_gauge = self.obs.gauge("compaction_ratio")
         # chained stages: emissions feed the downstream runner as
         # columnar batches instead of the sinks (build_plan_chain).
@@ -1063,7 +1074,9 @@ class Runner:
         float64 -> f32 (exact-round-trip float32) -> raw;
         interned string ids (int32) -> i16 -> raw;
         bool columns and the valid mask -> bits (8 rows/byte).
-        A demoted column stays demoted (at most one recompile each)."""
+        A demoted column stays demoted (at most one recompile each).
+        An order-only float64 (``program.image_fields``) goes as its
+        exact int64 image (``img``), which never demotes."""
         compress = self.cfg.h2d_compress
         # bit-packing changes the wire leaf's leading dim from [B] to
         # [B/8]; the multi-host gshard split slices rows per process, so
@@ -1073,8 +1086,11 @@ class Runner:
             "d16" if compress and packed else "d32" if compress else "raw"
         )
         modes = []
-        for k in self.in_kinds:
-            if k == "i64":
+        images = self.program.image_fields
+        for i, k in enumerate(self.in_kinds):
+            if i in images:
+                modes.append("img")
+            elif k == "i64":
                 modes.append(i64_mode)
             elif k == "f64" and packed:
                 modes.append("f32")
@@ -1138,6 +1154,8 @@ class Runner:
                 # slices back to batch_size (bits is lossless — never
                 # demotes)
                 return np.packbits(arr.astype(bool)), np.int64(0), mode
+            if mode == "img":
+                return f64_image(arr), np.int64(0), mode
             return arr, np.int64(0), mode
 
         packed, bases, modes = [], [], []
@@ -1160,6 +1178,11 @@ class Runner:
             valid_p = np.packbits(valid)
         else:
             valid_p = valid
+        n_img = self._col_modes.count("img")
+        if n_img and any_valid:
+            self._images_packed.inc(
+                n_img * (len(valid) if all_valid else int(valid.sum()))
+            )
         return tuple(packed), tuple(bases), valid_p, ts_p, ts_b
 
     def _ensure_step(self):
@@ -2288,6 +2311,14 @@ class Runner:
             if int(jax.device_get(self.state["pending_fires"])) == 0:
                 break
 
+    def _chain_cols(self, cols):
+        """A step's fetched emission columns as the next stage takes
+        them: image fields back to float64 (the sinks' formatter does
+        the same for rows)."""
+        for i in self.program.image_fields:
+            cols[i] = f64_from_image(cols[i])
+        return cols
+
     def _emit_row(self, row, subtask, ts=None, order=None):
         """Fan one emitted record out to every branch: apply the
         branch's host-side map/filter tail, then its sink. Chained
@@ -2419,7 +2450,7 @@ class Runner:
                 # process's rows ARE its shards' region of the global
                 # row space). Compacted streams never reach here —
                 # compaction is disabled under multi-host.
-                cols = [take(c) for c in main["cols"]]
+                cols = self._chain_cols([take(c) for c in main["cols"]])
                 wend = main.get("window_end")
                 if wend is not None:
                     self._chain_buf.append(("win", cols,
@@ -2441,6 +2472,7 @@ class Runner:
             elif sel.size:
                 cols = [take(c) for c in main["cols"]]
                 if self.downstream is not None:
+                    cols = self._chain_cols(cols)
                     # chained stage: hand the columnar emissions straight
                     # to the next runner (no Python rows in between).
                     # Event timestamps: window results carry end - 1

@@ -12,13 +12,13 @@ print parallelism is 1.
 from __future__ import annotations
 
 import itertools
-from typing import Callable, List, Optional
+from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
 from ..api.tuples import TUPLE_CLASSES, _java_str
 from ..obs.registry import NULL_COUNTER
-from ..records import BOOL, F64, STR
+from ..records import BOOL, F64, STR, f64_from_image
 
 
 class EmissionFormatter:
@@ -28,18 +28,30 @@ class EmissionFormatter:
     Each column converts once (``tolist``, or one gather through its
     string table), and :func:`iter_rows` builds the rows from the
     converted lists as a sink takes them, so a row costs a few
-    interpreter steps rather than a cast and a dispatch per field."""
+    interpreter steps rather than a cast and a dispatch per field.
 
-    def __init__(self, kinds: List[str], tables: List[Optional[object]]):
+    ``images`` names the float64 columns that arrive as their int64
+    image (``records.f64_image``); they turn back into the exact
+    float64 values here."""
+
+    def __init__(self, kinds: List[str], tables: List[Optional[object]],
+                 images: Sequence[int] = ()):
         self.kinds = kinds
         self.tables = tables
+        self.images = frozenset(images)
 
     def columns(self, cols: List[np.ndarray]) -> List[list]:
         """Each column as a list of Python values of its kind: ``str``
         (None for a negative id), ``float``, ``bool`` or ``int``."""
         return [
-            _column_values(kind, np.asarray(col), table)
-            for kind, col, table in zip(self.kinds, cols, self.tables)
+            _column_values(
+                kind,
+                f64_from_image(col) if i in self.images else np.asarray(col),
+                table,
+            )
+            for i, (kind, col, table) in enumerate(
+                zip(self.kinds, cols, self.tables)
+            )
         ]
 
 

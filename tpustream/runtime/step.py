@@ -270,6 +270,11 @@ class BaseProgram:
     # kilobytes and megabytes per firing step
     main_emission_prefix = False
 
+    # record fields that cross the wire and come back as their int64
+    # image (records.f64_image) instead of as float64: the rolling
+    # program's order-only float64 fields; no other program has any
+    image_fields: Tuple[int, ...] = ()
+
     # -- SPMD hooks: identity on one chip, mesh collectives when sharded --
     n_shards = 1
     vary_axes: tuple = ()
@@ -378,6 +383,35 @@ class RollingProgram(BaseProgram):
         )
         self.out_kinds = self.post_chain.out_kinds
         self.out_tables = self.post_chain.out_tables
+        self.image_fields = self._order_only_f64()
+        # the kinds the device holds: an order-only float64 field rides
+        # as its int64 image (records.f64_image), in two int32 planes
+        self.state_kinds = [
+            I64 if i in self.image_fields else k
+            for i, k in enumerate(self.mid_kinds)
+        ]
+
+    def _order_only_f64(self) -> Tuple[int, ...]:
+        """Record fields (input = emitted positions) whose float64 the
+        step only compares, selects or passes through: every field of
+        ``max``/``min``/``max_by``/``min_by`` and the keep-first fields
+        of ``sum``, when no device op reads the record before or after
+        the aggregate. They cross the wire, live in state and come back
+        as their exact int64 image, compared in Java's order; the TPU
+        emulates float64, and its answers are not always among its
+        inputs. A field the 32-bit accumulator opt-in narrows keeps its
+        float32 plane, and the key column keeps its own path."""
+        st, plan = self.plan.stateful, self.plan
+        if st.kind != "rolling" or plan.device_pre or plan.device_post:
+            return ()
+        c32 = self._compact32
+        c32 = c32 if isinstance(c32, list) else [c32] * len(self.mid_kinds)
+        return tuple(
+            i for i, k in enumerate(self.mid_kinds)
+            if k == F64 and not c32[i]
+            and (plan.synthetic_key or i != self.key_pos)
+            and not (st.rolling_kind == "sum" and i == st.rolling_pos)
+        )
 
     @property
     def _compact32(self):
@@ -409,10 +443,37 @@ class RollingProgram(BaseProgram):
     def init_state(self):
         return self._with_rules(
             rolling_ops.init_rolling_state(
+                self.cfg.key_capacity, self.state_kinds, self._compact32,
+                sentinel_leaf=self._sentinel_leaf,
+            )
+        )
+
+    def legacy_init_state(self):
+        """The init state of a build before the images (checkpoint
+        format v12: a float64 plane per order-only field)."""
+        return self._with_rules(
+            rolling_ops.init_rolling_state(
                 self.cfg.key_capacity, self.mid_kinds, self._compact32,
                 sentinel_leaf=self._sentinel_leaf,
             )
         )
+
+    def upgrade_legacy_state(self, state: dict) -> dict:
+        """A v12 state (numpy leaves, :meth:`legacy_init_state`'s tree)
+        in this program's layout: each image field's float64 plane
+        becomes the two int32 planes of its image, bit for bit."""
+        from ..ops.wordplanes import leaf_plane_slices, pack_words
+        from ..records import f64_image
+
+        c32 = self._compact32
+        old = leaf_plane_slices(self.mid_kinds, c32)
+        planes = []
+        for i, sl in enumerate(old):
+            leaf = list(state["planes"][sl])
+            if i in self.image_fields:
+                leaf = pack_words([f64_image(leaf[0])], [I64])
+            planes.extend(np.asarray(p) for p in leaf)
+        return dict(state, planes=planes)
 
     def state_specs(self, state):
         from jax.sharding import PartitionSpec as P
@@ -457,7 +518,7 @@ class RollingProgram(BaseProgram):
             fast_kwargs["sort_also"] = (ts,)
         out = rolling_ops.rolling_step(
             state, keys, tuple(mid_cols), mask, self.combine,
-            self.mid_kinds, self._compact32, **fast_kwargs,
+            self.state_kinds, self._compact32, **fast_kwargs,
         )
         new_state, emitted_sorted, sv, sk, inv = out[:5]
         # emissions stay in sorted order; the host un-permutes via
