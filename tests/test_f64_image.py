@@ -8,7 +8,8 @@ system is held here to a record-at-a-time reference of Flink's rolling
 semantics in Java's ``Double.compare`` order, bit for bit (``float.hex``
 equality), at ``parallelism`` 1 and sharded over the CPU devices; the
 wire and the state are checked to carry no such value as float64, and
-the bandwidth job's step program to be the one it was before images.
+the bandwidth job's and the running max's step programs are pinned by
+the sha256 of their text.
 """
 
 import hashlib
@@ -229,19 +230,17 @@ def test_float32_override_keeps_its_32bit_plane(captured):
     assert dtypes == ["int32", "int32", "int32", "int32", "float32"]
 
 
-def _bandwidth_step_hlo(captured_runs):
-    """StableHLO text of the one-chip bandwidth job's jitted step."""
-    t0 = np.datetime64("2019-08-28T09:00:00")
-    text = "\n".join(f"{t0 + np.timedelta64(i, 's')} c{i % 5} {100 + i}"
-                     for i in range(64)).encode() + b"\n"
-    from tpustream.jobs.chapter3_bandwidth_eventtime import build
-
+def _step_hlo(captured_runs, build, text: bytes, n: int,
+              event_time: bool = False):
+    """StableHLO text of a one-chip job's jitted step, built by
+    ``build`` over ``n`` lines of ``text``."""
     env = StreamExecutionEnvironment(StreamConfig(
         batch_size=32, key_capacity=64, alert_capacity=256,
         obs=ObsConfig(enabled=True)))
-    env.set_stream_time_characteristic(TimeCharacteristic.EventTime)
-    build(env, env.add_source(ReplayBytesSource([(text, 64)]))).collect()
-    res = env.execute("bw")
+    if event_time:
+        env.set_stream_time_characteristic(TimeCharacteristic.EventTime)
+    build(env, env.add_source(ReplayBytesSource([(text, n)]))).collect()
+    res = env.execute("step-hlo")
     runner = captured_runs["runners"][0]
     shape = lambda x: jax.ShapeDtypeStruct(np.shape(x),  # noqa: E731
                                            np.asarray(x).dtype)
@@ -251,6 +250,26 @@ def _bandwidth_step_hlo(captured_runs):
     step = executor.Runner._counted_step(_NoObs(runner), runner._inner_step)
     hlo = step.lower(state, *inputs, shape(np.int64(0))).as_text()
     return hlo, runner, res
+
+
+def _bandwidth_step_hlo(captured_runs):
+    """StableHLO text of the one-chip bandwidth job's jitted step."""
+    from tpustream.jobs.chapter3_bandwidth_eventtime import build
+
+    t0 = np.datetime64("2019-08-28T09:00:00")
+    text = "\n".join(f"{t0 + np.timedelta64(i, 's')} c{i % 5} {100 + i}"
+                     for i in range(64)).encode() + b"\n"
+    return _step_hlo(captured_runs, build, text, 64, event_time=True)
+
+
+def _rolling_step_hlo(captured_runs):
+    """StableHLO text of the one-chip running max's (chapter 2,
+    ``cpu-max-1M``'s job) jitted step."""
+    from tpustream.jobs.chapter2_max import build
+
+    text = "\n".join(f"{1565000000 + i} host{i % 7:07d} cpu{i % 4} "
+                     f"{10 + i % 90}.{i % 10}" for i in range(64)).encode()
+    return _step_hlo(captured_runs, build, text + b"\n", 64)
 
 
 class _NoObs:
@@ -265,12 +284,16 @@ class _NoObs:
         return getattr(self._r, name)
 
 
-# sha256 of the one-chip bandwidth step's StableHLO text above, built
-# by the code before order-only float64 rode as images: the images must
-# leave that program as it was (update only with a deliberate change to
-# the window program or the executor's step wrapper)
+# sha256 of the one-chip bandwidth step's StableHLO text above: the
+# images must leave that program as it is (update only with a deliberate
+# change to the window program or the executor's step wrapper)
 BANDWIDTH_STEP_SHA256 = (
-    "a60cb436daddcab7914488ade8bf9668c5d0a310cfeb4a50bff173f046574d5e")
+    "e61e2be0f706f049a26247929c30c62db14284c99d2b55c76eda037885163994")
+
+# sha256 of the one-chip running max's step (the rolling program, which
+# runs no pane ring): a change to the pane ring must leave it as it is
+ROLLING_STEP_SHA256 = (
+    "5d2ccf5df78bd981d568f91802b680a7fcebb5d2e2ea23c03274729894b50d75")
 
 
 def test_bandwidth_step_program_is_unchanged(captured):
@@ -283,6 +306,12 @@ def test_bandwidth_step_program_is_unchanged(captured):
     counts = [s["value"] for s in series
               if s["name"].endswith("f64_ordered_values_total")]
     assert counts and sum(counts) == 0
+
+
+def test_rolling_step_program_is_unchanged(captured):
+    hlo, runner, _ = _rolling_step_hlo(captured)
+    assert runner.program.image_fields == (2,)
+    assert hashlib.sha256(hlo.encode()).hexdigest() == ROLLING_STEP_SHA256
 
 
 # -- checkpoints: the v12 goldens hold the max in a float64 plane -------------

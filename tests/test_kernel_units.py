@@ -81,20 +81,22 @@ def test_fire_candidates_fire_exactly_once_per_boundary():
 def test_retarget_clears_stale_slots_and_counts_unfired():
     spec = P.make_ring_spec(10_000, 10_000, 0, 0, slack=2)
     n = spec.n_slots
-    cnt = jnp.ones((1, n), dtype=jnp.int32)  # one record in every slot
-    acc = [jnp.full((1, n), 7.0)]
-    init = [jnp.zeros((1, n))]
+    cnt = jnp.ones((n,), dtype=jnp.int32)  # one record in every slot row
+    acc = [jnp.full((n,), 7, dtype=jnp.int32)]
+    init = [jnp.zeros((), dtype=jnp.int32)]
     slot_pane = P.slot_targets(jnp.int64(n - 1), spec)  # ring at panes [0, n)
     # jump far ahead: every slot becomes stale
     hi = jnp.int64(10 * n)
     wm = jnp.int64(0)  # nothing has fired
-    acc2, cnt2, tgt, evicted = P.retarget(acc, cnt, slot_pane, hi, wm, spec, init)
+    acc2, cnt2, tgt, evicted = P.retarget_rows(
+        acc, cnt, slot_pane, hi, wm, spec, init)
     assert int(evicted) == n  # all n records were evicted before firing
     assert int(np.asarray(cnt2).sum()) == 0
-    assert float(np.asarray(acc2[0]).sum()) == 0.0
+    assert int(np.asarray(acc2[0]).sum()) == 0
     # same jump but wm already past every stale window end: nothing "unfired"
     wm_done = jnp.int64((n + spec.panes_per_window) * spec.pane_ms)
-    _, _, _, evicted2 = P.retarget(acc, cnt, slot_pane, hi, wm_done, spec, init)
+    _, _, _, evicted2 = P.retarget_rows(
+        acc, cnt, slot_pane, hi, wm_done, spec, init)
     assert int(evicted2) == 0
 
 

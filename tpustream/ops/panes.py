@@ -89,43 +89,55 @@ def slot_targets(hi_pane, spec: RingSpec):
     return hi_pane - jnp.mod(hi_pane - s, n)
 
 
-def retarget(acc_leaves, cnt, slot_pane, hi_pane, wm, spec: RingSpec, init_leaves):
-    """Advance the ring to cover (hi-N, hi]: slots whose stored pane no
-    longer matches their target are cleared (evicted).
+def retarget_rows(planes, cnt, slot_pane, hi_pane, wm, spec: RingSpec,
+                  init_leaves, axes=()):
+    """Advance the ring to cover (hi-N, hi]: each slot whose stored pane
+    no longer matches its target is cleared (evicted) IN PLACE.
 
-    Returns (acc_leaves, cnt, new_slot_pane, evicted_unfired_records) —
-    the count covers records in evicted panes whose last window had NOT
+    ``planes`` and ``cnt`` are the FLAT slot-major word planes (cell =
+    slot * keys + key), so a slot is one contiguous row of ``keys``
+    cells. Only the stale rows are written, one ``dynamic_update_slice``
+    each, in a loop bounded by the stale count: 0 while the newest pane
+    holds, 1 when it advances by one, up to N after a jump. Every other
+    row is untouched, so XLA updates the donated state in place instead
+    of rewriting every plane. ``axes`` are the mesh axes the planes vary
+    over (see :func:`vary`).
+
+    Returns (planes, cnt, new_slot_pane, evicted_unfired_records) — the
+    count covers records in evicted panes whose last window had NOT
     fired yet (a ring-undersized condition; n_slots must cover
     (size + delay + lateness)/pane plus slack).
     """
+    n = spec.n_slots
+    k = cnt.shape[0] // n
     target = slot_targets(hi_pane, spec)
     stale = slot_pane != target
     last_end = (slot_pane + spec.panes_per_window) * spec.pane_ms
     unfired = stale & (last_end - 1 > wm)
-    evicted = jnp.sum(jnp.where(unfired, jnp.sum(cnt, axis=0), 0))
-    cnt = jnp.where(stale[None, :], 0, cnt)
-    acc_leaves = [
-        jnp.where(stale[None, :], init, a)
-        for a, init in zip(acc_leaves, init_leaves)
-    ]
-    return acc_leaves, cnt, target, evicted
+    slots, n_stale = compact_positions(stale, n)
 
+    def clear_row(i, carry):
+        planes, cnt, evicted = carry
+        slot = slots[i]
+        row0 = slot * k
+        held = jnp.sum(
+            jax.lax.dynamic_slice(cnt, (row0,), (k,)), dtype=jnp.int64
+        )
+        evicted = evicted + jnp.where(unfired[slot], held, 0)
+        cnt = jax.lax.dynamic_update_slice(
+            cnt, jnp.zeros((k,), dtype=cnt.dtype), (row0,)
+        )
+        planes = [
+            jax.lax.dynamic_update_slice(p, jnp.full((k,), init, p.dtype), (row0,))
+            for p, init in zip(planes, init_leaves)
+        ]
+        return planes, cnt, evicted
 
-def retarget_rows(plane_leaves, cnt, slot_pane, hi_pane, wm, spec: RingSpec, init_leaves):
-    """:func:`retarget` for slot-major ``[n_slots, keys]`` state planes
-    (the word-plane window layout): slots are ROWS, so stale slots clear
-    whole rows and the unfired count sums each stale row."""
-    target = slot_targets(hi_pane, spec)
-    stale = slot_pane != target
-    last_end = (slot_pane + spec.panes_per_window) * spec.pane_ms
-    unfired = stale & (last_end - 1 > wm)
-    evicted = jnp.sum(jnp.where(unfired, jnp.sum(cnt, axis=1), 0))
-    cnt = jnp.where(stale[:, None], 0, cnt)
-    plane_leaves = [
-        jnp.where(stale[:, None], init, p)
-        for p, init in zip(plane_leaves, init_leaves)
-    ]
-    return plane_leaves, cnt, target, evicted
+    planes, cnt, evicted = jax.lax.fori_loop(
+        0, n_stale, clear_row,
+        (list(planes), cnt, vary(jnp.zeros((), dtype=jnp.int64), axes)),
+    )
+    return planes, cnt, target, evicted
 
 
 def fire_candidates(hi_pane, wm_old, wm_new, spec: RingSpec):

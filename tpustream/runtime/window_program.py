@@ -814,23 +814,10 @@ class WindowProgram(BaseProgram):
             )
             hi_next = jnp.maximum(hi_next, hi_cur)
 
-            def do_rt(_):
-                p2, c2, sp2, ev = pane_ops.retarget_rows(
-                    [pl.reshape(n, kloc) for pl in planes],
-                    cnt.reshape(n, kloc),
-                    slot_pane, hi_next, ft, ring, init_leaves,
-                )
-                return [pl.reshape(-1) for pl in p2], c2.reshape(-1), sp2, ev
-
-            def no_rt(_):
-                return (
-                    list(planes), cnt, slot_pane,
-                    v(jnp.zeros((), dtype=jnp.int64)),
-                )
-
             with jax.named_scope("retarget"):
-                planes2, cnt2, slot_pane2, ev = jax.lax.cond(
-                    hi_next > hi_cur, do_rt, no_rt, operand=None
+                planes2, cnt2, slot_pane2, ev = pane_ops.retarget_rows(
+                    planes, cnt, slot_pane, hi_next, ft, ring, init_leaves,
+                    self.vary_axes,
                 )
             smask = unscat & (pane <= hi_next)
             with jax.named_scope("scatter"):
@@ -911,7 +898,7 @@ class WindowProgram(BaseProgram):
             jnp.asarray(ident, dtype=p.dtype)
             for p, ident in zip(state["planes"], self._plane_identities())
         ]
-        n_slots, kloc = ring.n_slots, self.local_key_capacity
+        n_slots = ring.n_slots
         ft0 = state["fired_through"]
 
         # ---- fast path vs jump sweep ------------------------------------
@@ -945,34 +932,14 @@ class WindowProgram(BaseProgram):
 
         def fast_path(op):
             planes, cnt = op
-
-            def do_retarget(_):
-                planes2d, cnt2d, slot_pane2, evicted = pane_ops.retarget_rows(
-                    [p.reshape(n_slots, kloc) for p in planes],
-                    cnt.reshape(n_slots, kloc),
-                    state["slot_pane"], hi, ft0, ring, init_leaves,
-                )
-                return (
-                    [p.reshape(-1) for p in planes2d],
-                    cnt2d.reshape(-1),
-                    slot_pane2,
-                    evicted,
-                )
-
-            def skip_retarget(_):
-                return (
-                    list(planes),
-                    cnt,
-                    state["slot_pane"],
-                    pane_ops.vary(jnp.zeros((), dtype=jnp.int64), self.vary_axes),
-                )
-
             # named scopes label the device ops of each phase in the
-            # compiled program's metadata (obs/compilation.py maps them)
+            # compiled program's metadata (obs/compilation.py maps them);
+            # while the newest pane holds, no slot is stale and the
+            # retarget's row loop runs no iteration
             with jax.named_scope("retarget"):
-                planes2, cnt2, slot_pane, evicted = jax.lax.cond(
-                    hi > state["hi"], do_retarget, skip_retarget,
-                    operand=None,
+                planes2, cnt2, slot_pane, evicted = pane_ops.retarget_rows(
+                    planes, cnt, state["slot_pane"], hi, ft0, ring,
+                    init_leaves, self.vary_axes,
                 )
             with jax.named_scope("scatter"):
                 planes2, cnt2, touched = self._scatter_words(
